@@ -1,0 +1,292 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the served SQL path, once, on one TPU chip.
+
+One process builds `TpchConnector(SF)`, a `TpuCluster` with its default
+in-process workers and a `StatementServer`, then sends TPC-H q06, q01 and
+q03 through `run_statement` (client POST /v1/statement to last row) twice
+each: cold, then warm. Every result is compared, outside the timed part,
+with an oracle that never touches the engine: numpy over the generated
+arrays for q01/q06, a pandas merge for q03.
+
+It needs a TPU: with none it exits non-zero and prints no result
+(`--allow-cpu` exists only for the CPU rehearsal in the tests, `--sf` only
+so that rehearsal can run at 0.01). Any mismatch or exception in any phase
+is a non-zero exit. Walls printed here are a smoke's, not benchmark numbers.
+
+The last line of stdout is the contract's:
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+"""
+
+from __future__ import annotations
+
+import argparse
+import datetime
+import json
+import os
+import shutil
+import sys
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+QIDS = (6, 1, 3)
+_EPOCH = datetime.date(1970, 1, 1)
+
+
+def _days(y: int, m: int, d: int) -> int:
+    return (datetime.date(y, m, d) - _EPOCH).days
+
+
+class CompileCounter:
+    """Counts XLA backend compilations through jax.monitoring: every
+    compile request fires the backend-compile duration event, and a
+    request the persistent cache answered also fires a cache-hit event,
+    so `compiled` is the programs the compiler really built. `seconds`
+    sums the requests' durations over all threads (two workers compile
+    at once), so it can exceed the wall around them."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self.requests = 0
+        self.hits = 0
+        self.seconds = 0.0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, event: str, secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.requests += 1
+            self.seconds += secs
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+    @property
+    def compiled(self) -> int:
+        return self.requests - self.hits
+
+
+_COUNTER = None
+
+
+def _compile_counter() -> CompileCounter:
+    """One listener per process (jax.monitoring has no unregister), so
+    the in-process rehearsal can call main() more than once."""
+    global _COUNTER
+    if _COUNTER is None:
+        _COUNTER = CompileCounter()
+    return _COUNTER
+
+
+def _say(**fields) -> None:
+    print(json.dumps(fields), flush=True)
+
+
+# ---------------------------------------------------------------- oracles
+
+def oracle_q06(conn):
+    t = conn.table("lineitem")
+    n = int(t.num_rows)
+    qty = t.arrays["l_quantity"][:n]
+    eprice = t.arrays["l_extendedprice"][:n]
+    disc = t.arrays["l_discount"][:n]
+    sdate = t.arrays["l_shipdate"][:n]
+    keep = ((sdate >= _days(1994, 1, 1)) & (sdate < _days(1995, 1, 1))
+            & (disc >= 0.05) & (disc <= 0.07) & (qty < 24))
+    return [(float((eprice[keep] * disc[keep]).sum()),)]
+
+
+def oracle_q01(conn):
+    """Grouped sums over the dictionary codes (StringDict is sorted, so
+    code order == ORDER BY 1, 2)."""
+    import numpy as np
+    t = conn.table("lineitem")
+    n = int(t.num_rows)
+    keep = t.arrays["l_shipdate"][:n] <= _days(1998, 9, 2)
+    qty = t.arrays["l_quantity"][:n][keep]
+    eprice = t.arrays["l_extendedprice"][:n][keep]
+    disc = t.arrays["l_discount"][:n][keep]
+    tax = t.arrays["l_tax"][:n][keep]
+    key = (t.arrays["l_returnflag"][:n][keep].astype(np.int64) * 64
+           + t.arrays["l_linestatus"][:n][keep])
+    uniq, inv = np.unique(key, return_inverse=True)
+    cnt = np.bincount(inv)
+    disc_price = eprice * (1 - disc)
+    sums = [np.bincount(inv, weights=w)
+            for w in (qty, eprice, disc_price, disc_price * (1 + tax),
+                      disc)]
+    return [
+        (t.dicts["l_returnflag"][int(k) // 64],
+         t.dicts["l_linestatus"][int(k) % 64],
+         sums[0][i], sums[1][i], sums[2][i], sums[3][i],
+         sums[0][i] / cnt[i], sums[1][i] / cnt[i], sums[4][i] / cnt[i],
+         int(cnt[i]))
+        for i, k in enumerate(uniq)]
+
+
+def oracle_q03(conn):
+    from oracle import table_df
+    cutoff = _days(1995, 3, 15)
+    c = table_df(conn, "customer", ["c_custkey", "c_mktsegment"])
+    o = table_df(conn, "orders", ["o_orderkey", "o_custkey",
+                                  "o_orderdate", "o_shippriority"])
+    li = table_df(conn, "lineitem", ["l_orderkey", "l_extendedprice",
+                                     "l_discount", "l_shipdate"])
+    c = c[c.c_mktsegment == "BUILDING"]
+    o = o[o.o_orderdate < cutoff]
+    li = li[li.l_shipdate > cutoff]
+    j = (li.merge(o, left_on="l_orderkey", right_on="o_orderkey")
+         .merge(c, left_on="o_custkey", right_on="c_custkey"))
+    j["revenue"] = j.l_extendedprice * (1 - j.l_discount)
+    g = (j.groupby(["l_orderkey", "o_orderdate", "o_shippriority"],
+                   as_index=False).revenue.sum()
+         .sort_values(["revenue", "o_orderdate"], ascending=[False, True])
+         .head(10))
+    return [(int(r.l_orderkey), float(r.revenue), int(r.o_orderdate),
+             int(r.o_shippriority)) for r in g.itertuples()]
+
+
+ORACLES = {6: oracle_q06, 1: oracle_q01, 3: oracle_q03}
+
+
+def rows_exact(got, want) -> str:
+    """'' when the served rows equal the oracle's, in order (floats to the
+    repo's own 1e-6 relative tolerance: the sums run in another order),
+    else the first difference."""
+    from oracle import assert_rows_match
+    try:
+        assert_rows_match([tuple(r) for r in got], want)
+    except AssertionError as e:
+        return str(e)
+    return ""
+
+
+# ------------------------------------------------------------------ phases
+
+def rebuild_native_codec() -> bool:
+    """Drop any libpagecodec.so left on disk (git-ignored, so it is not
+    what a checkout holds) and let load() rebuild it from page_codec.cc.
+    Raises when a compiler exists and the library still does not load."""
+    from presto_tpu import native
+    lib_path = os.path.join(os.path.dirname(native.__file__),
+                            "libpagecodec.so")
+    if os.path.exists(lib_path):
+        os.unlink(lib_path)
+    loaded = native.load() is not None
+    if not loaded and shutil.which("g++"):
+        raise RuntimeError("g++ exists but presto_tpu.native.load() gave "
+                           "no C++ page codec")
+    return loaded
+
+
+def run_queries(sf: float, counter: CompileCounter) -> bool:
+    """The served path at scale `sf`; prints one line per query and one
+    of run facts. Returns whether every query was exact."""
+    import jax
+    from tpch_queries import QUERIES
+
+    from presto_tpu.connectors import TpchConnector
+    from presto_tpu.server.cluster import TpuCluster
+    from presto_tpu.server.statement import StatementServer, run_statement
+
+    t0 = time.perf_counter()
+    conn = TpchConnector(sf)
+    table_rows = {t: int(conn.table(t).num_rows)
+                  for t in ("lineitem", "orders", "customer")}
+    generation_s = time.perf_counter() - t0
+
+    served = {}
+    cluster = TpuCluster(conn)
+    try:
+        srv = StatementServer(cluster).start()
+        try:
+            for qid in QIDS:
+                entry = {}
+                for phase in ("cold", "warm"):
+                    before = counter.compiled, counter.seconds
+                    t0 = time.perf_counter()
+                    _cols, rows = run_statement(srv.base, QUERIES[qid])
+                    entry[f"{phase}_s"] = time.perf_counter() - t0
+                    entry[f"{phase}_compilations"] = (counter.compiled
+                                                      - before[0])
+                    entry[f"{phase}_compile_s"] = (counter.seconds
+                                                   - before[1])
+                    entry[f"{phase}_rows"] = rows
+                served[qid] = entry
+        finally:
+            srv.stop()
+    finally:
+        cluster.stop()
+
+    all_exact = True
+    for qid in QIDS:
+        entry = served[qid]
+        want = ORACLES[qid](conn)
+        diff = (rows_exact(entry["cold_rows"], want)
+                or rows_exact(entry["warm_rows"], want))
+        all_exact &= not diff
+        line = {"query": f"q{qid:02d}", "sf": sf,
+                "cold_s": entry["cold_s"], "warm_s": entry["warm_s"],
+                "rows": len(entry["warm_rows"]), "exact": not diff,
+                "compilations": {"cold": entry["cold_compilations"],
+                                 "warm": entry["warm_compilations"]},
+                "compile_thread_s": {"cold": entry["cold_compile_s"],
+                                     "warm": entry["warm_compile_s"]}}
+        if diff:
+            line["mismatch"] = diff[:300]
+        _say(**line)
+
+    stats = jax.devices()[0].memory_stats() or {}
+    _say(generation_s=generation_s, table_rows=table_rows,
+         peak_device_bytes=stats.get("peak_bytes_in_use"),
+         device_bytes_limit=stats.get("bytes_limit"),
+         compile_requests=counter.requests,
+         persistent_cache_hits=counter.hits)
+    return all_exact
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--sf", type=float, default=1.0,
+                    help="TPC-H scale factor (the CPU rehearsal uses 0.01)")
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="CPU rehearsal only: do not require a TPU")
+    args = ap.parse_args(argv)
+
+    for p in (REPO, os.path.join(REPO, "tests")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    caps_existed = os.path.exists(os.path.join(REPO, ".caps_cache.json"))
+
+    import jax
+
+    import presto_tpu  # noqa: F401 — x64 and the compile cache
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    cache_existed = bool(cache_dir and os.path.isdir(cache_dir)
+                         and os.listdir(cache_dir))
+    counter = _compile_counter()
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if dev.platform != "tpu" and not args.allow_cpu:
+        print(f"chip_smoke: needs a TPU, JAX found {device}",
+              file=sys.stderr)
+        return 2
+
+    _say(device=device, sf=args.sf,
+         caps_cache_existed=caps_existed,
+         compile_cache_dir=cache_dir,
+         compile_cache_existed=cache_existed,
+         native_codec=rebuild_native_codec())
+    if not run_queries(args.sf, counter):
+        print("chip_smoke: a served result differs from its oracle",
+              file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -764,8 +764,7 @@ def compact(page: Page, keep: jnp.ndarray) -> Page:
     Implemented as ONE 2-operand argsort on the order key + per-column
     gathers: on this stack gathers compile in under a second and run at
     memory bandwidth, while a lax.sort carrying every column as a payload
-    operand multiplies compile cost with column count (wide variadic
-    sorts are what OOM the remote compile service on join plans).
+    operand multiplies compile cost with column count.
 
     Reference semantics: PageProcessor's filter
     (presto-main-base/.../operator/project/PageProcessor.java:56), re-expressed

@@ -35,7 +35,7 @@ METRIC_NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 LABEL_NAME_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
 
 #: wall-time seconds buckets: ~1ms .. ~2min covers everything from one
-#: fused-kernel dispatch to a cold remote-TPU compile
+#: fused-kernel dispatch to a cold TPU compile
 DEFAULT_TIME_BUCKETS_S = (0.001, 0.005, 0.025, 0.1, 0.5, 2.5, 10.0,
                           30.0, 120.0)
 #: row-count buckets: decade-ish spacing from tiny dimension tables to

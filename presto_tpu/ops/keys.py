@@ -136,7 +136,7 @@ def lex_perm(lanes: Sequence[jnp.ndarray]) -> jnp.ndarray:
     significant first, each ascending), via composed STABLE argsorts —
     2-operand sorts only. On this stack a wide variadic lax.sort's
     compile cost explodes with operand count (20 operands at SF1 shapes
-    never finishes compiling through the remote compile service), while
+    never finished compiling), while
     argsort + gather compiles in seconds per lane and gathers run at
     memory bandwidth; every operator therefore sorts via this helper and
     gathers its payload by the permutation."""

@@ -31,7 +31,8 @@ from presto_tpu.ops.aggregate import AggSpec, grouped_aggregate
 from presto_tpu.ops.join import hash_join
 from presto_tpu.parallel.mesh import AXIS, run_sharded
 from presto_tpu.parallel.shuffle import (
-    all_gather_page, partition_ids, partition_ids_cols, repartition_page,
+    all_gather_page, mesh_max, partition_ids, partition_ids_cols,
+    repartition_page,
 )
 from presto_tpu.types import BIGINT, DOUBLE
 
@@ -237,6 +238,13 @@ def gather_page_global(page: Page, ndev: int, axis: str = AXIS) -> Page:
 # Host-level wrappers over stacked sharded pages (tests / entry points).
 # ---------------------------------------------------------------------------
 
+def _needed_max(needed) -> tuple:
+    """The mesh-wide maximum of each "needed" counter, as int64 scalars
+    replicated on every device (one gather for the whole tuple)."""
+    m = mesh_max(jnp.stack([jnp.asarray(n, jnp.int64) for n in needed]))
+    return tuple(m[i] for i in range(len(needed)))
+
+
 def dist_aggregate(mesh, stacked: Page, group_fields: Sequence[int],
                    aggs: Sequence[AggSpec], partial_capacity: int,
                    out_capacity: int) -> Tuple[Page, tuple]:
@@ -245,8 +253,7 @@ def dist_aggregate(mesh, stacked: Page, group_fields: Sequence[int],
     def fn(local: Page):
         out, needed = dist_aggregate_local(local, group_fields, aggs, ndev,
                                            partial_capacity, out_capacity)
-        return out, tuple(jax.lax.pmax(jnp.asarray(n, jnp.int64), AXIS)
-                          for n in needed)
+        return out, _needed_max(needed)
 
     return run_sharded(mesh, fn, stacked, with_needed=True)
 
@@ -268,8 +275,7 @@ def dist_hash_join(mesh, probe_stacked: Page, build_stacked: Page,
             out, needed = dist_hash_join_local(
                 p, b, probe_fields, build_fields, ndev, out_capacity,
                 join_type, probe_recv_capacity, build_recv_capacity)
-        return out, tuple(jax.lax.pmax(jnp.asarray(n, jnp.int64), AXIS)
-                          for n in needed)
+        return out, _needed_max(needed)
 
     return run_sharded(mesh, fn, probe_stacked, build_stacked,
                        with_needed=True)

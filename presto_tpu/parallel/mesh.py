@@ -22,16 +22,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from presto_tpu.data.column import Page
 
-# jax.shard_map (with check_vma) landed after 0.4.x; older releases ship
-# it as jax.experimental.shard_map.shard_map with the kwarg spelled
-# check_rep. Same semantics either way: unchecked replication.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KWARGS = {"check_vma": False}
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KWARGS = {"check_rep": False}
-
 AXIS = "d"
 
 
@@ -100,9 +90,9 @@ def run_sharded(mesh: Mesh, fn: Callable, *stacked_args,
         out_specs = P()
     else:
         out_specs = P(AXIS)
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         wrapper, mesh=mesh,
         in_specs=tuple(P(AXIS) for _ in stacked_args),
         out_specs=out_specs,
-        **_CHECK_KWARGS)
+        check_vma=False)
     return shmapped(*stacked_args)

@@ -29,6 +29,15 @@ from presto_tpu.ops.keys import hash_columns
 from presto_tpu.parallel.mesh import AXIS
 
 
+def mesh_max(x: jnp.ndarray, axis: str = AXIS) -> jnp.ndarray:
+    """Elementwise maximum of `x` over the mesh axis, replicated on every
+    device. Gather-then-max, not `lax.pmax`: for 64-bit integers the TPU
+    compiler lowers only Sum all-reduces, while a 64-bit all_gather
+    lowers. Callers reduce a handful of counters, so the gather moves a
+    few bytes."""
+    return jnp.max(jax.lax.all_gather(x, axis), axis=0)
+
+
 def partition_ids(page: Page, key_fields: Sequence[int], ndev: int
                   ) -> jnp.ndarray:
     """Hash-partition id per row in [0, ndev); padding rows get ndev.

