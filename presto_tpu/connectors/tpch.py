@@ -29,9 +29,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from presto_tpu.data.column import Column, Page, StringDict, bucket_capacity
+from presto_tpu.data.column import (Column, Page, StringDict,
+                                    bucket_capacity, page_nbytes)
 from presto_tpu.expr.compile import days_from_civil
 from presto_tpu.types import BIGINT, DATE, DOUBLE, INTEGER, VARCHAR, Type
+from presto_tpu.utils.tracing import TRACER
 
 # ---------------------------------------------------------------------------
 # schema
@@ -225,6 +227,11 @@ class HostTable:
         # per call.
         cache = self.__dict__.setdefault("_dev_page_cache", {})
         out = []
+        held = [cache[c, cap] for c in cols if (c, cap) in cache]
+        if held:
+            # what the device already holds is not moved: the `upload`
+            # span around this call counts the rest
+            TRACER.add("upload", resident=page_nbytes(held))
         for c in cols:
             key = (c, cap)
             col = cache.get(key)

@@ -704,6 +704,22 @@ def concat_pages_host(pages: Sequence[Page],
     return Page.from_columns(cols, total, first.names)
 
 
+def page_nbytes(page: Page) -> int:
+    """Bytes of a page's arrays at their capacity: what a transfer of
+    the page moves."""
+    return sum(int(getattr(a, "nbytes", 0))
+               for a in jax.tree_util.tree_leaves(page))
+
+
+def page_to_host(page: Page) -> None:
+    """Fetch every array of a device page. jax keeps the host copy with
+    the array, so the conversions that follow (`to_numpy`, the wire
+    blocks) find it there: the device->host step of an output page is
+    paid here, once, apart from partitioning and serialization."""
+    for a in jax.tree_util.tree_leaves(page):
+        np.asarray(a)
+
+
 def select_page_host(page: Page, idx: np.ndarray) -> Page:
     """Host-side row selection (numpy take) keeping dictionaries — the
     producer side of partitioned output (PartitionedOutputOperator.java:57

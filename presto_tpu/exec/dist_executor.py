@@ -205,8 +205,11 @@ class DistExecutor(Executor):
         from presto_tpu.exec.executor import RemoteSpec
         if isinstance(s, RemoteSpec):
             return self._frag_results[s.fragment_id]
+        return super()._fetch(s)
+
+    def _scan_page(self, s) -> Page:
         if self.ndev == 1:
-            return super()._fetch(s)
+            return super()._scan_page(s)
         pages = [self.connector.table(s.table, part=d,
                                       num_parts=self.ndev)
                  .page(columns=list(s.columns), capacity=s.capacity)
@@ -384,13 +387,10 @@ class DistSplitExecutor(DistExecutor):
             return super()._scan_rows(node)
         return max(max(t.num_rows for t in ts), 1)
 
-    def _fetch(self, s) -> Page:
-        from presto_tpu.exec.executor import RemoteSpec
-        ts = None
-        if not isinstance(s, RemoteSpec) and hasattr(s, "table"):
-            ts = self._split_tables(s.table)
+    def _scan_page(self, s) -> Page:
+        ts = self._split_tables(s.table)
         if ts is None:
-            return super()._fetch(s)
+            return super()._scan_page(s)
         pages = [t.page(columns=list(s.columns), capacity=s.capacity)
                  for t in ts]
         return pages[0] if self.ndev == 1 else stack_pages(pages)

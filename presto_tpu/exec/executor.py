@@ -24,7 +24,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from presto_tpu.data.column import Column, Page, bucket_capacity, compact
+from presto_tpu.data.column import (
+    Column, Page, bucket_capacity, compact, page_nbytes,
+)
 from presto_tpu.expr.compile import compile_expr
 from presto_tpu.expr.nodes import (
     Call, InputRef, Literal, RowExpression, SpecialForm,
@@ -36,6 +38,7 @@ from presto_tpu.obs.metrics import (
 from presto_tpu.ops.aggregate import grouped_aggregate
 from presto_tpu.ops.join import hash_join, merge_join
 from presto_tpu.ops.sort import limit_page, sort_page, top_n
+from presto_tpu.utils.tracing import TRACER, now
 from presto_tpu.plan.nodes import (
     AggregationNode, AssignUniqueIdNode, ExchangeNode, FilterNode,
     GroupIdNode, JoinNode, JoinType, LimitNode, OutputNode, PlanNode,
@@ -114,6 +117,24 @@ class MemoryLimitExceeded(Exception):
             f"limit is {limit // (1 << 20)} MiB")
         self.estimated = estimated
         self.limit = limit
+
+
+def _kind(node: PlanNode) -> str:
+    """A plan node's operator name: its class less the `Node`."""
+    return type(node).__name__.replace("Node", "")
+
+
+def _operators(plan: PlanNode) -> List[str]:
+    """The operator kinds inside one program, each once, root first."""
+    kinds: Dict[str, None] = {}
+
+    def walk(n):
+        if n is not None:
+            kinds.setdefault(_kind(n))
+            for c in n.children():
+                walk(c)
+    walk(plan)
+    return list(kinds)
 
 
 def _row_bytes(types) -> int:
@@ -317,13 +338,15 @@ class Executor:
                     # times — the per-operator profile fused execution
                     # cannot produce (profiling trades away the async
                     # overlap, production runs keep it)
-                    import time as _t
-                    t0 = _t.perf_counter()
+                    t0 = now()
                     out = self._execute_fused(mini)
-                    jax.block_until_ready(out)   # Page is a pytree
+                    with TRACER.span(None, "device_wait",
+                                     sync="per_island"):
+                        jax.block_until_ready(out)   # Page is a pytree
                     entry = {
-                        "root": type(node).__name__.replace("Node", ""),
-                        "seconds": _t.perf_counter() - t0,
+                        "root": _kind(node),
+                        "t0": t0,
+                        "seconds": now() - t0,
                         "rows": int(out.num_rows),
                         "memory_bytes": self.last_memory_estimate,
                     }
@@ -370,6 +393,10 @@ class Executor:
         time budget stays enforced (the chain dispatches in
         milliseconds, so this wait is where the compute time actually
         passes)."""
+        with TRACER.span(None, "device_wait", sync="chain"):
+            return self._sync_counters(pendings)
+
+    def _sync_counters(self, pendings):
         import numpy as _np
         if getattr(self, "_deadline", None) is None:
             return [_np.asarray(p["needed"]) for p in pendings]
@@ -431,6 +458,14 @@ class Executor:
                 getattr(self.connector, "sf", None), tuple(sizes))
         return hashlib.sha1(
             (repr(salt) + repr(plan)).encode()).hexdigest()[:24]
+
+    def program_name(self, plan: PlanNode) -> str:
+        """What the device trace calls the island's program
+        (`jit_<this>` on the modules' line): its root operator and the
+        plan fingerprint the learned capacities are keyed by. Nothing
+        of the query, task or run enters, so the name, which is part of
+        XLA's cache key, repeats wherever the program does."""
+        return f"presto_{_kind(plan)}_{self._plan_fingerprint(plan)[:8]}"
 
     @staticmethod
     def _walk_scans(plan):
@@ -543,16 +578,29 @@ class Executor:
         key = (plan, tuple(sorted(caps.items(), key=repr)),
                bool(self.session["collect_stats"]))
         entry = self._compiled.get(key)
-        if entry is None:
+        first_call = entry is None
+        if first_call:
             # stats_box is filled at this entry's first execution
             # (trace time fixes the node-id order for its lifetime).
-            entry = (jax.jit(self._wrap(fn)), scans, watch, [])
+            program = self._wrap(fn)
+            program.__name__ = program.__qualname__ = \
+                self.program_name(plan)
+            # what its `dispatch` spans say of the program ("+" joins
+            # the operators: a comma would end the value in the
+            # profiler's encoding of an annotation's metadata)
+            about = {"program": "jit_" + program.__name__,
+                     "root": _kind(plan),
+                     "operators": "+".join(_operators(plan))}
+            entry = (jax.jit(program), scans, watch, [], about)
             self._compiled[key] = entry
             self._note_compile(plan)
-        fn, scans, watch, stats_box = entry
+        fn, scans, watch, stats_box, about = entry
         pages = [self._fetch(s) for s in scans]
         self._stats_ids = []
-        out, needed = fn(pages)
+        # on a new executor the first call is Python trace + lowering +
+        # compile or cache read + enqueue; later calls only enqueue
+        with TRACER.span(None, "dispatch", first_call=first_call, **about):
+            out, needed = fn(pages)
         if self._stats_ids and not stats_box:
             stats_box.extend(self._stats_ids)
         pending = {"plan": plan, "caps": caps, "watch": watch,
@@ -621,7 +669,11 @@ class Executor:
         """Sync + resolve one dispatched program (the single-program
         path): returns True when a re-run is required."""
         import numpy as _np
-        needed = _np.asarray(pending["needed"])   # the sync point
+        # one program, one wait: under collect_stats that is a wait an
+        # island (the profiled branch of _execute_islands comes here)
+        sync = "per_island" if self.session["collect_stats"] else "chain"
+        with TRACER.span(None, "device_wait", sync=sync):
+            needed = _np.asarray(pending["needed"])   # the sync point
         if self._grow_caps(pending, needed):
             return True
         self._finish_counters(pending, needed)
@@ -693,6 +745,15 @@ class Executor:
     def _fetch(self, s) -> Page:
         if isinstance(s, PageInputSpec):
             return self._island_inputs[s.slot]
+        # host -> device: the connector's arrays (or the task's splits
+        # of them) become one device page
+        with TRACER.span(None, "upload", table=s.table) as sp:
+            page = self._scan_page(s)
+            sp.attributes["bytes"] = page_nbytes(page) \
+                - sp.attributes.get("resident", 0)
+        return page
+
+    def _scan_page(self, s: ScanSpec) -> Page:
         t = self.connector.table(s.table)
         return t.page(columns=list(s.columns), capacity=s.capacity)
 
@@ -794,10 +855,14 @@ class Executor:
             mem_bytes[0] += cap * _row_bytes(node.output_types)
             self._node_map[nid_stats] = (node, cap)
 
-            def cached(pages, fn=fn, key=key, nid=nid_stats):
+            def cached(pages, fn=fn, key=key, nid=nid_stats,
+                       kind=_kind(node)):
                 if key in run_cache:
                     return run_cache[key]
-                out = fn(pages)
+                # traced here: the operations' metadata carries the
+                # operator (and, nested, the operators it feeds)
+                with jax.named_scope(kind):
+                    out = fn(pages)
                 if collect_stats:
                     _node_rows.append((nid, out.num_rows))
                 run_cache[key] = out

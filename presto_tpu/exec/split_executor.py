@@ -68,14 +68,15 @@ class SplitExecutor(Executor):
     def _fetch(self, s) -> Page:
         if isinstance(s, RemotePageSpec):
             return self.remote_pages[s.node_id]
-        if not hasattr(s, "table"):       # island PageInputSpec
-            return super()._fetch(s)
+        return super()._fetch(s)
+
+    def _scan_page(self, s: ScanSpec) -> Page:
         t = self.split_tables.get(s.table)
         if t is not None:
             return t.page(columns=list(s.columns), capacity=s.capacity)
         parts = self.splits.get(s.table)
         if parts is None:
-            return super()._fetch(s)
+            return super()._scan_page(s)
         tables = [self.connector.table(s.table, part=p, num_parts=n)
                   for p, n in parts]
         n_rows = sum(t.num_rows for t in tables)

@@ -39,6 +39,7 @@ from presto_tpu.obs.metrics import (
 )
 from presto_tpu.protocol.exchange_client import PageStream, decode_pages
 from presto_tpu.utils.threads import spawn
+from presto_tpu.utils.tracing import TRACER, now
 
 _M_BUF_BYTES_HIGH = _gauge(
     "presto_tpu_exchange_buffered_bytes_high_water",
@@ -114,6 +115,13 @@ class ExchangeClient:
         #: the process-wide max)
         self.buffered_bytes_high_water = 0
         self.buffer_depth_high_water = 0
+        #: wire bytes landed so far (the `bytes` of the consumer's
+        #: `exchange_wait` and `collect_root` spans)
+        self.bytes_pulled = 0
+        # the trace and span this client pulls for: the fetcher threads
+        # record their pulls and decodes under it (they install no trace
+        # scope, so their GETs stay off the query's RPC timeline)
+        self._trace = TRACER.here()
         # at most this many GETs in flight across all streams; the
         # permit wraps ONLY the network fetch, never the buffer wait —
         # a parked fetcher must not starve other streams of permits
@@ -138,12 +146,14 @@ class ExchangeClient:
                 if self._permits is not None:
                     self._permits.acquire()
                 try:
+                    t0 = now()
                     data = stream.fetch()
                 finally:
                     if self._permits is not None:
                         self._permits.release()
                 if data:
-                    payload = (decode_pages(data, self.types)
+                    self._pulled(t0, len(data))
+                    payload = (self._decode(data)
                                if self.types is not None else data)
                     if not self._offer(len(data), payload):
                         return
@@ -158,6 +168,24 @@ class ExchangeClient:
             with self._cond:
                 self._open_streams -= 1
                 self._cond.notify_all()
+
+    def _pulled(self, start: float, nbytes: int) -> None:
+        """An `exchange_pull` span for a GET that landed data, recorded
+        when it ends: a long poll that comes back empty was a wait for
+        the producer, not a pull (the consumer's `exchange_wait` holds
+        it), and which of the two a GET is shows only in its answer."""
+        here = self._trace
+        TRACER.record(here.trace_id if here else None, "exchange_pull",
+                      start, now(),
+                      parent_id=here.parent_span_id if here else "",
+                      mark=True, bytes=nbytes)
+
+    def _decode(self, data: bytes) -> List:
+        here = self._trace
+        with TRACER.span(here.trace_id if here else None, "deserialize",
+                         parent_id=here.parent_span_id if here else None,
+                         bytes=len(data)):
+            return decode_pages(data, self.types)
 
     def _offer(self, nbytes: int, payload) -> bool:
         """Land one chunk in the buffer, parking while it is full.
@@ -177,6 +205,7 @@ class ExchangeClient:
                 return False
             self._buf.append((nbytes, payload))
             self._buffered_bytes += nbytes
+            self.bytes_pulled += nbytes
             if self._buffered_bytes > self.buffered_bytes_high_water:
                 self.buffered_bytes_high_water = self._buffered_bytes
             if len(self._buf) > self.buffer_depth_high_water:
@@ -274,7 +303,8 @@ def stream_pages(location: str, buffer_id: str = "0", types=None, *,
             if types is None:
                 yield data
             else:
-                for p in decode_pages(data, list(types)):
-                    yield p
+                with TRACER.span(None, "deserialize", bytes=len(data)):
+                    pages = decode_pages(data, list(types))
+                yield from pages
     finally:
         stream.close()

@@ -21,13 +21,17 @@ SURVEY.md §5.8)."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import logging
 import random
 import threading
 import time
+import uuid
 from typing import Dict, List, Optional, Tuple
 
+from presto_tpu.admission.groups import current_admission
 from presto_tpu.config import DEFAULT_OBS, TransportConfig
 from presto_tpu.obs.metrics import counter as _obs_counter, \
     gauge as _obs_gauge
@@ -37,7 +41,7 @@ from presto_tpu.plan.stats import (
     HistoryStore, canonical_key, default_history_path, estimate_rows,
 )
 from presto_tpu.utils.threads import spawn
-from presto_tpu.utils.tracing import TRACER, trace_scope
+from presto_tpu.utils.tracing import TRACER, root_scope, trace_scope
 from presto_tpu.plan.nodes import ExchangeNode, Partitioning, PlanNode
 from presto_tpu.protocol import structs as S
 from presto_tpu.protocol.exchange import (
@@ -428,6 +432,7 @@ class TpuCluster:
         self.affinity = AffinityRouter()
         self.all_worker_uris = [f"http://127.0.0.1:{w.port}"
                                 for w in self.workers]
+        self._inprocess_uris = frozenset(self.all_worker_uris)
         self.dead: set = set()
         # graceful-decommission set: workers that reported SHUTTING_DOWN
         # (or answered a task POST with the draining 410). They leave
@@ -723,13 +728,27 @@ class TpuCluster:
     # ------------------------------------------------------------------
     def plan_sql(self, sql: str) -> PlanNode:
         from presto_tpu.sql.parser import parse_sql
-        if sql not in self._plans:
-            self._plans[sql] = self.planner.plan_query(parse_sql(sql))
-        return self._plans[sql]
+        with TRACER.span(None, "plan", plan_cache_hit=sql in self._plans):
+            if sql not in self._plans:
+                self._plans[sql] = self.planner.plan_query(parse_sql(sql))
+            return self._plans[sql]
 
     def execute_sql(self, sql: str,
                     _capture: bool = False,
                     cancel_event=None) -> List[tuple]:
+        with self._lock:
+            self._query_counter += 1
+            qid = f"cluster_q{self._query_counter}"
+        # one trace a statement: under the statement server the scope is
+        # already open and this keeps it; called directly, the statement
+        # starts here (the id is salted: cluster query ids repeat across
+        # the clusters of one process, the tracer is process-wide)
+        with root_scope(f"{qid}_{uuid.uuid4().hex[:8]}",
+                        DEFAULT_OBS.sampled(random.random())):
+            return self._execute_sql(qid, sql, _capture, cancel_event)
+
+    def _execute_sql(self, qid: str, sql: str, _capture: bool,
+                     cancel_event) -> List[tuple]:
         from presto_tpu.utils.tracing import query_lifecycle
 
         # plugin access control: the cluster is the network-exposed
@@ -743,14 +762,13 @@ class TpuCluster:
             plan_full=lambda: self.plan_sql(sql),
             plan_query=self.planner.plan_query)
 
-        with self._lock:
-            self._query_counter += 1
-            qid = f"cluster_q{self._query_counter}"
         # wide-event query log: exactly ONE event per cluster query id,
         # success or failure — recovery retries happen INSIDE the body,
         # so they can never duplicate it (obs/wide_events.py)
         from presto_tpu.obs import wide_events as _wide
-        pre = _wide.pre_query_snapshot(self)
+        with TRACER.span(None, "telemetry"):
+            pre = _wide.pre_query_snapshot(self)
+            self._scrape_telemetry((), force=True)
         # bracket the query with LOCAL-ONLY telemetry sweeps so
         # metrics_history holds a before/after pair for every
         # coordinator-side counter the query moved (transport,
@@ -758,7 +776,6 @@ class TpuCluster:
         # running; worker registries ride the heartbeat cadence —
         # fetching them here would add one HTTP round-trip per worker
         # to every query
-        self._scrape_telemetry((), force=True)
         try:
             with query_lifecycle(qid, sql) as box:
                 group = self.resource_groups.select(
@@ -767,7 +784,11 @@ class TpuCluster:
                 # when the statement front door already admitted this
                 # query (dispatcher pool thread), acquire returns a no-op
                 # nested slot — admission happens once per statement
-                slot = group.acquire(timeout_s=600, query_id=qid)
+                with (contextlib.nullcontext()
+                      if current_admission() is not None
+                      else TRACER.span(None, "admission_wait",
+                                       group=group.path)):
+                    slot = group.acquire(timeout_s=600, query_id=qid)
                 self.last_admission = {
                     "group": slot.group.path,
                     "queue_wait_s": slot.queue_wait_s or 0.0}
@@ -791,12 +812,14 @@ class TpuCluster:
                             self.plan_sql(sql), capture=_capture,
                             cancel_event=cancel_event)
         except Exception as e:
-            _wide.emit_wide_event(self, qid, sql, rows=None,
-                                  error=str(e), pre=pre)
+            with TRACER.span(None, "telemetry"):
+                _wide.emit_wide_event(self, qid, sql, rows=None,
+                                      error=str(e), pre=pre)
             raise
-        _wide.emit_wide_event(self, qid, sql, rows=box[0], error=None,
-                              pre=pre)
-        self._scrape_telemetry((), force=True)
+        with TRACER.span(None, "telemetry"):
+            _wide.emit_wide_event(self, qid, sql, rows=box[0], error=None,
+                                  pre=pre)
+            self._scrape_telemetry((), force=True)
         return box[0]
 
     @property
@@ -1088,17 +1111,23 @@ class TpuCluster:
 
     # ---------------------------------------------------------- tracing
     def _scrape_worker_traces(self, trace_id: str) -> None:
-        """GET /v1/trace/{id} from every worker and stitch the spans
-        into the coordinator tracer (span_id dedupe makes this a no-op
-        for in-process workers, which share the process tracer)."""
-        for uri in self.worker_uris:
-            try:
-                doc = self.http.get_json(f"{uri}/v1/trace/{trace_id}",
-                                         request_class="control")
-                TRACER.merge_remote(trace_id, doc)
-            except Exception:   # noqa: BLE001 — tracing is best-effort
-                log.debug("trace scrape failed for %s", uri,
-                          exc_info=True)
+        """GET /v1/trace/{id} from every worker of another process and
+        stitch the spans into the coordinator tracer. In-process workers
+        share the process tracer: their spans are already there."""
+        remote = [u for u in self.worker_uris
+                  if u not in self._inprocess_uris]
+        if not remote:
+            return
+        with TRACER.span(trace_id, "telemetry", workers=len(remote)):
+            for uri in remote:
+                try:
+                    doc = self.http.get_json(
+                        f"{uri}/v1/trace/{trace_id}",
+                        request_class="control")
+                    TRACER.merge_remote(trace_id, doc)
+                except Exception:   # noqa: BLE001 — best-effort
+                    log.debug("trace scrape failed for %s", uri,
+                              exc_info=True)
 
     def render_trace(self, query_id: Optional[str] = None) -> str:
         """One cross-node timeline for `query_id` (default: the most
@@ -1178,7 +1207,33 @@ class TpuCluster:
         # final aggregation orders float summation differently, and a
         # literal produced by a different pipeline would break exact
         # comparisons like Q15's total_revenue = (select max(...)).
+        # (outside the `plan` span: each runs a `query` of its own)
         plan = _ClusterSubqueryExec(self)._resolve_subqueries(plan)
+        with TRACER.span(None, "plan") as plan_span:
+            plan, h0, frags, merge_keys, mesh_plan = \
+                self._fragment_plan(plan, writer_tasks)
+            plan_span.attributes["fragments"] = len(frags)
+        try:
+            return self._run_fragments(frags, list(plan.output_types),
+                                       capture=capture,
+                                       merge_keys=merge_keys,
+                                       cancel_event=cancel_event,
+                                       writer_tasks=writer_tasks,
+                                       mesh_plan=mesh_plan)
+        finally:
+            # planning-time HBO consultation delta for this query
+            # (EXPLAIN ANALYZE's "HBO:" line)
+            if h0 is not None:
+                self.last_hbo = {
+                    "hits": self.history.hits - h0[0],
+                    "misses": self.history.misses - h0[1]}
+            else:
+                self.last_hbo = {"hits": 0, "misses": 0}
+
+    def _fragment_plan(self, plan: PlanNode, writer_tasks):
+        """From the logical plan to the fragments to schedule: join
+        reordering, exchanges, the cut into fragments, and the mesh
+        tier's offer to fuse them."""
         from presto_tpu.config import PROPERTIES, Session
         known = {p.name for p in PROPERTIES}
         session = Session({k: v for k, v in
@@ -1211,22 +1266,7 @@ class TpuCluster:
             frags = [PlanFragment(0, _unshare(plan),
                                   Partitioning.SINGLE, ())]
             merge_keys = None
-        try:
-            return self._run_fragments(frags, list(plan.output_types),
-                                       capture=capture,
-                                       merge_keys=merge_keys,
-                                       cancel_event=cancel_event,
-                                       writer_tasks=writer_tasks,
-                                       mesh_plan=mesh_plan)
-        finally:
-            # planning-time HBO consultation delta for this query
-            # (EXPLAIN ANALYZE's "HBO:" line)
-            if h0 is not None:
-                self.last_hbo = {
-                    "hits": self.history.hits - h0[0],
-                    "misses": self.history.misses - h0[1]}
-            else:
-                self.last_hbo = {"hits": 0, "misses": 0}
+        return plan, h0, frags, merge_keys, mesh_plan
 
     # ------------------------------------------------------------------
     def _run_fragments(self, frags, out_types,
@@ -1236,6 +1276,28 @@ class TpuCluster:
         with self._lock:
             self._query_counter += 1
             qid = f"q{self._query_counter}_{int(time.time())}"
+        run = functools.partial(
+            self._run_fragments_scoped, qid, frags, out_types, capture,
+            merge_keys, writer_tasks, cancel_event, mesh_plan)
+        with root_scope(qid, DEFAULT_OBS.sampled(random.random())) as ctx:
+            if ctx is None:
+                return run()
+            # sampled query: the coordinator opens the root span, the
+            # trace_scope makes every RPC this scheduling thread issues
+            # carry X-Presto-Trace with the root span as parent, and
+            # span dumps of workers in other processes are scraped back
+            # at query end into one stitched timeline
+            self.last_trace_id = ctx.trace_id
+            with TRACER.span(ctx.trace_id, "query", worker="coordinator",
+                             fragments=len(frags)) as root:
+                with trace_scope(ctx.trace_id, root.span_id):
+                    rows = run()
+            self._scrape_worker_traces(ctx.trace_id)
+            return rows
+
+    def _run_fragments_scoped(self, qid, frags, out_types, capture,
+                              merge_keys, writer_tasks, cancel_event,
+                              mesh_plan) -> List[tuple]:
         by_id = {f.fragment_id: f for f in frags}
 
         consumers: Dict[int, List[int]] = {}
@@ -1257,8 +1319,9 @@ class TpuCluster:
         placement = list(self.worker_uris)
         W = len(placement)
         self.last_membership = self.membership_snapshot()
-        specs = {f.fragment_id: fragment_to_protocol(f, self.connector)
-                 for f in frags}
+        with TRACER.span(None, "schedule", stages=len(frags)):
+            specs = {f.fragment_id: fragment_to_protocol(f, self.connector)
+                     for f in frags}
 
         stages: Dict[int, _Stage] = {}
 
@@ -1347,6 +1410,17 @@ class TpuCluster:
             self._start_stage(qid, fid, stages, by_id, stage_placement)
             scheduled.add(fid)
 
+        def schedule_all():
+            with TRACER.span(None, "schedule", stages=len(stages),
+                             tasks=sum(st.n_tasks
+                                       for st in stages.values())):
+                schedule(0)
+
+        def await_all():
+            with TRACER.span(None, "await_tasks"):
+                self._await_all(stages, cancel_event=cancel_event,
+                                query_id=qid)
+
         batch_mode = (str(self.session_properties.get(
             "exchange_materialization_enabled", ""))
             .strip().lower() == "true")
@@ -1393,11 +1467,9 @@ class TpuCluster:
                     while True:
                         try:
                             if need_schedule:
-                                schedule(0)
+                                schedule_all()
                                 need_schedule = False
-                            self._await_all(stages,
-                                            cancel_event=cancel_event,
-                                            query_id=qid)
+                            await_all()
                             break
                         except ClusterMemoryKillError:
                             # the low-memory killer is terminal: a
@@ -1418,11 +1490,9 @@ class TpuCluster:
                                 raise
                             rounds += 1
                 else:
-                    schedule(0)
+                    schedule_all()
                     try:
-                        self._await_all(stages,
-                                        cancel_event=cancel_event,
-                                        query_id=qid)
+                        await_all()
                     except ClusterMemoryKillError:
                         raise       # terminal: killed queries never retry
                     except (ClusterQueryError, OSError):
@@ -1440,9 +1510,7 @@ class TpuCluster:
                         if not self._recover_dead_tasks(qid, stages,
                                                         by_id):
                             raise
-                        self._await_all(stages,
-                                        cancel_event=cancel_event,
-                                        query_id=qid)
+                        await_all()
                 if capture or self.history is not None:
                     self._capture_task_infos(stages)
                     self._record_history(stages, by_id)
@@ -1483,19 +1551,7 @@ class TpuCluster:
                     self.last_cluster_mesh = None
                     _mesh_tier.set_colocation_gauge(0)
 
-        if not DEFAULT_OBS.sampled(random.random()):
-            return run_query()
-        # sampled query: the coordinator opens the root span, the
-        # trace_scope makes every RPC this scheduling thread issues
-        # carry X-Presto-Trace, and worker span dumps are scraped back
-        # at query end into one stitched timeline
-        self.last_trace_id = qid
-        with TRACER.span(qid, "query", worker="coordinator",
-                         fragments=len(frags)) as root:
-            with trace_scope(qid, root.span_id):
-                rows = run_query()
-        self._scrape_worker_traces(qid)
-        return rows
+        return run_query()
 
     def _run_fragments_batch(self, qid, stages, by_id, placement,
                              out_types, merge_keys, capture,
@@ -2251,8 +2307,15 @@ class TpuCluster:
 
     def _collect_root(self, root: _Stage, out_types,
                       merge_keys=None) -> List[tuple]:
-        if merge_keys:
-            return self._merge_root(root, out_types, merge_keys)
+        with TRACER.span(None, "collect_root") as sp:
+            if merge_keys:
+                rows = self._merge_root(root, out_types, merge_keys)
+            else:
+                rows = self._drain_root(root, out_types, sp)
+            sp.attributes["rows"] = len(rows)
+        return rows
+
+    def _drain_root(self, root: _Stage, out_types, span) -> List[tuple]:
         # concurrent final-result drain: all root tasks' buffers pull in
         # parallel through the bounded exchange buffer; arrival-order
         # interleaving is legal here because ordered results always
@@ -2267,6 +2330,7 @@ class TpuCluster:
             for pages in xc:
                 for p in pages:
                     rows.extend(p.to_pylist())
+            span.attributes["bytes"] = xc.bytes_pulled
         return rows
 
     #: per-stream cap on decoded-but-unmerged row batches held at the

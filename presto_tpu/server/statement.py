@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import random
 import re
 import threading
 import time as _time
@@ -28,12 +29,13 @@ import presto_tpu.exec.dist_executor  # noqa: F401 — registers mesh metrics
 from presto_tpu.admission import (DispatchManager, OverloadedError,
                                   QueryQueueFull, ResourceGroupManager)
 from presto_tpu.admission import dispatcher as _dispatch
-from presto_tpu.config import DEFAULT_ADMISSION, DEFAULT_ELASTIC
+from presto_tpu.config import (DEFAULT_ADMISSION, DEFAULT_ELASTIC,
+                               DEFAULT_OBS)
 from presto_tpu.net.aio_server import AioHttpServer, Request, Response
 from presto_tpu.server.journal import QueryJournal
 from presto_tpu.obs.metrics import counter as _counter, gauge as _gauge
 from presto_tpu.utils.threads import spawn
-from presto_tpu.utils.tracing import TRACER
+from presto_tpu.utils.tracing import TRACER, now, root_scope
 
 _EXECUTING = re.compile(r"^/v1/statement/executing/([^/]+)/(\d+)$")
 _QUEUED = re.compile(r"^/v1/statement/queued/([^/]+)/(\d+)$")
@@ -112,6 +114,8 @@ class _Query:
         self.rows: List[tuple] = []
         self.done = _DoneEvent()
         self.cancelled = False
+        #: span clock at the hand-over to the dispatcher
+        self.t_submitted: Optional[float] = None
         # final-batch cache: clients auto-retry nextUri GETs, so the
         # last data batch must survive serving it once — a replayed GET
         # of the same token re-serves the same rows instead of silently
@@ -125,7 +129,22 @@ class _Query:
         self.delivered = False
 
     def run(self, engine):
+        """On the dispatcher's pool thread. The statement's trace opens
+        here under the protocol query id; the wait it ends (submit ->
+        admission grant -> a free pool thread: callback-driven, no
+        thread sat in it) is recorded now, with its measured start."""
         self.state = "RUNNING"
+        with root_scope(self.qid, DEFAULT_OBS.sampled(random.random())), \
+                TRACER.span(None, "statement", qid=self.qid) as sp:
+            if self.t_submitted is not None:
+                handle = getattr(self, "_handle", None)
+                TRACER.record(
+                    None, "admission_wait", self.t_submitted, now(),
+                    parent_id=sp.span_id, mark=True,
+                    group=getattr(handle, "group_path", None) or "")
+            self._run(engine)
+
+    def _run(self, engine):
         try:
             rows = engine.execute_sql(self.sql)
             names = ()
@@ -151,11 +170,12 @@ class _Query:
             # encode identically to scaled ones.
             dec_cols = {i for i, t in enumerate(types)
                         if getattr(t, "is_decimal", False)}
-            self.rows = [
-                [None if v is None else
-                 (str(v) if i in dec_cols
-                  or type(v).__name__ == "Decimal" else v)
-                 for i, v in enumerate(r)] for r in rows]
+            with TRACER.span(None, "collect_root", rows=len(rows)):
+                self.rows = [
+                    [None if v is None else
+                     (str(v) if i in dec_cols
+                      or type(v).__name__ == "Decimal" else v)
+                     for i, v in enumerate(r)] for r in rows]
             self.state = "FINISHED"
         except Exception as e:  # noqa: BLE001 — rendered to the client
             self.error = f"{type(e).__name__}: {e}"[:500]
@@ -805,6 +825,7 @@ class StatementServer:
             if self.journal is not None:
                 self.journal.append(q.qid, state=q.state)
 
+        q.t_submitted = now()
         try:
             q._handle = self.dispatcher.submit(
                 _run, user=user, source=source,

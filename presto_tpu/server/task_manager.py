@@ -20,7 +20,10 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from presto_tpu.data.column import Page, concat_pages_host, select_page_host
+from presto_tpu.data.column import (
+    Page, concat_pages_host, page_nbytes, page_to_host,
+    select_page_host,
+)
 from presto_tpu.exec.split_executor import SplitExecutor
 from presto_tpu.obs.metrics import (
     counter as _counter, gauge as _gauge, histogram as _histogram,
@@ -113,6 +116,21 @@ def _fragment_has_remote_sources(frag: S.PlanFragment) -> bool:
                     walk(c)
     walk(frag.root)
     return found[0]
+
+
+def _concat_upload(pages: List[Page], source: str) -> Page:
+    """Pulled pages fused row-wise on the host and put on the device as
+    one input page: the host->device step of an exchange."""
+    with TRACER.span(None, "upload", source=source) as sp:
+        page = concat_pages_host(pages)
+        sp.attributes["bytes"] = page_nbytes(page)
+    return page
+
+
+def _fragment_of(task_id: str) -> str:
+    """The fragment id inside a task id `<query>.<fragment>.<...>`."""
+    parts = task_id.split(".")
+    return parts[1] if len(parts) > 1 else ""
 
 
 def _remote_source_nodes(plan) -> List[RemoteSourceNode]:
@@ -440,6 +458,18 @@ class TpuTaskManager:
                          req: S.TaskUpdateRequest,
                          trace_ctx: Optional[TraceContext] = None
                          ) -> S.TaskInfo:
+        if trace_ctx is None:
+            return self._create_or_update(task_id, req, None)
+        # on the HTTP handler's thread, under the propagated context:
+        # registry, fragment decode, split binding, the run thread's start
+        with TRACER.span(trace_ctx.trace_id, "task_create",
+                         parent_id=trace_ctx.parent_span_id,
+                         worker=self.node_id, task=task_id):
+            return self._create_or_update(task_id, req, trace_ctx)
+
+    def _create_or_update(self, task_id: str, req: S.TaskUpdateRequest,
+                          trace_ctx: Optional[TraceContext]
+                          ) -> S.TaskInfo:
         with self.lock:
             if self.lifecycle_state != "ACTIVE" \
                     and task_id not in self.tasks:
@@ -466,10 +496,6 @@ class TpuTaskManager:
                 _M_TASKS_CREATED.inc()
         if trace_ctx is not None and task.trace_ctx is None:
             task.trace_ctx = trace_ctx
-            TRACER.record(trace_ctx.trace_id, "task_create",
-                          time.time(), end=time.time(),
-                          parent_id=trace_ctx.parent_span_id,
-                          worker=self.node_id, task=task_id)
         # The update protocol is at-least-once and concurrent (coordinator
         # retries race the original POST): apply the whole update under
         # the task's lock, dedupe splits by sequenceId, and resolve split
@@ -570,8 +596,8 @@ class TpuTaskManager:
         # live here; the coordinator scrapes them back at query end
         with trace_scope(ctx.trace_id, ctx.parent_span_id):
             with TRACER.span(ctx.trace_id, "task_run",
-                             worker=self.node_id,
-                             task=task.task_id) as sp:
+                             worker=self.node_id, task=task.task_id,
+                             fragment=_fragment_of(task.task_id)) as sp:
                 self._run_inner(task)
                 sp.attributes["state"] = task.state
 
@@ -580,38 +606,39 @@ class TpuTaskManager:
             from presto_tpu.config import PROPERTIES, Session
             from presto_tpu.protocol.validator import translate_validated
 
-            # Validate + translate (VeloxPlanValidator analog): foreign
-            # connectors / unknown nodes / unsupported features fail with
-            # a precise reason, not a mid-execution traceback.
-            plan = translate_validated(task.fragment)
-            ch = (task.session_properties or {}).get(
-                "x_dynamic_filter_channel")
-            if ch is not None:
-                try:
-                    task.df_channel = int(ch)
-                except (TypeError, ValueError):
-                    task.df_channel = None
-            if task.scan_constraints:
-                plan = self._apply_scan_constraints(task, plan)
-            # Session properties arrive on the wire as strings
-            # (SessionRepresentation.systemProperties); unknown ones are
-            # coordinator-side and ignored here, like the C++ worker's
-            # PrestoToVeloxQueryConfig mapping.
-            known = {p.name for p in PROPERTIES}
-            props = {k: v for k, v in
-                     (task.session_properties or {}).items()
-                     if k in known}
-            # per-operator row counters feed the TaskInfo stats tree the
-            # coordinator renders (OperatorStats role) — on by default
-            props.setdefault("collect_stats", "true")
-            ex = SplitExecutor(self.connector, session=Session(props))
-            if self.memory_pool is not None:
-                # static footprints reserve against the worker pool as
-                # programs dispatch; the unique task-id key lets
-                # concurrent tasks of one query account independently
-                ex.memory_pool = self.memory_pool
-                ex.pool_query_id = task.task_id
-            ex.set_splits(task.splits)
+            with TRACER.span(None, "task_plan"):
+                # Validate + translate (VeloxPlanValidator analog): foreign
+                # connectors / unknown nodes / unsupported features fail with
+                # a precise reason, not a mid-execution traceback.
+                plan = translate_validated(task.fragment)
+                ch = (task.session_properties or {}).get(
+                    "x_dynamic_filter_channel")
+                if ch is not None:
+                    try:
+                        task.df_channel = int(ch)
+                    except (TypeError, ValueError):
+                        task.df_channel = None
+                if task.scan_constraints:
+                    plan = self._apply_scan_constraints(task, plan)
+                # Session properties arrive on the wire as strings
+                # (SessionRepresentation.systemProperties); unknown ones are
+                # coordinator-side and ignored here, like the C++ worker's
+                # PrestoToVeloxQueryConfig mapping.
+                known = {p.name for p in PROPERTIES}
+                props = {k: v for k, v in
+                         (task.session_properties or {}).items()
+                         if k in known}
+                # per-operator row counters feed the TaskInfo stats tree the
+                # coordinator renders (OperatorStats role) — on by default
+                props.setdefault("collect_stats", "true")
+                ex = SplitExecutor(self.connector, session=Session(props))
+                if self.memory_pool is not None:
+                    # static footprints reserve against the worker pool as
+                    # programs dispatch; the unique task-id key lets
+                    # concurrent tasks of one query account independently
+                    ex.memory_pool = self.memory_pool
+                    ex.pool_query_id = task.task_id
+                ex.set_splits(task.splits)
             task.total_splits = sum(len(v) for v in task.splits.values())
             task.start_time = time.time()
             # fragment result cache consult (Presto@Meta VLDB'23 §4.2):
@@ -995,7 +1022,7 @@ class TpuTaskManager:
                 return
             for p in pages:
                 p.names = driving.output_names
-            chunk = concat_pages_host(pages)
+            chunk = _concat_upload(pages, driving.node_id)
             ex.set_remote_pages({**others, driving.node_id: chunk})
             out = ex.execute(plan)
             for nid, r in (getattr(ex, "last_node_rows", None)
@@ -1016,7 +1043,21 @@ class TpuTaskManager:
                             types=list(driving.output_types),
                             config=self.exchange_config,
                             spool=self.spool) as xc:
-            for pages in xc:
+            landed = 0
+            while True:
+                # a container: the fetchers' GETs that land data are
+                # the `exchange_pull` spans; bytes are what landed since
+                # the last chunk (the sum is exact)
+                with TRACER.span(None, "exchange_wait",
+                                 source=driving.node_id,
+                                 upstreams=len(xc._streams)) as sp:
+                    pages = xc.next_chunk()
+                    sp.attributes.update(
+                        bytes=xc.bytes_pulled - landed,
+                        pages=len(pages or ()))
+                    landed += sp.attributes["bytes"]
+                if pages is None:
+                    break
                 run_chunk(pages)
         if emitted[0] == 0:
             # no upstream rows at all: run once on an empty chunk so
@@ -1097,22 +1138,18 @@ class TpuTaskManager:
                         0, int(rows[s_nid]) - int(rows[f_nid]))
         if task.df_pruned:
             _M_DF_PRUNED.inc(task.df_pruned)
-        # per-operator worker spans from the island profile: wall times
-        # are real, placement is a sequential reconstruction from the
-        # task start (islands execute in dependency order)
+        # per-operator worker spans from the island profile: each island
+        # was timed where it ran (start and seconds on the span clock)
         ctx = task.trace_ctx
         profile = getattr(ex, "last_island_profile", None) or []
-        if ctx is not None and profile:
-            cursor = task.start_time or time.time()
+        if ctx is not None:
             for entry in profile:
-                secs = float(entry.get("seconds", 0.0) or 0.0)
                 TRACER.record(
                     ctx.trace_id, f"op:{entry.get('root', '?')}",
-                    cursor, end=cursor + secs,
+                    entry["t0"], end=entry["t0"] + entry["seconds"],
                     parent_id=ctx.parent_span_id,
                     worker=self.node_id, task=task.task_id,
                     rows=int(entry.get("rows", 0) or 0))
-                cursor += secs
 
     #: Each GET to an upstream buffer returns at most this many bytes
     #: (client-side backpressure; reference: ExchangeClient's
@@ -1140,26 +1177,34 @@ class TpuTaskManager:
             if skip and node.node_id in skip:
                 continue
             splits = task.remote_splits.get(node.node_id, [])
-            pages: List[Page] = []
-            if splits:
-                with ExchangeClient(splits,
-                                    types=list(node.output_types),
-                                    config=self.exchange_config,
-                                    spool=self.spool) as xc:
-                    pages = xc.drain_pages()
-            if not pages:
-                # no producer emitted rows: empty page of the right shape
-                from presto_tpu.data.column import Column
-                cols = [Column.from_numpy(
-                    np.zeros(0, t.dtype), t, capacity=256)
-                    for t in node.output_types]
-                out[node.node_id] = Page.from_columns(
-                    cols, 0, node.output_names)
-                continue
-            for p in pages:
-                p.names = node.output_names
-            out[node.node_id] = concat_pages_host(pages)
+            with TRACER.span(None, "exchange_wait", source=node.node_id,
+                             upstreams=len(splits)) as sp:
+                out[node.node_id] = self._pull_one(node, splits, sp)
         return out
+
+    def _pull_one(self, node, splits, span) -> Page:
+        """One RemoteSourceNode's upstream buffers, drained and fused
+        into one page on the device."""
+        from presto_tpu.protocol.exchange import ExchangeClient
+
+        pages: List[Page] = []
+        if splits:
+            with ExchangeClient(splits, types=list(node.output_types),
+                                config=self.exchange_config,
+                                spool=self.spool) as xc:
+                pages = xc.drain_pages()
+                span.attributes.update(bytes=xc.bytes_pulled,
+                                       pages=len(pages))
+        if not pages:
+            # no producer emitted rows: empty page of the right shape
+            from presto_tpu.data.column import Column
+            cols = [Column.from_numpy(
+                np.zeros(0, t.dtype), t, capacity=256)
+                for t in node.output_types]
+            return Page.from_columns(cols, 0, node.output_names)
+        for p in pages:
+            p.names = node.output_names
+        return _concat_upload(pages, node.node_id)
 
     def _emit_output(self, task: Task, page: Page):
         """Route the fragment result into output buffers per the
@@ -1175,6 +1220,18 @@ class TpuTaskManager:
             # build-side fragment: summarize the join-key domain from
             # the pre-partitioning page (DynamicFilterSourceOperator)
             self._accumulate_df_domain(task, page)
+        with TRACER.span(None, "download",
+                         bytes=page_nbytes(page)):
+            page_to_host(page)
+        with TRACER.span(None, "serialize",
+                         buffers=len(task.buffers.buffers)) as sp:
+            before = task.bytes_out
+            self._route_output(task, page)
+            sp.attributes["bytes"] = task.bytes_out - before
+
+    def _route_output(self, task: Task, page: Page) -> None:
+        """Partition, serialize, compress and buffer one output page
+        (its arrays already on the host)."""
         codec = (task.session_properties or {}).get(
             "exchange_compression_codec")
         if codec in (None, "", "none"):

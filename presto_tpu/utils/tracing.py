@@ -17,7 +17,18 @@ Reference roles:
 
 Engines call `tracer.span(...)` around phases (plan/lower/execute) and
 `emit_query_event(...)` at lifecycle edges; listeners are plain
-callables (the plugin surface collapsed to its functional core)."""
+callables (the plugin surface collapsed to its functional core).
+
+One span API, two sinks, one clock. Every span is also held open as a
+`jax.profiler.TraceAnnotation("presto:<name>", **attributes)`: under a
+running profiler it lies in the host plane of the device trace, on the
+thread that did the work and on the device trace's clock; with no
+profiler running the annotation is an inactive TraceMe (half a
+microsecond). The in-memory span is what GET /v1/trace/{id},
+`render_trace` and EXPLAIN ANALYZE serve. Spans stamp `now()`, a
+monotonic clock. The span open on a thread is the parent of the next
+one opened there, so a layer's self time can be computed; counts
+(bytes, pages, rows) travel as attributes of the span around the work."""
 
 from __future__ import annotations
 
@@ -29,7 +40,23 @@ import uuid
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 log = logging.getLogger("presto_tpu.tracing")
+
+#: what the profiler sees of a span named <name>
+ANNOTATION_PREFIX = "presto:"
+
+#: wall time at perf_counter() == 0, read once: spans stamp the
+#: monotonic clock, and the wire format keeps epoch seconds so span
+#: dumps of several processes still lie on one axis
+_EPOCH = time.time() - time.perf_counter()
+
+
+def now() -> float:
+    """The span clock: monotonic, in epoch seconds."""
+    return _EPOCH + time.perf_counter()
+
 
 #: wire header carrying "<trace_id>;<parent_span_id>" on every
 #: coordinator -> worker RPC (PrestoHeaders-style custom header)
@@ -128,6 +155,30 @@ def trace_scope(trace_id: str, parent_span_id: str = ""):
                 _THREAD_TRACES[tid] = prev_tid
 
 
+@contextmanager
+def root_scope(trace_id: str, sampled: bool):
+    """The outermost entry of a statement on this thread decides whether
+    it is traced and under which id: the first caller installs
+    `trace_id` if its draw (`ObsConfig.sampled`, the server layer's
+    policy) came out `sampled`; a caller inside an open scope (the
+    cluster under the statement server, a subquery under its query)
+    keeps what it finds, whatever its own draw. Yields the active
+    TraceContext, or None for an unsampled statement."""
+    ctx = current_trace()
+    if ctx is not None or getattr(_ACTIVE, "unsampled", False):
+        yield ctx
+        return
+    if sampled:
+        with trace_scope(trace_id) as ctx:
+            yield ctx
+        return
+    _ACTIVE.unsampled = True
+    try:
+        yield None
+    finally:
+        _ACTIVE.unsampled = False
+
+
 def parse_trace_header(value: Optional[str]) -> Optional[TraceContext]:
     """'<trace_id>;<parent_span_id>' -> TraceContext (None on absent or
     malformed input — tracing is never a reason to fail an RPC)."""
@@ -182,27 +233,90 @@ class Tracer:
         return kept
 
     @contextmanager
-    def span(self, trace_id: str, name: str, **attributes):
+    def span(self, trace_id: Optional[str], name: str,
+             parent_id: Optional[str] = None, **attributes):
+        """Time the body as span `name`, in the profiler's host plane
+        and, where the thread has a trace, in memory. `trace_id` None
+        takes the thread's `current_trace()` (the execution layer knows
+        no query id); on a thread with none the span still annotates
+        and is stored nowhere. The parent is `parent_id` if given (a
+        helper thread working for a span of another thread), else the
+        span open on this thread, else the propagated context's.
+        Attributes set on the yielded span inside the body reach both
+        sinks when it ends."""
         ctx = current_trace()
-        parent = ctx.parent_span_id \
-            if ctx is not None and ctx.trace_id == trace_id else ""
-        s = Span(name, time.time(), attributes=dict(attributes),
-                 parent_id=parent)
-        self._store(trace_id, s)
-        try:
-            yield s
-        finally:
-            s.end = time.time()
+        if trace_id is None and ctx is not None:
+            trace_id = ctx.trace_id
+        outer = getattr(_ACTIVE, "span", None)
+        if parent_id is None:
+            if outer is not None and outer[0] == trace_id:
+                parent_id = outer[1].span_id
+            elif ctx is not None and ctx.trace_id == trace_id:
+                parent_id = ctx.parent_span_id
+            else:
+                parent_id = ""
+        s = Span(name, 0.0, attributes=dict(attributes),
+                 parent_id=parent_id)
+        _ACTIVE.span = (trace_id, s)
+        with _Annotation(ANNOTATION_PREFIX + name, **attributes) as mark:
+            s.start = now()
+            if trace_id is not None:
+                self._store(trace_id, s)
+            try:
+                yield s
+            finally:
+                s.end = now()
+                _ACTIVE.span = outer
+                late = {k: v for k, v in s.attributes.items()
+                        if k not in attributes or attributes[k] != v}
+                if late:
+                    mark.set_metadata(**late)
 
-    def record(self, trace_id: str, name: str, start: float,
+    def record(self, trace_id: Optional[str], name: str, start: float,
                end: Optional[float] = None, parent_id: str = "",
-               **attributes) -> Span:
-        """Record an already-timed span (worker-side per-operator spans
-        whose wall times come from the executor's profile)."""
+               mark: bool = False, **attributes) -> Optional[Span]:
+        """Record a span that was timed elsewhere, on the span clock
+        (`now()`): the worker's per-island `op:` spans, a wait no
+        thread sits in, a GET that turned out to be a pull. `mark` also
+        leaves a marker in the profiler's trace at the moment of the
+        call, carrying `waited_ms`, from which a reader back-dates the
+        interval."""
+        if mark:
+            waited_ms = 1e3 * ((now() if end is None else end) - start)
+            with _Annotation(ANNOTATION_PREFIX + name,
+                             waited_ms=waited_ms, **attributes):
+                pass
+            attributes["waited_ms"] = waited_ms
+        if trace_id is None:
+            ctx = current_trace()
+            if ctx is None:
+                return None
+            trace_id = ctx.trace_id
         s = Span(name, start, end=end, attributes=dict(attributes),
                  parent_id=parent_id)
         self._store(trace_id, s)
         return s
+
+    def add(self, name: str, **counts) -> None:
+        """Add counts to the span `name` open on this thread, if one is:
+        the code that knows a count reports it, the span around the work
+        carries it."""
+        outer = getattr(_ACTIVE, "span", None)
+        if outer is not None and outer[1].name == name:
+            at = outer[1].attributes
+            for k, v in counts.items():
+                at[k] = at.get(k, 0) + v
+
+    def here(self) -> Optional[TraceContext]:
+        """This thread's trace with the span open on it as the parent:
+        what a helper thread needs to record spans for this one."""
+        ctx = current_trace()
+        outer = getattr(_ACTIVE, "span", None)
+        if ctx is None:
+            return None
+        if outer is not None and outer[0] == ctx.trace_id:
+            return TraceContext(ctx.trace_id, outer[1].span_id)
+        return ctx
 
     def get(self, trace_id: str) -> List[Span]:
         with self._lock:
@@ -239,16 +353,28 @@ class Tracer:
     def render(self, trace_id: str) -> str:
         """One cross-node timeline: spans sorted by start, offsets
         relative to the earliest span, worker column from the `worker`
-        attribute (coordinator spans carry none)."""
+        attribute of the span or of its nearest ancestor that has one
+        (coordinator spans carry none)."""
         spans = sorted(self.get(trace_id), key=lambda s: s.start)
         if not spans:
             return ""
         t0 = spans[0].start
+        by_id = {s.span_id: s for s in spans}
+
+        def node_of(s: Span) -> str:
+            # the nearest span up the chain that names its node
+            for _ in range(len(spans)):
+                if "worker" in s.attributes or s.parent_id not in by_id:
+                    break
+                s = by_id[s.parent_id]
+            return str(s.attributes.get("worker", "coordinator"))
+
         out = []
         for s in spans:
             d = f"{s.duration_s * 1000:.1f}ms" if s.end else "…"
             attrs = dict(s.attributes)
-            worker = str(attrs.pop("worker", "coordinator"))
+            worker = node_of(s)
+            attrs.pop("worker", None)
             rest = " ".join(f"{k}={v}" for k, v in attrs.items())
             out.append(f"+{(s.start - t0) * 1000:8.1f}ms "
                        f"{worker:<16} {s.name:<24} {d:>10} {rest}")
@@ -323,16 +449,17 @@ def query_lifecycle(qid: str, sql: str):
     execution (used by LocalEngine and TpuCluster). Yields a one-slot
     list the body fills with the result rows so `completed` can report
     the row count."""
-    t0 = time.time()
+    t0 = time.perf_counter()
     EVENTS.emit(QueryEvent("created", qid, sql))
     box: List[Any] = [None]
     try:
         yield box
     except Exception as e:
         EVENTS.emit(QueryEvent("failed", qid, sql,
-                               wall_s=time.time() - t0, error=str(e)))
+                               wall_s=time.perf_counter() - t0,
+                               error=str(e)))
         raise
     rows = box[0]
     EVENTS.emit(QueryEvent(
-        "completed", qid, sql, wall_s=time.time() - t0,
+        "completed", qid, sql, wall_s=time.perf_counter() - t0,
         rows=len(rows) if rows is not None else None))

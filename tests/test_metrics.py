@@ -294,6 +294,112 @@ def test_trace_propagation_two_workers_under_retry(cluster):
         text), "transport retries not visible in exposition"
 
 
+#: the spans device idle time may be attributed to (PERF.md section 3)
+LEAF_SPANS = {"admission_wait", "telemetry", "plan", "schedule",
+              "collect_root", "task_create", "task_plan", "exchange_pull",
+              "deserialize", "upload", "dispatch", "download", "serialize"}
+CONTAINER_SPANS = {"statement", "query", "await_tasks", "task_run",
+                   "exchange_wait", "device_wait"}
+
+
+def test_served_statement_leaves_every_layers_span(cluster,
+                                                   statement_server):
+    """One statement through POST /v1/statement (two tables joined, a
+    group by: scans, a page exchange, partial and final aggregation):
+    every leaf span occurs under the statement's one trace id, nested
+    under `query` on the workers and under `statement` on the
+    coordinator, the byte counts ride on the spans, the `op:` spans lie
+    where their islands ran, and no in-process worker is asked for its
+    spans over HTTP."""
+    from presto_tpu.server.statement import run_statement
+    asked = []
+    real_get = cluster.http.get_json
+
+    def get_json(url, *a, **kw):
+        asked.append(url)
+        return real_get(url, *a, **kw)
+
+    cluster.http.get_json = get_json
+    try:
+        _columns, rows = run_statement(
+            statement_server.base,
+            "select o_orderpriority, count(*), sum(l_extendedprice) "
+            "from orders join lineitem on l_orderkey = o_orderkey "
+            "group by o_orderpriority")
+    finally:
+        del cluster.http.get_json
+    assert len(rows) == 5
+    assert not [u for u in asked if "/v1/trace/" in u]
+
+    spans = TRACER.get(cluster.last_trace_id)
+    by_id = {s.span_id: s for s in spans}
+    names = {s.name for s in spans}
+    assert LEAF_SPANS <= names, LEAF_SPANS - names
+    assert CONTAINER_SPANS <= names, CONTAINER_SPANS - names
+    assert all(s.end is not None and s.end >= s.start for s in spans)
+    assert not [s for s in spans if s.name == "task_create"
+                and s.end == s.start]
+
+    def chain(s):
+        out = [s.name]
+        while s.parent_id:
+            s = by_id[s.parent_id]      # every parent is in the trace
+            out.append(s.name)
+        return out
+
+    coordinator = {"statement", "admission_wait", "telemetry", "plan",
+                   "query", "schedule", "await_tasks", "collect_root"}
+    for s in spans:
+        c = chain(s)
+        assert c[-1] == "statement", c
+        if s.name not in coordinator:
+            assert "query" in c, c      # a worker's span, under the root
+    root = next(s for s in spans if s.name == "query")
+    assert all(s.parent_id == root.span_id for s in spans
+               if s.name in ("task_run", "task_create"))
+    for name in ("upload", "exchange_wait", "exchange_pull", "serialize",
+                 "download", "deserialize"):
+        got = [s.attributes.get("bytes", 0) for s in spans
+               if s.name == name]
+        assert got and sum(got) > 0, name
+    assert {s.attributes.get("table") for s in spans
+            if s.name == "upload"} >= {"orders", "lineitem"}
+    programs = {s.attributes["program"] for s in spans
+                if s.name == "dispatch"}
+    assert programs and all(p.startswith("jit_presto_")
+                            for p in programs)
+    wait = next(s for s in spans if s.name == "admission_wait")
+    assert wait.attributes["waited_ms"] >= 0 and chain(wait)[1] == \
+        "statement"
+    # an island ran after its task's inputs were pulled: an `op:` span
+    # placed from the task's start would begin before the pull ended
+    runs = {s.attributes["task"]: s for s in spans
+            if s.name == "task_run"}
+    ops = [s for s in spans if s.name.startswith("op:")]
+    assert ops
+    for op in ops:
+        run = runs[op.attributes["task"]]
+        assert run.start <= op.start and op.end <= run.end
+        waits = [w for w in spans if w.name == "exchange_wait"
+                 and w.parent_id == run.span_id]
+        assert all(w.end <= op.start for w in waits)
+    waits = {w.span_id: w for w in spans if w.name == "exchange_wait"}
+    assert {w.parent_id for w in waits.values()} & \
+        {runs[op.attributes["task"]].span_id for op in ops}
+    # a fetcher's GET that landed data is a pull, inside the wait of the
+    # consumer it fetched for (or the root's collect); both count bytes
+    pulls = [p for p in spans if p.name == "exchange_pull"]
+    for p in pulls:
+        assert p.attributes["bytes"] > 0 and p.end > p.start
+        # (under `task_run` itself where a task streams its input)
+        assert chain(p)[1] in ("exchange_wait", "collect_root",
+                               "task_run"), chain(p)
+        w = waits.get(p.parent_id)
+        assert w is None or w.start <= p.start and p.end <= w.end
+    assert sum(w.attributes["bytes"] for w in waits.values()) == \
+        sum(p.attributes["bytes"] for p in pulls if p.parent_id in waits)
+
+
 def test_worker_trace_endpoint_serves_span_dump(cluster):
     qid = cluster.last_trace_id
     port = cluster.workers[0].port
