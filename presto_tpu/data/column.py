@@ -778,9 +778,14 @@ def compact(page: Page, keep: jnp.ndarray) -> Page:
     num_rows is the survivor count. This is the engine's filter primitive.
 
     Implemented as ONE 2-operand argsort on the order key + per-column
-    gathers: on this stack gathers compile in under a second and run at
-    memory bandwidth, while a lax.sort carrying every column as a payload
-    operand multiplies compile cost with column count.
+    gathers: on this stack gathers compile in under a second, while a
+    lax.sort carrying every column as a payload operand multiplies
+    compile cost with column count (~25 s an operand; PR 27). The
+    gathers are what it costs to RUN: 7-9 ns an element for every 32-bit
+    lane on the chip, values and null flags alike (15-19 ms a lane at
+    2 M rows, ~20 ns an element where the table is out of near memory;
+    ledger, PR 26), where one stacked [k, n] gather moves up to 8 lanes
+    for the price of one (ops/join._gather_columns; PERF.md, PR 27).
 
     Reference semantics: PageProcessor's filter
     (presto-main-base/.../operator/project/PageProcessor.java:56), re-expressed

@@ -159,6 +159,10 @@ class Executor:
         # lower time). None = unlimited.
         self.memory_limit_bytes = self.session["query_max_memory_per_node"]
         self.last_memory_estimate = 0
+        #: how the last lowering joins, a JoinNode each in plan order:
+        #: "merge" (ops/join.merge_join) or "expansion" (hash_join: cross
+        #: joins, merge_join_enabled off, duplicate build keys seen)
+        self.last_join_paths: List[str] = []
         # Optional MemoryPool (exec/memory.py): static footprints
         # reserve against it at lower time (admission control BEFORE
         # execution — the TPU analog of MemoryPool.java's runtime
@@ -591,6 +595,8 @@ class Executor:
             about = {"program": "jit_" + program.__name__,
                      "root": _kind(plan),
                      "operators": "+".join(_operators(plan))}
+            if self.last_join_paths:
+                about["join_paths"] = "+".join(self.last_join_paths)
             entry = (jax.jit(program), scans, watch, [], about)
             self._compiled[key] = entry
             self._note_compile(plan)
@@ -974,6 +980,8 @@ class Executor:
                                       JoinType.ANTI_EXISTS):
                     # Merge path: duplicates can't change a match flag,
                     # so no fallback is ever needed here.
+                    join_paths.append("merge")
+
                     def semi_fn(pages, node=node):
                         p = psrc(pages)
                         b = bsrc(pages)
@@ -1004,6 +1012,7 @@ class Executor:
                                                     JoinType.LEFT,
                                                     JoinType.FULL)
                              and caps.get(-nid, 0) == 0)
+                join_paths.append("merge" if use_merge else "expansion")
                 if use_merge:
                     caps[-nid] = 0
                     watch.append(-nid)
@@ -1267,8 +1276,10 @@ class Executor:
             raise NotImplementedError(f"lowering {type(node).__name__}")
 
         _needed: List = []
+        join_paths: List[str] = []
         root, _cap = build(plan)
         self.last_memory_estimate = mem_bytes[0]
+        self.last_join_paths = join_paths
         if self.memory_limit_bytes is not None \
                 and mem_bytes[0] > self.memory_limit_bytes:
             raise MemoryLimitExceeded(mem_bytes[0],

@@ -1,21 +1,37 @@
-"""Joins — sorted-build, searchsorted-probe, vectorized pair expansion.
+"""Joins — a sort-merge join for unique build keys, and the expansion join.
 
 Reference roles: HashBuilderOperator/LookupJoinOperator
 (presto-main-base/.../operator/HashBuilderOperator.java:55,
 LookupJoinOperator.java:52 over PagesHash/JoinProbe), HashSemiJoinOperator,
-NestedLoopJoinOperator. TPU-first redesign: no pointer-chasing hash table —
+NestedLoopJoinOperator, MergeOperator. TPU-first redesign, no
+pointer-chasing hash table, in two forms:
+
+`merge_join` — what every equi-join lowers onto first (FK joins: the build
+keys are unique; semi/anti, where duplicates change nothing). One sort over
+build ++ probe keyed on the key values, one running max in sorted order,
+one sort back. Laid out on what the chip measured (PERF.md, PR 27): a sort
+of 3 M rows with three 32-bit operands takes 7 ms and a scan under 1 ms,
+while every 1-D gather takes 7-9 ns an element (15-19 ms for ONE 32-bit lane
+of 2 M rows, 59 ms where the table does not sit in near memory) — so no lane
+is fetched by a permutation that a sort can carry or a scan can propagate,
+and the payload columns move once, stacked: a [k, n] gather along n costs
+what one lane costs for k up to 8. Sorts are dear to COMPILE instead, and by
+operand: ~40 s for two, +25 s for each one more, twice that when stable — so
+each sort carries one lane beside its keys and none asks for stability.
+
+`hash_join` — the expansion join, for duplicate build keys and cross joins:
 the build side is sorted by a 64-bit key hash (one argsort), probes binary-
 search the sorted hashes (jnp.searchsorted is vectorized), and the variable
 match fan-out is materialized by a prefix-sum pair expansion into a page of
 *static* capacity. Hash-equal-but-key-unequal pairs (collisions, multi-key)
-are masked by an exact key comparison on the expanded pairs.
-
-Capacity contract: like aggregation, `out_capacity` bounds the join output;
+are masked by an exact key comparison on the expanded pairs. Capacity
+contract: like aggregation, `out_capacity` bounds the join output;
 `total_pairs` (traced) lets the executor detect overflow and retry at a
 larger bucket.
 
-NULL join keys never match (SQL semantics), enforced by tagging null-key
-rows with disjoint sentinel hashes on each side.
+NULL join keys never match (SQL semantics): merge_join's null-key rows take
+no part in its scan; hash_join tags them with disjoint sentinel hashes on
+each side.
 """
 
 from __future__ import annotations
@@ -23,11 +39,13 @@ from __future__ import annotations
 from typing import Sequence, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 from presto_tpu.data.column import Column, Page
 from presto_tpu.expr.compile import align_string_columns
 from presto_tpu.ops.keys import group_values, hash_columns, \
     values_equal
+from presto_tpu.ops.scan import blocked_cummax
 
 
 def _aligned_keys(probe: Page, build: Page, probe_fields, build_fields):
@@ -42,23 +60,132 @@ def _aligned_keys(probe: Page, build: Page, probe_fields, build_fields):
     return pcols, bcols
 
 
+#: 32-bit lanes one stacked gather moves. On the chip a [k, n] gather
+#: along n costs what ONE 1-D gather of n costs for k up to 8 (11-13 ms
+#: against 15-19 ms a lane at 2 M rows; PERF.md, PR 27).
+_STACK = 8
+
+
+def _stacked_take(lanes, idx):
+    """lanes[j][idx] for same-dtype 1-D lanes, _STACK lanes a gather."""
+    out = []
+    for at in range(0, len(lanes), _STACK):
+        chunk = lanes[at:at + _STACK]
+        if len(chunk) == 1:
+            out.append(jnp.take(chunk[0], idx, mode="clip"))
+        else:
+            got = jnp.take(jnp.stack(chunk), idx, axis=1, mode="clip")
+            out.extend(got[j] for j in range(len(chunk)))
+    return out
+
+
+def _gather_columns(cols, idx, valid):
+    """[c.gather(idx, valid) for c in cols], moved in as few gathers as
+    the dtypes allow: every plain column's 32-bit pieces — int32 lanes,
+    the two halves of 64-bit integers, float32 bits, and all null flags
+    and booleans packed 31 to a word — ride one stack, DOUBLEs another
+    (the chip's float64 cannot be split into words: no 64-bit bitcast).
+    Other column classes gather themselves."""
+    words, doubles, flags, plan = [], [], [], []
+    for c in cols:
+        dt = c.values.dtype if type(c) is Column else None
+        if dt == jnp.bool_:
+            kind, at = "flag", len(flags)
+            flags.append(c.values)
+        elif dt == jnp.float64:
+            kind, at = "f64", len(doubles)
+            doubles.append(c.values)
+        elif dt == jnp.float32:
+            kind, at = "f32", len(words)
+            words.append(lax.bitcast_convert_type(c.values, jnp.int32))
+        elif dt == jnp.int64:
+            kind, at = "i64", len(words)
+            words += [(c.values >> 32).astype(jnp.int32),
+                      c.values.astype(jnp.int32)]
+        elif dt is not None and jnp.issubdtype(dt, jnp.signedinteger):
+            kind, at = "i32", len(words)
+            words.append(c.values.astype(jnp.int32))
+        else:
+            plan.append((c, None, None, None))
+            continue
+        plan.append((c, kind, at, len(flags)))
+        flags.append(c.nulls)
+    first_pack = len(words)
+    for at in range(0, len(flags), 31):
+        word = jnp.zeros(flags[at].shape, jnp.int32)
+        for bit, f in enumerate(flags[at:at + 31]):
+            word = word | (f.astype(jnp.int32) << bit)
+        words.append(word)
+    words = _stacked_take(words, idx)
+    doubles = _stacked_take(doubles, idx)
+
+    def flag(i):
+        return ((words[first_pack + i // 31] >> (i % 31)) & 1
+                ).astype(bool)
+
+    out = []
+    for c, kind, at, null_at in plan:
+        if kind is None:
+            out.append(c.gather(idx, valid))
+            continue
+        if kind == "flag":
+            vals = flag(at)
+        elif kind == "f64":
+            vals = doubles[at]
+        elif kind == "f32":
+            vals = lax.bitcast_convert_type(words[at], jnp.float32)
+        elif kind == "i64":
+            vals = (words[at].astype(jnp.int64) << 32) \
+                | (words[at + 1].astype(jnp.int64) & 0xFFFFFFFF)
+        else:
+            vals = words[at].astype(c.values.dtype)
+        sent = jnp.asarray(c.type.null_sentinel(), dtype=c.values.dtype)
+        out.append(Column(jnp.where(valid, vals, sent),
+                          jnp.where(valid, flag(null_at), True),
+                          c.type, c.dictionary))
+    return out
+
+
+def _key_lanes(pc: Column, bc: Column):
+    """One key column's comparison lanes (probe, build), of one dtype.
+    A key both of whose sides are stored in at most 32 bits (INTEGER,
+    DATE, dictionary codes, BOOLEAN) stays int32 — half the sort's key
+    bytes on a chip that emulates 64-bit lanes as pairs; every other
+    pair takes group_values' 64-bit image."""
+    def narrow(c):
+        dt = c.values.dtype
+        return dt == jnp.bool_ or (jnp.issubdtype(dt, jnp.integer)
+                                   and dt.itemsize <= 4)
+    if narrow(pc) and narrow(bc):
+        return pc.values.astype(jnp.int32), bc.values.astype(jnp.int32)
+    return group_values(pc), group_values(bc)
+
+
 def merge_join(probe: Page, build: Page,
                probe_fields: Sequence[int], build_fields: Sequence[int],
                join_type: str = "inner",
-               ) -> Tuple[Page, jnp.ndarray]:
+               ) -> Tuple[Page, jnp.ndarray, jnp.ndarray]:
     """Sort-merge join for UNIQUE build keys (+ semi/anti, where
-    duplicates cannot change the answer). The TPU-native replacement for
-    the searchsorted probe: binary search with millions of queries and
-    random pair-expansion gathers both serialize on TPU, while this path
-    is two multi-operand sorts plus blocked fill-forward scans.
+    duplicates cannot change the answer). The lanes ride the sorts; the
+    payload is gathered once (the module docstring has the chip's prices).
 
-      1. Co-sort build+probe rows by the actual key values, build rows
-         first within a key run.
-      2. Blocked fill-forward (ops/scan.py) propagates each build row's
-         payload and key to the probe slots after it — a probe slot
-         matches iff the propagated key equals its own.
-      3. A second sort restores probe order carrying only the per-probe
-         results; probe columns never move at all.
+      1. ONE sort over concatenate([build, probe]), keyed on each key
+         column's stored values and then on a tag, 2 * position + "takes
+         part" (live, no NULL key). The tag makes every row distinct, so
+         the order is a stable sort's — build rows before probe rows
+         within a key run — and it is all the sort carries. NULL-key and
+         dead rows sort wherever their stored values fall: they take no
+         part below, so neither a nulls lane nor a liveness lane is a key.
+      2. ONE running max in sorted order (ops/scan.blocked_cummax, 64-bit)
+         gives every slot the newest build row that takes part and says
+         whether it lies in the slot's own key run: a probe slot matches
+         iff it does. The same scan counts duplicate build keys.
+      3. ONE sort back, keyed on the position, with the matched build row
+         as its payload. For inner it puts the matched probe rows first,
+         so it is the compaction as well. Then each side's columns move in
+         one stacked gather of their words and one of their DOUBLEs
+         (`_gather_columns`); for left/full/semi/anti the probe columns
+         do not move at all.
 
     Returns (page, dup_count, match) where dup_count > 0 means the build
     side had duplicate live keys: for inner/left/full the caller must
@@ -74,8 +201,6 @@ def merge_join(probe: Page, build: Page,
     (presto-main-base/.../operator/MergeOperator.java) fused with the
     LookupJoin contract (LookupJoinOperator.java:52).
     """
-    from presto_tpu.ops.scan import fill_forward
-
     pcap, bcap = probe.capacity, build.capacity
     cap = bcap + pcap
     pcols, bcols = _aligned_keys(probe, build, probe_fields, build_fields)
@@ -87,94 +212,79 @@ def merge_join(probe: Page, build: Page,
     for c in bcols:
         b_null = b_null | c.nulls
 
-    b_present = build.row_valid() & ~b_null
-    p_live = probe.row_valid()
-
     def cat(b, p):
         return jnp.concatenate([b, p])
 
-    # Sort PERMUTATION via ops/keys.lex_perm (composed 2-operand stable
-    # argsorts): per key column (nulls, values), then build-before-probe
-    # tag least significant. NO wide variadic sort — on this stack
-    # lax.sort compile cost explodes with operand count (a ~20-operand
-    # sort at SF1 shapes never finishes compiling), while argsort +
-    # gather compiles in seconds and gathers run at memory bandwidth.
-    # Dead rows need no sort lane: propagation only flows from `present`
-    # build rows, and matches mask on the gathered null/live flags.
-    from presto_tpu.ops.keys import lex_perm
-    tag = cat(jnp.zeros((bcap,), jnp.int8), jnp.ones((pcap,), jnp.int8))
-    lanes = []
+    # Sort operands: one value lane per key column + the tag.
+    operands = []
     for pc, bc in zip(pcols, bcols):
-        lanes.append(cat(bc.nulls, pc.nulls))
-        lanes.append(cat(group_values(bc), group_values(pc)))
-    lanes.append(tag)
-    perm = lex_perm(lanes)
+        pv, bv = _key_lanes(pc, bc)
+        operands.append(cat(bv, pv))
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    takes_part = cat(build.row_valid() & ~b_null,
+                     probe.row_valid() & ~p_null)
+    # The tag is the last KEY, so no two rows compare equal: the order
+    # is that of a stable sort without asking for one (is_stable doubles
+    # the sort's compile time on the TPU: 109 s against 55 s here).
+    *s_keys, s_tag = lax.sort(operands + [2 * idx + takes_part],
+                              num_keys=len(operands) + 1, is_stable=False)
+    s_pos = s_tag >> 1             # position in the concatenation
+    s_part = (s_tag & 1).astype(bool)
+    s_present = s_part & (s_pos < bcap)    # live build row, non-null key
+    s_probe = s_part & (s_pos >= bcap)     # live probe row, non-null key
 
-    present = cat(b_present, jnp.zeros((pcap,), bool))
-    s_present = present[perm]
-    # Propagate (build source index + 1) forward: one scan yields both
-    # the candidate build row and the seen flag for every sorted slot.
-    src1 = cat(jnp.arange(1, bcap + 1, dtype=jnp.int32),
-               jnp.zeros((pcap,), jnp.int32))
-    ff = fill_forward(jnp.where(s_present, src1[perm], 0), s_present)
-
-    # Duplicate live build keys: adjacent present build rows, equal keys.
-    prev_present = jnp.roll(s_present, 1).at[0].set(False)
+    # Key runs: a slot continues its predecessor's run iff every key
+    # column's stored value is equal (NaN == NaN).
     same_key = jnp.ones((cap,), bool)
-    s_kv = []     # sorted key lanes (value, null) per key — reused below
-    for pc, bc in zip(pcols, bcols):
-        kv = cat(group_values(bc), group_values(pc))[perm]
-        kn = cat(bc.nulls, pc.nulls)[perm]
-        s_kv.append((kv, kn))
-        same_key = same_key & values_equal(kv, jnp.roll(kv, 1)) & ~kn \
-            & ~jnp.roll(kn, 1)
-    dup_count = jnp.sum(s_present & prev_present & same_key
+    for kv in s_keys:
+        same_key = same_key & values_equal(kv, jnp.roll(kv, 1))
+    same_key = same_key.at[0].set(False)
+
+    # One running max answers "which is the last present build row" and
+    # "does it lie in this slot's run" at once: mark run starts 2i and
+    # present rows 2i + 1 in the high word, with the build row below —
+    # the newest mark is odd iff no run began after the newest present
+    # row, and its low word is that row. No gather: a scan is ~1 ms here.
+    hi = (2 * idx).astype(jnp.int64) << 32
+    mark = blocked_cummax(jnp.where(
+        s_present, hi | (jnp.int64(1) << 32) | s_pos.astype(jnp.int64),
+        jnp.where(same_key, jnp.int64(-1), hi)))
+    in_run = ((mark >> 32) & 1).astype(bool)
+    # Duplicate live build keys: a present build row whose run already
+    # holds one (rows that take no part may lie between the two).
+    dup_count = jnp.sum(s_present & same_key & jnp.roll(in_run, 1)
                         ).astype(jnp.int64)
+    s_ff = jnp.where(s_probe & in_run, mark.astype(jnp.int32) + 1, 0)
 
-    # Restore probe order by inverting the permutation: probe row j sits
-    # at sorted slot inv[bcap + j].
-    inv = jnp.argsort(perm)
-    q = inv[bcap:]                               # [pcap]
-    ffq = ff[q]
-    bidx = jnp.maximum(ffq - 1, 0)               # candidate build row
-    match_p = (ffq > 0) & p_live & ~p_null
-    for pc, bc in zip(pcols, bcols):
-        bv = group_values(bc)[bidx]
-        bn = bc.nulls[bidx]
-        match_p = match_p & values_equal(group_values(pc), bv) & ~bn
+    if join_type == "inner":
+        # Matched probe rows first, in probe order: restore + compact.
+        order = jnp.where(s_ff > 0, s_pos, s_pos + cap)
+        r_pos, r_ff = lax.sort((order, s_ff), num_keys=1, is_stable=False)
+        n = jnp.sum(s_ff > 0).astype(jnp.int32)
+        valid = jnp.arange(pcap, dtype=jnp.int32) < n
+        prow = jnp.where(valid, r_pos[:pcap] - bcap, 0)
+        brow = jnp.where(valid, r_ff[:pcap] - 1, 0)
+        cols = _gather_columns(probe.columns, prow, valid) \
+            + _gather_columns(build.columns, brow, valid)
+        return Page(tuple(cols), n, ()), dup_count, None
 
-    # FULL outer also needs per-BUILD-row matched flags: a present build
-    # row is matched iff its key run contains a live non-null-key probe
-    # row. Runs are contiguous after the sort, so count probes per run
-    # with blocked scans — no gathers.
-    b_matched = None
+    payload = [s_ff]
     if join_type == "full":
-        from presto_tpu.ops.scan import cumsum as bl_cumsum
-        from presto_tpu.ops.scan import fill_backward
-
-        is_probe = tag[perm].astype(bool)
-        any_key_null = jnp.zeros((cap,), bool)
-        run_start = jnp.zeros((cap,), bool).at[0].set(True)
-        for kv, kn in s_kv:
-            any_key_null = any_key_null | kn
-            same = (values_equal(kv, jnp.roll(kv, 1))
-                    & ~kn & ~jnp.roll(kn, 1)) \
-                | (kn & jnp.roll(kn, 1))
-            run_start = run_start | ~same
-        run_start = run_start.at[0].set(True)
-        s_live = cat(build.row_valid(), p_live)[perm]
-        probe_contrib = (is_probe & s_live & ~any_key_null
-                         ).astype(jnp.int32)
-        cs_p = bl_cumsum(probe_contrib)
-        before_run = fill_forward(
-            jnp.where(run_start, cs_p - probe_contrib, 0), run_start)
-        run_end = jnp.roll(run_start, -1).at[-1].set(True)
-        at_run_end = fill_backward(jnp.where(run_end, cs_p, 0), run_end)
-        probes_in_run = at_run_end - before_run
-        b_matched_cat = s_present & (probes_in_run > 0)
-        b_matched = b_matched_cat[inv[:bcap]]    # build original order
+        # A present build row is matched iff a live non-null-key probe
+        # row follows it inside its run: the same running max, backward,
+        # over run ends (2i) and such probe rows (2i + 1).
+        run_end = jnp.roll(~same_key, -1).at[-1].set(True)
+        ahead = 2 * (cap - 1 - idx)
+        back = blocked_cummax(jnp.where(
+            s_probe, ahead + 1, jnp.where(run_end, ahead, -1))[::-1])
+        payload.append(s_present & (back[::-1] & 1).astype(bool))
+    _r_pos, *restored = lax.sort([s_pos] + payload, num_keys=1,
+                                 is_stable=False)
+    ffq = restored[0][bcap:]                     # probe order
+    match_p = ffq > 0
 
     if join_type in ("semi", "anti", "anti_exists"):
+        p_live = probe.row_valid()
         if join_type == "semi":
             flag = match_p
         elif join_type == "anti_exists":
@@ -186,24 +296,17 @@ def merge_join(probe: Page, build: Page,
         out = Page(probe.columns + (col,), probe.num_rows, ())
         return out, dup_count, None
 
-    # Build payload lands by direct gather in probe order — nothing is
-    # carried through the sorts at all.
-    out_cols = list(probe.columns)
-    for c in build.columns:
-        out_cols.append(c.gather(bidx, match_p))
-
-    if join_type == "left":
-        return Page(tuple(out_cols), probe.num_rows, ()), dup_count, \
-            match_p
-    if join_type == "full":
-        page = Page(tuple(out_cols), probe.num_rows, ())
-        unmatched = build.row_valid() & ~b_matched
-        out = full_outer_append(page, probe, build, unmatched)
-        return out, dup_count, match_p
-    # inner: keep only matched probe rows.
-    from presto_tpu.data.column import compact
+    # Probe columns stay where they are; the build payload lands by one
+    # gather in probe order.
+    bidx = jnp.maximum(ffq - 1, 0)
+    out_cols = list(probe.columns) \
+        + _gather_columns(build.columns, bidx, match_p)
     page = Page(tuple(out_cols), probe.num_rows, ())
-    return compact(page, match_p), dup_count, None
+    if join_type == "left":
+        return page, dup_count, match_p
+    unmatched = build.row_valid() & ~restored[1][:bcap]
+    return full_outer_append(page, probe, build, unmatched), dup_count, \
+        match_p
 
 
 def full_outer_append(left_page: Page, probe: Page, build: Page,

@@ -136,10 +136,16 @@ def lex_perm(lanes: Sequence[jnp.ndarray]) -> jnp.ndarray:
     significant first, each ascending), via composed STABLE argsorts —
     2-operand sorts only. On this stack a wide variadic lax.sort's
     compile cost explodes with operand count (20 operands at SF1 shapes
-    never finished compiling), while
-    argsort + gather compiles in seconds per lane and gathers run at
-    memory bandwidth; every operator therefore sorts via this helper and
-    gathers its payload by the permutation."""
+    never finished compiling; PR 27 measured ~40 s for two operands and
+    ~25 s for each one more, twice that when stable, for a described
+    v5e), while a gather compiles in under a second; every operator but
+    merge_join therefore sorts via this helper and gathers its payload by
+    the permutation. The gathers are NOT free on the chip: a 1-D gather
+    takes 7-9 ns an element for every 32-bit lane, ~20 ns where the table
+    is out of near memory (50-62 ms for 2-3 M elements; ledger, PR 26),
+    and each `lane[perm]` and `perm[...]` here is one, 64-bit wide under
+    x64 (argsort's iota is int64). ops/join.merge_join shows the way
+    round them: a tag as last key, and the lanes riding the sort."""
     perm = None
     for lane in reversed(list(lanes)):
         if perm is None:

@@ -3,7 +3,7 @@
 import numpy as np
 import pytest
 
-from presto_tpu import BIGINT, DOUBLE, VARCHAR
+from presto_tpu import BIGINT, DATE, DOUBLE, INTEGER, VARCHAR
 from presto_tpu.data.column import Page
 from presto_tpu.ops.join import hash_join, merge_join
 from presto_tpu.ops.scan import cumsum, fill_forward, segment_sums
@@ -130,6 +130,209 @@ def test_merge_join_matches_hash_join_random():
     assert dup == 0
     h, _tot = hash_join(probe, build, [0], [0], 1024, "inner")
     assert sorted(m.to_pylist()) == sorted(h.to_pylist())
+
+
+# One algorithm for every join type and key shape: the equivalence
+# matrix below holds merge_join to a plain Python oracle and to hash_join.
+
+_NAN = float("nan")
+_KEY_SHAPES = {
+    # name: (key types, key domain)
+    "bigint": ((BIGINT,), [(k,) for k in range(100, 112)]),
+    "int_date": ((INTEGER, DATE),
+                 [(i, 9000 + d) for i in range(4) for d in range(3)]),
+    "string": ((VARCHAR,), [(w,) for w in (
+        "ash", "birch", "cedar", "elm", "fir", "larch", "oak", "pine",
+        "rowan", "yew")]),
+    # -0.0 == +0.0 and NaN == NaN are one key each (SQL grouping
+    # semantics, ops/keys.values_equal)
+    "double": ((DOUBLE,), [(v,) for v in (
+        0.0, _NAN, 1.5, -2.25, float("inf"), 7.0, 1e-300, -1e18)]),
+}
+_JOIN_TYPES = ("inner", "left", "full", "semi", "anti", "anti_exists")
+_VARIANTS = ("nulls_both", "nulls_probe", "nulls_build",
+             "dup_between_nulls")
+
+
+def _key_equal(a, b):
+    """Python image of the join's key equality: no NULL, NaN == NaN."""
+    if any(x is None for x in a + b):
+        return False
+    return all(x == y or (x != x and y != y) for x, y in zip(a, b))
+
+
+def _side(keys, stored, types, payload_name, payload_type, payload,
+          live):
+    """A page whose first `live` rows are live and whose other rows are
+    dead but look alive (real values, null flags down); a NULL key
+    physically stores `stored`, a real key of the domain."""
+    import jax.numpy as jnp
+    from presto_tpu.data.column import Column
+    cap = 256
+    cols = []
+    for j, t in enumerate(types):
+        phys = [s[j] if k[j] is None else k[j]
+                for k, s in zip(keys, stored)]
+        if t is VARCHAR:
+            c = Column.from_strings(phys, capacity=cap)
+        else:
+            c = Column.from_numpy(np.asarray(phys, dtype=t.dtype), t,
+                                  capacity=cap)
+        nulls = np.ones(cap, bool)
+        nulls[:len(keys)] = [k[j] is None for k in keys]
+        cols.append(Column(c.values, jnp.asarray(nulls), c.type,
+                           c.dictionary))
+    cols.append(Column.from_numpy(
+        np.asarray(payload, dtype=payload_type.dtype), payload_type,
+        capacity=cap))
+    return Page(tuple(cols), jnp.asarray(live, jnp.int32),
+                tuple(f"k{j}" for j in range(len(types)))
+                + (payload_name,))
+
+
+def _join_case(shape, variant, seed):
+    types, domain = _KEY_SHAPES[shape]
+    rng = np.random.RandomState(seed)
+    nk = len(types)
+    null_probe = variant != "nulls_build"
+    null_build = variant != "nulls_probe"
+
+    def with_null(k):
+        j = rng.randint(nk)
+        return tuple(None if i == j else x for i, x in enumerate(k))
+
+    order = [domain[i] for i in rng.permutation(len(domain))]
+    bkeys = order[:len(domain) - 2]            # unique; two keys absent
+    if shape == "double":                      # probe sends the other zero
+        domain = domain + [(-0.0,)]
+    bstored = list(bkeys)
+    if null_build:
+        # NULL-key rows that physically store a live row's key
+        for at in (1, 4):
+            bkeys.insert(at, with_null(bkeys[at]))
+            bstored.insert(at, bstored[at])
+    if variant == "dup_between_nulls":
+        # k, NULL (storing k), k: only the nulls lane brings the two
+        # live rows together
+        k = bstored[2]
+        bkeys[2:3] = [k, with_null(k), k]
+        bstored[2:3] = [k, k, k]
+    b_live = len(bkeys)
+    bkeys += bstored[:3]                       # dead rows, live keys
+    bstored += bstored[:3]
+
+    pkeys = [domain[i] for i in rng.randint(0, len(domain), 40)]
+    pstored = list(pkeys)
+    if null_probe:
+        for at in (0, 7, 19):
+            pkeys[at] = with_null(pkeys[at])
+    p_live = len(pkeys)
+    pkeys += pstored[:5]
+    pstored += pstored[:5]
+
+    probe = _side(pkeys, pstored, types, "pv", DOUBLE,
+                  [i + 0.5 for i in range(len(pkeys))], p_live)
+    build = _side(bkeys, bstored, types, "bw", BIGINT,
+                  [1000 + i for i in range(len(bkeys))], b_live)
+    return probe, build, pkeys[:p_live], bkeys[:b_live]
+
+
+def _norm(rows):
+    return sorted(tuple(repr(v) for v in r) for r in rows)
+
+
+def _oracle(jt, prows, brows, pkeys, bkeys):
+    none_p = (None,) * len(prows[0])
+    none_b = (None,) * len(brows[0])
+    hits = [[j for j, bk in enumerate(bkeys) if _key_equal(pk, bk)]
+            for pk in pkeys]
+    if jt in ("semi", "anti", "anti_exists"):
+        build_null = any(None in bk for bk in bkeys)
+        out = []
+        for r, pk, h in zip(prows, pkeys, hits):
+            if jt == "semi":
+                flag = bool(h)
+            elif jt == "anti_exists":
+                flag = not h
+            else:
+                flag = not h and None not in pk and not build_null
+            out.append(r + (flag,))
+        return out
+    out = [r + brows[j] for r, h in zip(prows, hits) for j in h]
+    if jt in ("left", "full"):
+        out += [r + none_b for r, h in zip(prows, hits) if not h]
+    if jt == "full":
+        seen = {j for h in hits for j in h}
+        out += [none_p + r for j, r in enumerate(brows) if j not in seen]
+    return out
+
+
+@pytest.mark.parametrize("variant", _VARIANTS)
+@pytest.mark.parametrize("shape", list(_KEY_SHAPES))
+@pytest.mark.parametrize("jt", _JOIN_TYPES)
+def test_merge_join_equivalence(jt, shape, variant):
+    probe, build, pkeys, bkeys = _join_case(
+        shape, variant, seed=len(jt) * 31 + len(shape) * 7 + len(variant))
+    fields = list(range(len(_KEY_SHAPES[shape][0])))
+    out, dup, match = merge_join(probe, build, fields, fields, jt)
+    assert out.capacity == probe.capacity + (
+        build.capacity if jt == "full" else 0)
+    if variant == "dup_between_nulls":
+        assert int(dup) > 0
+        if jt in ("inner", "left", "full"):
+            return          # the caller re-lowers onto hash_join
+    else:
+        assert int(dup) == 0
+    got = _norm(out.to_pylist())
+    want = _oracle(jt, probe.to_pylist(), build.to_pylist(), pkeys, bkeys)
+    assert got == _norm(want)
+    if jt in ("left", "full"):
+        hit = np.asarray(match)[:len(pkeys)]
+        assert hit.tolist() == [
+            any(_key_equal(pk, bk) for bk in bkeys) for pk in pkeys]
+    if jt != "full":        # the expansion path has no full-outer form
+        h, _total = hash_join(probe, build, fields, fields, 1024, jt)
+        assert _norm(h.to_pylist()) == got
+
+
+def test_merge_join_census():
+    """The lanes ride the sorts, the payload is gathered once: a one-key
+    inner merge_join holds two sorts over the concatenation and no gather
+    over it, no sort carries a 64-bit lane that is not a key, and each
+    side's columns move in one gather of their words and one of their
+    DOUBLEs — so a later edit cannot quietly bring back the per-lane
+    gathers (15-60 ms each on the chip) or argsort's int64 iota."""
+    import jax
+    probe = _page({"k": list(range(300)), "a": [1.0] * 300,
+                   "b": [2] * 300}, {"k": BIGINT, "a": DOUBLE, "b": BIGINT})
+    build = _page({"k": list(range(200)), "w": [3.0] * 200},
+                  {"k": BIGINT, "w": DOUBLE})
+    cap = probe.capacity + build.capacity
+    assert probe.capacity != build.capacity
+
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for v in e.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else [v]:
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from eqns(inner)
+
+    closed = jax.make_jaxpr(
+        lambda p, b: merge_join(p, b, [0], [0], "inner")[:2])(probe, build)
+    sorts = gathers = 0
+    for e in eqns(closed.jaxpr):
+        if e.primitive.name == "sort":
+            wide = [v.aval.dtype.itemsize > 4 for v in e.invars]
+            assert not any(wide[e.params["num_keys"]:]), e
+            assert not e.params["is_stable"], e
+            sorts += cap in e.invars[0].aval.shape
+        elif e.primitive.name == "gather":
+            assert cap not in e.invars[0].aval.shape, e
+            assert cap not in e.outvars[0].aval.shape, e
+            gathers += 1
+    assert (sorts, gathers) == (2, 4)
 
 
 def test_fragmenter_structure():

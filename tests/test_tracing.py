@@ -7,6 +7,7 @@ Pure and quick: no cluster here (the served path's spans are held in
 tests/test_metrics.py, on its cluster)."""
 
 import glob
+import itertools
 import os
 import re
 import threading
@@ -252,6 +253,36 @@ def test_the_device_sees_the_name_and_the_operators(engine):
     assert {s.attributes["table"] for s in uploads} == {"orders", "lineitem"}
     assert all(s.attributes["bytes"] + s.attributes.get("resident", 0) > 0
                for s in uploads)
+
+
+@pytest.mark.parametrize("sql, paths", [
+    # FK join, unique build keys: merge_join answers
+    (JOIN_SQL, ["merge"]),
+    # duplicate keys on both sides: the dup counter re-lowers the program
+    # onto the expansion join, and the second dispatch says so
+    ("select count(*) from orders join lineitem on l_suppkey = o_custkey",
+     ["merge", "expansion"]),
+    # semi joins never fall back; a cross join never merges
+    ("select count(*) from orders where o_custkey in "
+     "(select l_suppkey from lineitem)", ["merge"]),
+    ("select count(*) from nation, region", ["expansion"]),
+], ids=["unique_build", "duplicate_build", "semi", "cross"])
+def test_dispatch_says_how_it_joins(engine, request, sql, paths):
+    """`join_paths` on the `dispatch` span: what can silently bypass
+    merge_join is the re-lowering onto hash_join, and the span names it."""
+    from presto_tpu.exec.executor import Executor
+    ex = Executor(engine.connector)
+    trace_id = "join_paths_" + request.node.callspec.id
+    with trace_scope(trace_id, ""):
+        ex.execute(engine.plan_sql(sql))
+    dispatched = [s.attributes for s in TRACER.get(trace_id)
+                  if s.name == "dispatch"]
+    # (a run of one path: the expansion join may grow its capacity and
+    # dispatch again)
+    seen = [a["join_paths"] for a in dispatched if "join_paths" in a]
+    assert [path for path, _run in itertools.groupby(seen)] == paths
+    assert all("join_paths" not in a for a in dispatched
+               if "Join" not in a["operators"].split("+"))
 
 
 def test_upload_counts_only_what_moves(engine):
