@@ -773,9 +773,13 @@ def gather_page(page: Page, idx: jnp.ndarray,
                 page.names if names is None else names)
 
 
-def compact(page: Page, keep: jnp.ndarray) -> Page:
+def compact(page: Page, keep: jnp.ndarray,
+            capacity: Optional[int] = None) -> Page:
     """Stable-partition rows where `keep` is True to the front; the result's
     num_rows is the survivor count. This is the engine's filter primitive.
+    With a smaller `capacity` the result holds only that many slots (the
+    first survivors; the caller watches the count for overflow), and the
+    gathers fetch only that many elements.
 
     Implemented as ONE 2-operand argsort on the order key + per-column
     gathers: on this stack gathers compile in under a second, while a
@@ -796,8 +800,10 @@ def compact(page: Page, keep: jnp.ndarray) -> Page:
     # Stable order: non-survivors get index offset + capacity.
     order_key = (jnp.where(keep, 0, cap).astype(jnp.int32)
                  + jnp.arange(cap, dtype=jnp.int32))
-    n = jnp.sum(keep).astype(jnp.int32)
-    valid = jnp.arange(cap, dtype=jnp.int32) < n
     perm = jnp.argsort(order_key)        # distinct keys: stability free
+    if capacity is not None and capacity < cap:
+        perm, cap = perm[:capacity], capacity
+    n = jnp.minimum(jnp.sum(keep), cap).astype(jnp.int32)
+    valid = jnp.arange(cap, dtype=jnp.int32) < n
     cols = [c.gather(perm, valid) for c in page.columns]
     return Page(tuple(cols), n, page.names)

@@ -163,6 +163,10 @@ class Executor:
         #: "merge" (ops/join.merge_join) or "expansion" (hash_join: cross
         #: joins, merge_join_enabled off, duplicate build keys seen)
         self.last_join_paths: List[str] = []
+        #: beside it, each JoinNode's type (INNER, LEFT, FULL, SEMI, ANTI)
+        #: and each AggregationNode's step (PARTIAL, FINAL, SINGLE)
+        self.last_join_types: List[str] = []
+        self.last_agg_steps: List[str] = []
         # Optional MemoryPool (exec/memory.py): static footprints
         # reserve against it at lower time (admission control BEFORE
         # execution — the TPU analog of MemoryPool.java's runtime
@@ -597,6 +601,9 @@ class Executor:
                      "operators": "+".join(_operators(plan))}
             if self.last_join_paths:
                 about["join_paths"] = "+".join(self.last_join_paths)
+                about["join_types"] = "+".join(self.last_join_types)
+            if self.last_agg_steps:
+                about["agg_steps"] = "+".join(self.last_agg_steps)
             entry = (jax.jit(program), scans, watch, [], about)
             self._compiled[key] = entry
             self._note_compile(plan)
@@ -953,6 +960,7 @@ class Executor:
                     out_cap = 256
                 caps[nid] = out_cap
                 watch.append(nid)
+                agg_steps.append(node.step.name)
 
                 def agg_fn(pages, node=node, out_cap=out_cap, steps=steps):
                     p = src(pages)
@@ -976,13 +984,27 @@ class Executor:
             if isinstance(node, JoinNode):
                 psrc, pcap = build(node.probe)
                 bsrc, bcap = build(node.build)
+                # ANTI_EXISTS is an ANTI join to whoever reads the span
+                join_types.append(node.join_type.name.split("_")[0])
                 if node.join_type in (JoinType.SEMI, JoinType.ANTI,
                                       JoinType.ANTI_EXISTS):
                     # Merge path: duplicates can't change a match flag,
                     # so no fallback is ever needed here.
                     join_paths.append("merge")
+                    out_cap = pcap
+                    if not node.emit_flag:
+                        # A filtering semi join may keep a sliver of its
+                        # probe (TPC-H Q18: 378 of 6M rows): the
+                        # survivors' capacity is learned like a join's,
+                        # so what runs above it is not paid at the
+                        # probe's size. It starts at the probe's (no
+                        # overflow), anneals to the count seen, and an
+                        # undershoot re-runs through the overflow loop.
+                        out_cap = caps.get(nid) or pcap
+                        caps[nid] = out_cap
+                        watch.append(nid)
 
-                    def semi_fn(pages, node=node):
+                    def semi_fn(pages, node=node, out_cap=out_cap):
                         p = psrc(pages)
                         b = bsrc(pages)
                         out, _dup, _m = merge_join(
@@ -993,13 +1015,13 @@ class Executor:
                             # probe row, expose the flag column.
                             return Page(out.columns, out.num_rows,
                                         node.output_names)
-                        flag = out.columns[-1]
-                        filtered = compact(
+                        keep = out.columns[-1].values.astype(bool) \
+                            & out.row_valid()
+                        _needed.append(jnp.sum(keep))
+                        return compact(
                             Page(out.columns[:-1], out.num_rows,
-                                 node.output_names),
-                            flag.values.astype(bool))
-                        return filtered
-                    return semi_fn, pcap
+                                 node.output_names), keep, out_cap)
+                    return semi_fn, out_cap
 
                 # Unique-build merge join first (two sorts + scans; the
                 # TPU-fast path — TPC-H joins are FK joins). The dup
@@ -1277,9 +1299,13 @@ class Executor:
 
         _needed: List = []
         join_paths: List[str] = []
+        join_types: List[str] = []
+        agg_steps: List[str] = []
         root, _cap = build(plan)
         self.last_memory_estimate = mem_bytes[0]
         self.last_join_paths = join_paths
+        self.last_join_types = join_types
+        self.last_agg_steps = agg_steps
         if self.memory_limit_bytes is not None \
                 and mem_bytes[0] > self.memory_limit_bytes:
             raise MemoryLimitExceeded(mem_bytes[0],

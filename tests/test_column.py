@@ -57,3 +57,26 @@ def test_compact():
     out = compact(p, keep)
     assert int(out.num_rows) == 3
     assert out.to_pylist() == [(1,), (3,), (5,)]
+
+
+@pytest.mark.parametrize("capacity", [768, 1024, 256, 2048], ids=[
+    "smaller", "as_it_was", "overflowing", "larger_is_ignored"])
+def test_compact_into_a_smaller_capacity(capacity):
+    """`capacity` cuts the result to that many slots (what a filtering
+    semi join's learned capacity asks for): the first survivors in
+    order, num_rows clamped, and never more slots than the page had."""
+    import jax.numpy as jnp
+    n = 1000
+    vals = list(range(n))
+    p = Page.from_pydict({"a": vals, "s": [f"w{v % 7}" for v in vals]},
+                         {"a": BIGINT, "s": VARCHAR})
+    assert p.capacity == 1024
+    mask = np.ones(p.capacity, dtype=bool)    # padding never survives
+    mask[:n] = np.arange(n) % 5 != 1
+    mask[700:n] = False
+    want = [(v, f"w{v % 7}") for v in vals if mask[v]]
+    assert len(want) == 560
+    out = compact(p, jnp.asarray(mask), capacity)
+    assert out.capacity == min(capacity, 1024)
+    assert int(out.num_rows) == min(560, capacity)
+    assert out.to_pylist() == want[:capacity]

@@ -97,7 +97,11 @@ def run_case(qnum, engine, oracle):
                 assert x == y, f"Q{qnum} row {i} col {j}: {x!r} != {y!r}"
 
 
-@pytest.mark.parametrize("qnum", sorted(QUERIES))
+# Tier-1 runs under xdist's `--dist loadfile`: a file is one worker's. All
+# 99 queries in this file were 1,381 s of a 1,394 s run against a limit of
+# 1,470 s, whatever the other five workers did, so the even-numbered ones
+# run from `test_tpcds_even.py` (same cases, same fixtures, own worker).
+@pytest.mark.parametrize("qnum", [q for q in sorted(QUERIES) if q % 2])
 def test_tpcds(qnum, engine, oracle):
     run_case(qnum, engine, oracle)
 

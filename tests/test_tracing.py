@@ -4,7 +4,8 @@ sink (a span under the JAX profiler comes back from the `.xplane.pb` as
 `presto:<name>` with its attributes), the names the executor gives its
 device programs, and the true placement of the worker's `op:` spans.
 Pure and quick: no cluster here (the served path's spans are held in
-tests/test_metrics.py, on its cluster)."""
+tests/test_metrics.py, on its cluster; TPC-H Q18's `join_types` and
+`agg_steps` through two workers in tests/test_q18_served.py, on its)."""
 
 import glob
 import itertools
@@ -255,21 +256,23 @@ def test_the_device_sees_the_name_and_the_operators(engine):
                for s in uploads)
 
 
-@pytest.mark.parametrize("sql, paths", [
+@pytest.mark.parametrize("sql, paths, types", [
     # FK join, unique build keys: merge_join answers
-    (JOIN_SQL, ["merge"]),
+    (JOIN_SQL, ["merge"], "INNER"),
     # duplicate keys on both sides: the dup counter re-lowers the program
     # onto the expansion join, and the second dispatch says so
     ("select count(*) from orders join lineitem on l_suppkey = o_custkey",
-     ["merge", "expansion"]),
+     ["merge", "expansion"], "INNER"),
     # semi joins never fall back; a cross join never merges
     ("select count(*) from orders where o_custkey in "
-     "(select l_suppkey from lineitem)", ["merge"]),
-    ("select count(*) from nation, region", ["expansion"]),
+     "(select l_suppkey from lineitem)", ["merge"], "SEMI"),
+    ("select count(*) from nation, region", ["expansion"], "INNER"),
 ], ids=["unique_build", "duplicate_build", "semi", "cross"])
-def test_dispatch_says_how_it_joins(engine, request, sql, paths):
+def test_dispatch_says_how_it_joins(engine, request, sql, paths, types):
     """`join_paths` on the `dispatch` span: what can silently bypass
-    merge_join is the re-lowering onto hash_join, and the span names it."""
+    merge_join is the re-lowering onto hash_join, and the span names it.
+    Beside it `join_types`, a JoinNode each, and `agg_steps`, an
+    AggregationNode each; neither on a program that holds none."""
     from presto_tpu.exec.executor import Executor
     ex = Executor(engine.connector)
     trace_id = "join_paths_" + request.node.callspec.id
@@ -281,8 +284,15 @@ def test_dispatch_says_how_it_joins(engine, request, sql, paths):
     # dispatch again)
     seen = [a["join_paths"] for a in dispatched if "join_paths" in a]
     assert [path for path, _run in itertools.groupby(seen)] == paths
-    assert all("join_paths" not in a for a in dispatched
-               if "Join" not in a["operators"].split("+"))
+    assert {a["join_types"] for a in dispatched
+            if "join_types" in a} == {types}
+    for a in dispatched:
+        ops = a["operators"].split("+")
+        assert ("join_types" in a) == ("Join" in ops) == ("join_paths" in a)
+        assert ("agg_steps" in a) == ("Aggregation" in ops)
+        if "agg_steps" in a:
+            # one executor runs the whole plan: no step but SINGLE
+            assert set(a["agg_steps"].split("+")) == {"SINGLE"}
 
 
 def test_upload_counts_only_what_moves(engine):
