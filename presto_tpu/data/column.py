@@ -28,6 +28,9 @@ from __future__ import annotations
 
 import dataclasses
 import decimal as _decimal
+import hashlib
+import itertools
+import struct
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 import jax
@@ -84,10 +87,46 @@ class StringDict:
     pytree aux data without hashing millions of strings per jit-cache lookup;
     keep one instance per table column and reuse it."""
 
-    __slots__ = ("words",)
+    __slots__ = ("words", "sparse", "_wire")
 
-    def __init__(self, words: Sequence[str]):
+    def __init__(self, words: Sequence[str], sparse: bool = False):
         self.words: Tuple[str, ...] = tuple(words)
+        #: the pages that carry this dictionary may use only some of its
+        #: words: it is a dictionary as it crossed the wire, shared by
+        #: every page that named it (protocol/serde). Whoever fuses such
+        #: pages, or hands one on as a page of its own, compacts
+        #: (`compact_string_dict`)
+        self.sparse = sparse
+        self._wire = None
+
+    @property
+    def has_wire_form(self) -> bool:
+        return self._wire is not None
+
+    def wire_form(self) -> Tuple[np.ndarray, np.ndarray, Tuple[int, int]]:
+        """The dictionary as a VARIABLE_WIDTH block holds it: the int32
+        end offset of every word, the words' UTF-8 bytes joined, and a
+        128-bit digest of both as two int64 — what names this dictionary
+        to a receiver. Built on first use and kept: a dictionary lives as
+        long as its column, and every page sent with it needs the same
+        bytes. An empty dictionary goes as the one word ""."""
+        if self._wire is None:
+            words = self.words or ("",)
+            text = "".join(words)
+            payload = text.encode()
+            if len(payload) == len(text):      # ASCII: bytes == characters
+                lens = map(len, words)
+            else:
+                lens = (len(w.encode()) for w in words)
+            ends = np.cumsum(np.fromiter(lens, np.int64, len(words))
+                             ).astype(np.int32)
+            payload = np.frombuffer(payload, dtype=np.uint8)
+            digest = hashlib.blake2b(digest_size=16)
+            digest.update(ends)
+            digest.update(payload)
+            self._wire = (ends, payload,
+                          struct.unpack("<qq", digest.digest()))
+        return self._wire
 
     def __len__(self) -> int:
         return len(self.words)
@@ -623,6 +662,12 @@ def merge_string_dicts(dicts: Sequence[Optional[StringDict]]
     scans) become comparable on codes again — the cross-page dictionary
     story the round-1 review flagged (reference role: the Block layer's
     DictionaryBlock id spaces are also per-block and re-resolved on use)."""
+    first = dicts[0] if dicts else None
+    if first is not None and all(d is first for d in dicts):
+        # one object on every page (a table's dictionary, or one the
+        # wire decode handed to all of them): nothing to union
+        same = np.arange(len(first), dtype=np.int32)
+        return first, [same] * len(dicts)
     word_lists = [list(d.words) if d is not None else [] for d in dicts]
     union = sorted(set().union(*[set(w) for w in word_lists]))
     union_arr = np.asarray(union, dtype=object).astype(str)
@@ -636,6 +681,31 @@ def merge_string_dicts(dicts: Sequence[Optional[StringDict]]
             union_arr, np.asarray(words, dtype=object).astype(str)
         ).astype(np.int32))
     return out, remaps
+
+
+def compact_string_dict(dictionary: StringDict, codes: np.ndarray,
+                        nulls: np.ndarray) -> Tuple[StringDict, np.ndarray]:
+    """The dictionary of exactly the words that the rows use, and the
+    rows' codes into it: one pass of arrays over the rows and one over
+    the words. What a page decoded from the wire has always carried (a
+    sorted dictionary of the words present), and what
+    `ops/aggregate._direct_domains` and every lowering that reads
+    `len(dictionary)` therefore see. A null string crosses the wire as a
+    null slot and decodes as "", so "" stays where a row is null. Always
+    a new StringDict, as a decoded page's has always been."""
+    k = len(dictionary)
+    if not k:
+        return StringDict(()), codes
+    some_null = bool(nulls.any())
+    used = np.zeros(k, dtype=bool)
+    used[codes[~nulls] if some_null else codes] = True
+    if some_null and dictionary.words[0] == "":
+        used[0] = True
+    remap = np.cumsum(used, dtype=np.int32) - 1
+    words = itertools.compress(dictionary.words, used.tolist())
+    # null rows hold the sort sentinel once they have been a Column
+    codes = remap[np.where(nulls, 0, codes) if some_null else codes]
+    return StringDict(words), codes
 
 
 def concat_pages_host(pages: Sequence[Page],
@@ -680,19 +750,32 @@ def concat_pages_host(pages: Sequence[Page],
             cols.append(NestedColumn.from_pylist(pyvals, c0.type, cap))
             continue
         if c0.type.is_string:
-            union, remaps = merge_string_dicts(
-                [p.columns[ci].dictionary for p in pages])
-            for p, remap in zip(pages, remaps):
-                v, nl = p.columns[ci].to_numpy(int(p.num_rows))
-                if len(remap):
+            parts = [(p.columns[ci].dictionary,
+                      *p.columns[ci].to_numpy(int(p.num_rows)))
+                     for p in pages]
+            d0 = parts[0][0]
+            if any(d is not d0 for d, _v, _nl in parts):
+                # a dictionary from the wire holds words its page does
+                # not use: union the words in use, as before
+                parts = [(*compact_string_dict(d, v, nl), nl)
+                         if d is not None and d.sparse else (d, v, nl)
+                         for d, v, nl in parts]
+            union, remaps = merge_string_dicts([d for d, _v, _nl in parts])
+            for (d, v, nl), remap in zip(parts, remaps):
+                if d is not union and len(remap):
                     v = remap[np.clip(v, 0, len(remap) - 1)]
                 vals_parts.append(v)
                 null_parts.append(nl)
+            vals = (np.concatenate(vals_parts) if vals_parts else
+                    np.zeros(0, np.int32))
+            nulls = np.concatenate(null_parts)
+            if union.sparse:
+                # every page brought the one wire dictionary: compact
+                # once, for the fused page
+                union, vals = compact_string_dict(union, vals, nulls)
             cols.append(Column.from_numpy(
-                np.concatenate(vals_parts) if vals_parts else
-                np.zeros(0, np.int32),
-                c0.type, nulls=np.concatenate(null_parts),
-                dictionary=union, capacity=cap))
+                vals, c0.type, nulls=nulls, dictionary=union,
+                capacity=cap))
         else:
             for p in pages:
                 v, nl = p.columns[ci].to_numpy(int(p.num_rows))

@@ -248,7 +248,11 @@ class PageStream:
 
 
 def decode_pages(data: bytes, types) -> List:
-    """Concatenated wire frames -> engine Pages."""
+    """Concatenated wire frames -> engine Pages of an exchange. A string
+    column keeps its dictionary as it crossed the wire (`sparse`, one
+    object for all the pages that name it): a consumer fuses what it
+    pulls (`concat_pages_host`, which compacts once for the fused
+    page), and the root reads rows by code."""
     from presto_tpu.protocol.serde import (
         decode_serialized_page, wire_blocks_to_page,
     )
@@ -257,5 +261,6 @@ def decode_pages(data: bytes, types) -> List:
     off = 0
     while off < len(data):
         blocks, n, off = decode_serialized_page(data, off)
-        pages.append(wire_blocks_to_page(blocks, types, n))
+        pages.append(wire_blocks_to_page(blocks, types, n,
+                                         compact_strings=False))
     return pages

@@ -31,12 +31,20 @@ Zero-copy contract (the PageBuffer data plane):
     pair, with a payload-relative block-offsets table for writev-style
     consumers.
   * decode returns READ-ONLY `np.frombuffer` views over the received
-    frame: fixed-width lanes, int128 lanes, nested offsets and
-    dictionary ids alias the frame's memory, and each view's `.base`
-    pins the frame alive as long as any decoded block lives. The only
-    sanctioned copies — null-mask scatter, decompression, and
-    VARIABLE_WIDTH value slicing — are counted in
+    frame: fixed-width lanes, int128 lanes, nested offsets, dictionary
+    ids and a VARIABLE_WIDTH block's end offsets and payload alias the
+    frame's memory, and each view's `.base` pins the frame alive as
+    long as any decoded block lives. The only sanctioned copies —
+    null-mask scatter, decompression, and VARIABLE_WIDTH value slicing
+    for a caller that reads `.values` — are counted in
     `page_copy_fallback_total{site}` and still come back read-only.
+  * a string dictionary crosses once: a `StringDict` keeps its
+    VARIABLE_WIDTH form and a digest of it (`StringDict.wire_form`),
+    the digest travels as the DICTIONARY block's instance id, and the
+    receiver keeps the decoded dictionary under that id
+    (`_DICT_CACHE`). A page's rows then move as integer arrays on both
+    sides; `presto_tpu_serde_dictionary_total{side,result}` says how
+    often.
   * `analysis/rules.py` (`no-page-copy-in-data-plane`) polices the
     contract: `.tobytes()` / `frombuffer(...).copy()` under `protocol/`
     and `spool/` only at the sanctioned sites in this file.
@@ -44,8 +52,11 @@ Zero-copy contract (the PageBuffer data plane):
 
 from __future__ import annotations
 
-import dataclasses
+import collections
+import itertools
+import operator
 import struct
+import threading
 import time
 import zlib
 from typing import List, Optional, Tuple
@@ -54,6 +65,7 @@ import numpy as np
 
 from presto_tpu.obs.metrics import counter as _counter, \
     histogram as _histogram
+from presto_tpu.utils.tracing import TRACER
 
 COMPRESSED = 1
 ENCRYPTED = 2
@@ -76,29 +88,71 @@ _COPY_FALLBACK = _counter(
     "presto_tpu_page_copy_fallback_total",
     "Sanctioned data-plane copies by site (null_scatter, decompress, "
     "varwidth)", labelnames=("site",))
+_DICTIONARY = _counter(
+    "presto_tpu_serde_dictionary_total",
+    "String dictionaries that crossed the wire codec: a hit found the "
+    "dictionary's wire form (encode) or its decoded words (decode) "
+    "kept from an earlier page, a miss built them",
+    labelnames=("side", "result"))
 _ENCODE_SECONDS = _histogram(
     "presto_tpu_serde_encode_seconds", "Wall time per encode_serialized_page call")
 _DECODE_SECONDS = _histogram(
     "presto_tpu_serde_decode_seconds", "Wall time per decode_serialized_page call")
 
 
-@dataclasses.dataclass
+#: a DICTIONARY block's instance id when the sender names none
+_NO_ID = (0, 0, 0)
+
+
 class WireBlock:
     """Decoded block: fixed-width values + null mask, or nested forms."""
-    encoding: str
-    values: Optional[np.ndarray] = None      # fixed-width lanes
-    nulls: Optional[np.ndarray] = None       # bool, True = NULL
-    # VARIABLE_WIDTH: values is dtype=object array of bytes
-    # DICTIONARY: ids in values, dictionary block nested
-    dictionary: Optional["WireBlock"] = None
-    # RLE: single-position value block + count
-    rle_value: Optional["WireBlock"] = None
-    count: int = 0
-    # ARRAY: children=[elements]; MAP: children=[keys, values];
-    # ROW: children=[field0, field1, ...] — with per-position offsets
-    # (n+1 int32, rebased to 0, reference ArrayBlockEncoding.java layout)
-    children: Optional[List["WireBlock"]] = None
-    offsets: Optional[np.ndarray] = None
+
+    __slots__ = ("encoding", "_values", "nulls", "dictionary", "rle_value",
+                 "count", "children", "offsets", "ends", "payload",
+                 "instance_id")
+
+    def __init__(self, encoding: str,
+                 values: Optional[np.ndarray] = None,
+                 nulls: Optional[np.ndarray] = None,
+                 dictionary: Optional["WireBlock"] = None,
+                 rle_value: Optional["WireBlock"] = None,
+                 count: int = 0,
+                 children: Optional[List["WireBlock"]] = None,
+                 offsets: Optional[np.ndarray] = None,
+                 ends: Optional[np.ndarray] = None,
+                 payload: Optional[np.ndarray] = None,
+                 instance_id: Tuple[int, int, int] = _NO_ID):
+        self.encoding = encoding
+        self._values = values                # fixed-width lanes
+        self.nulls = nulls                   # bool, True = NULL
+        # DICTIONARY: ids in values, dictionary block nested, and the
+        # sender's (most, least significant bits, sequence) id of it
+        self.dictionary = dictionary
+        self.instance_id = instance_id
+        # RLE: single-position value block + count
+        self.rle_value = rle_value
+        self.count = count
+        # ARRAY: children=[elements]; MAP: children=[keys, values];
+        # ROW: children=[field0, field1, ...] — with per-position offsets
+        # (n+1 int32, rebased to 0, reference ArrayBlockEncoding.java
+        # layout)
+        self.children = children
+        self.offsets = offsets
+        # VARIABLE_WIDTH in array form: int32 end offset per position
+        # into the uint8 payload (what the wire holds). A block has this
+        # form, or `values` as a dtype=object array of bytes, or both
+        self.ends = ends
+        self.payload = payload
+
+    @property
+    def values(self) -> Optional[np.ndarray]:
+        if self._values is None and self.ends is not None:
+            self._values = _slice_values(self.ends, self.payload,
+                                         self.nulls)
+        return self._values
+
+    def __repr__(self) -> str:
+        return f"WireBlock({self.encoding}, n={self.position_count})"
 
     @property
     def position_count(self) -> int:
@@ -106,7 +160,30 @@ class WireBlock:
             return self.count
         if self.offsets is not None:
             return len(self.offsets) - 1
-        return len(self.values)
+        if self._values is None and self.ends is not None:
+            return len(self.ends)
+        return len(self._values)
+
+
+def _slices(ends: np.ndarray):
+    """The slice of a VARIABLE_WIDTH payload that each position holds."""
+    stops = ends.tolist()
+    return map(slice, [0] + stops[:-1], stops)
+
+
+def _slice_values(ends: np.ndarray, payload: np.ndarray,
+                  nulls: Optional[np.ndarray]) -> np.ndarray:
+    """A VARIABLE_WIDTH block's positions as `bytes` objects (None where
+    null): one object per position, so only for a caller that reads
+    single values — a constant, an RLE value, a golden-bytes test. The
+    string columns of a page never come this way (`_decode_words`)."""
+    _COPY_FALLBACK.inc(site="varwidth")
+    vals = np.empty(len(ends), dtype=object)
+    vals[:] = list(map(payload.tobytes().__getitem__, _slices(ends)))
+    if nulls is not None:
+        vals[nulls] = None
+    vals.setflags(write=False)
+    return vals
 
 
 class PageBuffer:
@@ -300,15 +377,24 @@ def _encode_block(w: _PageWriter, b: WireBlock):
             vals = vals[~b.nulls]
         w.put_array(vals)
     elif b.encoding == "VARIABLE_WIDTH":
-        n = len(b.values)
+        if b.ends is not None:
+            # the array form goes out as it is: no bytes objects
+            n = len(b.ends)
+            ends, payload = b.ends, b.payload
+        else:
+            n = len(b.values)
+            lens = np.array([0 if v is None else len(v) for v in b.values],
+                            dtype=np.int64)
+            ends = np.cumsum(lens).astype(np.int32)
+            payload = b"".join(v for v in b.values if v is not None)
         w.put(struct.pack("<i", n))
-        lens = np.array([0 if v is None else len(v) for v in b.values],
-                        dtype=np.int64)
-        w.put_array(np.cumsum(lens).astype(np.int32))
+        w.put_array(ends)
         _encode_nulls(w, b.nulls, n)
-        payload = b"".join(v for v in b.values if v is not None)
         w.put(struct.pack("<i", len(payload)))
-        w.put_bytes(payload)
+        if isinstance(payload, np.ndarray):
+            w.put_array(payload)
+        else:
+            w.put_bytes(payload)
     elif b.encoding == "ARRAY":
         # reference ArrayBlockEncoding.java: elements block, then
         # positionCount, offsets[n+1] rebased to 0, null bits
@@ -347,8 +433,9 @@ def _encode_block(w: _PageWriter, b: WireBlock):
         _encode_block(w, b.dictionary)
         w.put_array(np.ascontiguousarray(b.values, dtype=np.int32))
         # dictionary instance id (most/least significant bits, sequence);
-        # receivers only use it for caching — send a fixed id
-        w.put(struct.pack("<qqq", 0, 0, 0))
+        # receivers only use it for caching: a digest of the dictionary
+        # block where the sender has one (`_flat_to_wire`), else zeros
+        w.put(struct.pack("<qqq", *b.instance_id))
     else:
         raise ValueError(f"unsupported encoding {b.encoding}")
 
@@ -382,27 +469,17 @@ def _decode_block(buf: memoryview, off: int) -> Tuple[WireBlock, int]:
     if name == "VARIABLE_WIDTH":
         (n,) = struct.unpack_from("<i", buf, off)
         off += 4
-        offsets = _view(buf, off, np.int32, n)
+        ends = _view(buf, off, np.int32, n)
         off += 4 * n
         nulls, off = _decode_nulls(buf, off, n)
         (total,) = struct.unpack_from("<i", buf, off)
         off += 4
-        # per-value bytes objects: downstream string decode needs real
-        # bytes (`.decode()`), so this lane is a sanctioned copy
-        payload = bytes(buf[off:off + total])
+        # views: per-value bytes objects are made only for a caller
+        # that reads `.values` (WireBlock.values)
+        payload = _view(buf, off, np.uint8, total)
         off += total
-        _COPY_FALLBACK.inc(site="varwidth")
-        vals = np.empty(n, dtype=object)
-        prev = 0
-        for i in range(n):
-            end = int(offsets[i])
-            if nulls is not None and nulls[i]:
-                vals[i] = None
-            else:
-                vals[i] = payload[prev:end]
-            prev = end
-        vals.setflags(write=False)
-        return WireBlock(name, vals, nulls), off
+        return WireBlock(name, nulls=nulls, ends=ends,
+                         payload=payload), off
     if name == "ARRAY":
         elements, off = _decode_block(buf, off)
         (n,) = struct.unpack_from("<i", buf, off)
@@ -451,8 +528,10 @@ def _decode_block(buf: memoryview, off: int) -> Tuple[WireBlock, int]:
         dictionary, off = _decode_block(buf, off)
         ids = _view(buf, off, np.int32, n)
         off += 4 * n
-        off += 24  # instance id
-        return WireBlock("DICTIONARY", ids, None, dictionary=dictionary), off
+        instance_id = struct.unpack_from("<qqq", buf, off)
+        off += 24
+        return WireBlock("DICTIONARY", ids, None, dictionary=dictionary,
+                         instance_id=instance_id), off
     raise ValueError(f"unsupported encoding {name}")
 
 
@@ -652,21 +731,25 @@ def decode_serialized_page(data, offset: int = 0
 def _flat_to_wire(t, vals: np.ndarray, nulls: np.ndarray,
                   dictionary) -> WireBlock:
     if t.is_string and dictionary is not None:
-        words = np.array(
-            [w.encode() for w in dictionary.words] or [b""],
-            dtype=object)
-        dict_block = WireBlock("VARIABLE_WIDTH", words, None)
+        _note_dictionary("encode", dictionary.has_wire_form)
+        ends, payload, (most, least) = dictionary.wire_form()
         ids = np.where(nulls, 0, vals).astype(np.int32)
+        slot_nulls, sequence = None, 0
         # Presto represents a null string position as a null slot in
-        # the dictionary; simplest faithful form: append a null slot.
+        # the dictionary; simplest faithful form: append a null slot
+        # (zero bytes long) to the kept arrays. The id's sequence tells
+        # that dictionary from the plain one.
         if nulls.any():
-            null_slot = len(words)
-            words2 = np.append(words, None)
-            dict_block = WireBlock(
-                "VARIABLE_WIDTH", words2,
-                np.arange(len(words2)) == null_slot)
+            null_slot = len(ends)
+            ends = np.append(ends, ends[-1])
+            slot_nulls = np.arange(null_slot + 1) == null_slot
             ids = np.where(nulls, null_slot, ids).astype(np.int32)
-        return WireBlock("DICTIONARY", ids, None, dictionary=dict_block)
+            sequence = 1
+        return WireBlock(
+            "DICTIONARY", ids, None,
+            dictionary=WireBlock("VARIABLE_WIDTH", nulls=slot_nulls,
+                                 ends=ends, payload=payload),
+            instance_id=(most, least, sequence))
     if t.dtype == np.bool_:
         return WireBlock("BYTE_ARRAY", vals.astype(np.uint8),
                          nulls if nulls.any() else None)
@@ -769,10 +852,11 @@ def page_to_wire_blocks(page) -> List[WireBlock]:
     return out
 
 
-def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int):
+def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int,
+                    compact_strings: bool = True):
     """One wire block -> engine Column/NestedColumn of type t."""
-    from presto_tpu.data.column import Column, NestedColumn, StringDict, \
-        bucket_capacity
+    from presto_tpu.data.column import Column, NestedColumn, \
+        bucket_capacity, compact_string_dict
     import jax.numpy as jnp
 
     b = _materialize_rle(b)
@@ -791,7 +875,7 @@ def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int):
         n_child = int(offs[-1]) if len(offs) else 0
         ccap = bucket_capacity(max(n_child, 1))
         children = tuple(
-            _wire_to_column(cb, ct, n_child, ccap)
+            _wire_to_column(cb, ct, n_child, ccap, compact_strings)
             for cb, ct in zip(b.children, child_types))
         pad = capacity - n
         return NestedColumn(
@@ -815,9 +899,12 @@ def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int):
         return Decimal128Column.from_unscaled_ints(
             ints, t, capacity=capacity)
     if t.is_string:
-        words, codes, nulls = _block_to_strings(b, position_count)
+        dictionary, codes, nulls = _block_to_strings(b)
+        if compact_strings:
+            dictionary, codes = compact_string_dict(dictionary, codes,
+                                                    nulls)
         return Column.from_numpy(codes, t, nulls=nulls,
-                                 dictionary=StringDict(words),
+                                 dictionary=dictionary,
                                  capacity=capacity)
     vals = b.values
     nulls = b.nulls if b.nulls is not None else \
@@ -836,12 +923,19 @@ def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int):
 
 
 def wire_blocks_to_page(blocks: List[WireBlock], types, position_count: int,
-                        capacity: Optional[int] = None):
-    """Wire blocks -> engine Page. `types` are presto_tpu SQL types."""
+                        capacity: Optional[int] = None,
+                        compact_strings: bool = True):
+    """Wire blocks -> engine Page. `types` are presto_tpu SQL types. A
+    string column comes with a sorted dictionary of exactly the words
+    its rows use. Only a caller whose pages all go through
+    `concat_pages_host` next (the exchange) passes `compact_strings`
+    False: the columns then keep the dictionary as it crossed the wire,
+    marked `sparse`, one object for every page that names it, and the
+    fuse compacts once."""
     from presto_tpu.data.column import Page, bucket_capacity
 
     cap = capacity or bucket_capacity(max(position_count, 1))
-    cols = [_wire_to_column(b, t, position_count, cap)
+    cols = [_wire_to_column(b, t, position_count, cap, compact_strings)
             for b, t in zip(blocks, types)]
     return Page.from_columns(cols, position_count)
 
@@ -852,31 +946,129 @@ def _materialize_rle(b: WireBlock) -> WireBlock:
     v = b.rle_value
     n = b.count
     if v.encoding == "VARIABLE_WIDTH":
-        vals = np.empty(n, dtype=object)
-        vals[:] = [v.values[0]] * n
-        nulls = np.full(n, bool(v.nulls[0]) if v.nulls is not None
-                        else False)
-        return WireBlock("VARIABLE_WIDTH", vals, nulls)
+        # n rows of the one value: a dictionary of it
+        return WireBlock("DICTIONARY", np.zeros(n, np.int32),
+                         dictionary=v)
+    if v.encoding == "DICTIONARY":
+        return WireBlock("DICTIONARY", np.repeat(v.values[:1], n),
+                         dictionary=v.dictionary,
+                         instance_id=v.instance_id)
     vals = np.repeat(v.values[:1], n, axis=0)
     nulls = np.full(n, bool(v.nulls[0]) if v.nulls is not None else False)
     return WireBlock(v.encoding, vals, nulls)
 
 
-def _block_to_strings(b: WireBlock, n: int):
-    """Decode a string block to (sorted words, codes, nulls) — the engine's
-    sorted-dictionary layout."""
+#: the receiver's side of "a dictionary crosses once": decoded
+#: dictionaries by the instance id their sender gave them, least
+#: recently used out. Bounded by entries and by words held (a word is
+#: ~70 bytes of str: 2M words ~ 150 MB); a dictionary larger than the
+#: whole budget is decoded for its page and not kept.
+_DICT_CACHE_ENTRIES = 64
+_DICT_CACHE_WORDS = 2_000_000
+_DICT_CACHE: "collections.OrderedDict[Tuple[int, int, int], tuple]" = \
+    collections.OrderedDict()
+_DICT_CACHE_LOCK = threading.Lock()
+
+
+def _note_dictionary(side: str, hit: bool) -> None:
+    """Count one dictionary through the codec, on the counter and on
+    the span around the work (`serialize` / `deserialize`)."""
+    _DICTIONARY.inc(side=side, result="hit" if hit else "miss")
+    span = "serialize" if side == "encode" else "deserialize"
+    if hit:
+        TRACER.add(span, dict_hits=1)
+    else:
+        TRACER.add(span, dict_misses=1)
+
+
+def _decode_words(d: WireBlock):
+    """A VARIABLE_WIDTH block of k words -> (sorted StringDict, remap,
+    isnull): `remap[i]` is the code of wire position i (None for the
+    identity), `isnull[i]` whether that position is a null slot (None
+    where there is none). Null slots read as "", which the dictionary
+    then holds. Once a dictionary: the one place with a pass of Python
+    over the words."""
+    from presto_tpu.data.column import StringDict
+
+    if d.encoding != "VARIABLE_WIDTH":
+        raise NotImplementedError(f"string dictionary block {d.encoding}")
+    k = d.position_count
+    if d.ends is not None:
+        raw = d.payload.tobytes()
+        text = raw.decode()
+        if len(text) == len(raw):     # ASCII: byte offsets are characters
+            words = list(map(text.__getitem__, _slices(d.ends)))
+        else:
+            words = [raw[s].decode() for s in _slices(d.ends)]
+    else:
+        words = [(v or b"").decode() for v in d.values]
+    isnull = d.nulls if d.nulls is not None and d.nulls.any() else None
+    live = words if isnull is None else \
+        list(itertools.compress(words, (~isnull).tolist()))
+    if all(map(operator.lt, live, itertools.islice(live, 1, None))):
+        # strictly increasing, as the engine's own always are: the wire
+        # order is the code order
+        if isnull is None:
+            return StringDict(live, sparse=True), None, None
+        lead = 0 if live and live[0] == "" else 1
+        remap = np.zeros(k, np.int32)         # a null slot -> ""
+        remap[~isnull] = np.arange(lead, lead + len(live), dtype=np.int32)
+        return (StringDict([""] * lead + live, sparse=True), remap,
+                isnull)
+    # a foreign sender: any order, words may repeat
+    uniq, remap = np.unique(np.asarray(words, dtype=object).astype(str),
+                            return_inverse=True)
+    return (StringDict([str(u) for u in uniq], sparse=True),
+            remap.astype(np.int32), isnull)
+
+
+def _cached_words(d: WireBlock, key: Tuple[int, int, int]):
+    """`_decode_words(d)`, kept under the instance id `key` the sender
+    gave the dictionary. A zero id names nothing (a foreign frame, a
+    frame spooled by an older sender, a hand-built block): decoded for
+    its page alone."""
+    if key == _NO_ID:
+        return _decode_words(d)
+    k = d.position_count
+    with _DICT_CACHE_LOCK:
+        entry = _DICT_CACHE.get(key)
+        hit = entry is not None and entry[0] == k
+        if hit:
+            _DICT_CACHE.move_to_end(key)
+    _note_dictionary("decode", hit)
+    if hit:
+        return entry[1:]
+    decoded = _decode_words(d)
+    if k <= _DICT_CACHE_WORDS:
+        with _DICT_CACHE_LOCK:
+            _DICT_CACHE[key] = (k,) + decoded
+            _DICT_CACHE.move_to_end(key)
+            held = sum(e[0] for e in _DICT_CACHE.values())
+            while (len(_DICT_CACHE) > _DICT_CACHE_ENTRIES
+                   or held > _DICT_CACHE_WORDS):
+                _old, gone = _DICT_CACHE.popitem(last=False)
+                held -= gone[0]
+    return decoded
+
+
+def _block_to_strings(b: WireBlock):
+    """A string block's rows as (dictionary, codes, nulls): int32 codes
+    into a sorted StringDict that may hold words no row uses (`sparse`).
+    Arrays only, but for a dictionary seen for the first time."""
     if b.encoding == "DICTIONARY":
-        d = b.dictionary
-        raw = [None if (d.nulls is not None and d.nulls[i]) else
-               (d.values[i] or b"").decode() for i in range(len(d.values))]
+        dictionary, remap, isnull = _cached_words(b.dictionary,
+                                                  b.instance_id)
         ids = b.values
-        strings = [raw[i] for i in ids]
+        codes = ids if remap is None else remap[ids]
+        nulls = np.zeros(len(ids), dtype=bool) if isnull is None \
+            else isnull[ids]
     elif b.encoding == "VARIABLE_WIDTH":
-        strings = [None if v is None else v.decode() for v in b.values]
+        # a plain string column: its own dictionary, a word a row
+        dictionary, codes, nulls = _decode_words(b)
+        if codes is None:
+            codes = np.arange(len(dictionary), dtype=np.int32)
+        if nulls is None:
+            nulls = np.zeros(b.position_count, dtype=bool)
     else:
         raise NotImplementedError(f"string block {b.encoding}")
-    nulls = np.array([s is None for s in strings], dtype=bool)
-    filled = ["" if s is None else s for s in strings]
-    uniq, codes = np.unique(np.asarray(filled, dtype=object).astype(str),
-                            return_inverse=True)
-    return [str(u) for u in uniq], codes.astype(np.int32), nulls
+    return dictionary, codes.astype(np.int32, copy=False), nulls

@@ -80,3 +80,44 @@ def test_compact_into_a_smaller_capacity(capacity):
     assert out.capacity == min(capacity, 1024)
     assert int(out.num_rows) == min(560, capacity)
     assert out.to_pylist() == want[:capacity]
+
+
+def test_merge_string_dicts_of_one_shared_object_is_the_identity():
+    """One dictionary on every page (a table's, or the one the wire
+    decode hands to all the pages that name it): the object itself and
+    identity remaps, no union."""
+    from presto_tpu.data.column import merge_string_dicts
+
+    d = StringDict(["amy", "bob", "cat"])
+    union, remaps = merge_string_dicts([d, d, d])
+    assert union is d
+    assert len(remaps) == 3
+    for r in remaps:
+        assert r.dtype == np.int32 and r.tolist() == [0, 1, 2]
+    # equal words in another object are another dictionary: unioned
+    other = StringDict(["amy", "bob", "dan"])
+    union, remaps = merge_string_dicts([d, other])
+    assert union is not d and union.words == ("amy", "bob", "cat", "dan")
+    assert [r.tolist() for r in remaps] == [[0, 1, 2], [0, 1, 3]]
+    # no dictionary at all stays the empty union
+    union, remaps = merge_string_dicts([None, None])
+    assert union.words == () and [len(r) for r in remaps] == [0, 0]
+
+
+def test_compact_string_dict_keeps_the_words_in_use():
+    from presto_tpu.data.column import compact_string_dict
+
+    d = StringDict(["", "amy", "bob", "cat", "dan"], sparse=True)
+    codes = np.array([3, 2147483647, 1, 3], dtype=np.int32)
+    nulls = np.array([False, True, False, False])
+    out, new = compact_string_dict(d, codes, nulls)
+    assert out.words == ("", "amy", "cat") and not out.sparse
+    assert new[~nulls].tolist() == [2, 1, 2]
+    # no null row: "" goes with the other words no row uses
+    out, new = compact_string_dict(d, np.array([4, 4], np.int32),
+                                   np.zeros(2, bool))
+    assert out.words == ("dan",) and new.tolist() == [0, 0]
+    # every word in use: still a new object, as a decoded page's was
+    out, new = compact_string_dict(d, np.arange(5, dtype=np.int32),
+                                   np.zeros(5, bool))
+    assert out is not d and out.words == d.words

@@ -19,6 +19,7 @@ import sys
 import pytest
 
 from presto_tpu.connectors import TpchConnector
+from presto_tpu.protocol import serde
 from presto_tpu.server.cluster import TpuCluster
 from presto_tpu.utils.tracing import TRACER
 
@@ -73,11 +74,11 @@ def test_q18_agrees_with_the_plain_reference(connector, q18, quantity,
     sql = query["sql"].format(QUANTITY=quantity)
     want = reference(bench_run.Tables(connector), {"QUANTITY": quantity})
     cluster = TpuCluster(connector, n_workers=2, session_properties=session)
+    decode_hits = serde._DICTIONARY.value(side="decode", result="hit")
     try:
         got = cluster.execute_sql(sql)
-        dispatched = [s.attributes for s in
-                      TRACER.get(cluster.last_trace_id)
-                      if s.name == "dispatch"]
+        spans = TRACER.get(cluster.last_trace_id)
+        dispatched = [s.attributes for s in spans if s.name == "dispatch"]
         fragments = len(cluster._fragment_plan(cluster.plan_sql(sql),
                                                None)[2])
     finally:
@@ -87,5 +88,15 @@ def test_q18_agrees_with_the_plain_reference(connector, q18, quantity,
     # DATE comes as days since 1970-01-01 on both sides; the doubles are
     # whole quantities and prices in cents, exact in float64
     assert [list(r) for r in got] == want
+    # c_name's dictionary crosses once: the pages after its first find it
+    # decoded (protocol/serde), and the spans around the work say so
+    decode_hits = serde._DICTIONARY.value(side="decode",
+                                          result="hit") - decode_hits
+    assert decode_hits > 0
+    assert sum(s.attributes.get("dict_hits", 0) for s in spans
+               if s.name == "deserialize") == decode_hits
+    assert sum(s.attributes.get("dict_hits", 0)
+               + s.attributes.get("dict_misses", 0) for s in spans
+               if s.name == "serialize") > 0
     if quantity == 300:
         holds_what_each_program_joins_and_aggregates(dispatched)
