@@ -36,48 +36,6 @@ def _days(y: int, m: int, d: int) -> int:
     return (datetime.date(y, m, d) - _EPOCH).days
 
 
-class CompileCounter:
-    """Counts XLA backend compilations through jax.monitoring: every
-    compile request fires the backend-compile duration event, and a
-    request the persistent cache answered also fires a cache-hit event,
-    so `compiled` is the programs the compiler really built. `seconds`
-    sums the requests' durations over all threads (two workers compile
-    at once), so it can exceed the wall around them."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.requests = 0
-        self.hits = 0
-        self.seconds = 0.0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-            self.seconds += secs
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    @property
-    def compiled(self) -> int:
-        return self.requests - self.hits
-
-
-_COUNTER = None
-
-
-def _compile_counter() -> CompileCounter:
-    """One listener per process (jax.monitoring has no unregister), so
-    the in-process rehearsal can call main() more than once."""
-    global _COUNTER
-    if _COUNTER is None:
-        _COUNTER = CompileCounter()
-    return _COUNTER
-
-
 def _say(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
@@ -179,7 +137,7 @@ def rebuild_native_codec() -> bool:
     return loaded
 
 
-def run_queries(sf: float, counter: CompileCounter) -> bool:
+def run_queries(sf: float, counter) -> bool:
     """The served path at scale `sf`; prints one line per query and one
     of run facts. Returns whether every query was exact."""
     import jax
@@ -265,7 +223,11 @@ def main(argv=None) -> int:
     cache_dir = jax.config.jax_compilation_cache_dir
     cache_existed = bool(cache_dir and os.path.isdir(cache_dir)
                          and os.listdir(cache_dir))
-    counter = _compile_counter()
+    # one listener per process (jax.monitoring has no unregister), the
+    # benchmark's: two workers compile at once, so `seconds`, summed
+    # over threads, can exceed the wall around them
+    from benchmarks.compile_counter import compile_counter
+    counter = compile_counter()
 
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,

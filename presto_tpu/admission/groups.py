@@ -3,8 +3,7 @@
 Reference: execution/resourceGroups/InternalResourceGroup.java +
 InternalResourceGroupManager (hierarchical groups, per-group
 concurrency / queue limits / scheduling weight, selector rules mapping
-sessions to groups). Upgrades the flat semaphore groups that used to
-live in ``server/resource_groups.py``:
+sessions to groups):
 
 - groups form a tree; a query admitted at a leaf consumes one running
   slot at the leaf *and every ancestor*, so an internal node's
@@ -19,8 +18,7 @@ live in ``server/resource_groups.py``:
 - ``queue_timeout_s`` evicts waiters with a QUERY_QUEUE_FULL-class
   error instead of letting them camp forever.
 
-The legacy blocking API is preserved exactly (and re-exported from
-``presto_tpu.server.resource_groups``): ``acquire(timeout_s)`` blocks
+The blocking API: ``acquire(timeout_s)`` blocks
 FIFO for a slot or raises :class:`QueryQueueFull`; ``max_queued``
 limits only WAITING queries (``max_queued=0`` == run-or-reject); a
 free slot admits immediately only when nothing is already waiting

@@ -574,8 +574,8 @@ register(NoJaxInControlPlaneRule())
 # =====================================================================
 
 #: `handle` is the App-contract router (net/aio_server.py shells); the
-#: do_* names are the http.server handler surface the threaded shell
-#: and test doubles still use
+#: do_* names are the http.server handler surface test doubles still
+#: use
 _HANDLER_METHODS = ("do_GET", "do_POST", "do_DELETE", "do_PUT",
                     "do_HEAD", "handle")
 

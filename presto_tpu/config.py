@@ -53,26 +53,9 @@ PROPERTIES = [
     Property("group_count_hint",
              "Default aggregation output-capacity hint when the planner "
              "has no estimate", int, 65536),
-    Property("merge_join_enabled",
-             "Use the sort-merge join fast path for unique build keys",
-             _parse_bool, True),
-    Property("execution_mode",
-             "Plan lowering granularity: 'auto' splits join/window/"
-             "union-bearing plans into per-operator fusion islands "
-             "(bounded XLA program size), 'fused' "
-             "always lowers one whole-plan program, 'island' always "
-             "splits", str, "auto"),
-    Property("direct_agg_max_bins",
-             "Max mixed-radix bins for the scatter-free small-domain "
-             "aggregation path", int, 64),
     Property("exchange_chunk_factor",
              "Per-peer exchange chunk = factor * capacity / n_devices",
              int, 2),
-    Property("capacity_annealing_enabled",
-             "Shrink learned capacities back toward the observed "
-             "high-water mark after a converged run (costs one recompile "
-             "at the smaller bucket, then every later run executes the "
-             "smaller program)", _parse_bool, True),
     Property("collect_stats",
              "Record per-node output row counts for EXPLAIN ANALYZE",
              _parse_bool, False),

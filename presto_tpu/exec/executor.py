@@ -161,7 +161,7 @@ class Executor:
         self.last_memory_estimate = 0
         #: how the last lowering joins, a JoinNode each in plan order:
         #: "merge" (ops/join.merge_join) or "expansion" (hash_join: cross
-        #: joins, merge_join_enabled off, duplicate build keys seen)
+        #: joins, duplicate build keys seen)
         self.last_join_paths: List[str] = []
         #: beside it, each JoinNode's type (INNER, LEFT, FULL, SEMI, ANTI)
         #: and each AggregationNode's step (PARTIAL, FINAL, SINGLE)
@@ -244,24 +244,16 @@ class Executor:
                     MarkDistinctNode, GroupIdNode)
 
     def _use_islands(self, plan: PlanNode) -> bool:
-        mode = self.session["execution_mode"]
-        if mode == "fused" or getattr(self, "_force_fused", False):
+        if getattr(self, "_force_fused", False):
             return False
-        found = [0]
 
-        def walk(n):
-            if isinstance(n, (JoinNode, WindowNode, UnionAllNode,
-                              UnnestNode, MarkDistinctNode, GroupIdNode)):
-                found[0] += 1
-            elif isinstance(n, AggregationNode):
-                found[0] += (2 if mode == "island" else 0)
-            for c in n.children():
-                if c is not None:
-                    walk(c)
-        walk(plan)
-        # split only the shapes that blow up whole-plan compiles (in
-        # "island" mode aggregations count too, via walk() above)
-        return found[0] > 0
+        # split only the shapes that blow up whole-plan compiles
+        def splits(n):
+            return isinstance(n, (JoinNode, WindowNode, UnionAllNode,
+                                  UnnestNode, MarkDistinctNode,
+                                  GroupIdNode)) or any(
+                splits(c) for c in n.children() if c is not None)
+        return splits(plan)
 
     def _island_of(self, plan: PlanNode):
         """(mini_plan, children): `plan`'s fusion island with descendant
@@ -645,8 +637,6 @@ class Executor:
         flip-flopping. An undershoot on later, larger data is always
         recoverable: every watched counter reports its unclamped need
         and rides the normal overflow-retry loop."""
-        if not self.session["capacity_annealing_enabled"]:
-            return
         caps = pending["caps"]
         peaks = self.__dict__.setdefault("_peak_needs", {}) \
             .setdefault(pending["plan"], {})
@@ -975,9 +965,7 @@ class Executor:
                             p = Page(cols, p.num_rows, names)
                     out, true_groups = grouped_aggregate(
                         p, node.group_fields, node.aggs, out_cap,
-                        row_mask=mask,
-                        direct_max_bins=self.session[
-                            "direct_agg_max_bins"])
+                        row_mask=mask)
                     _needed.append(true_groups)
                     return self._finish_agg(node, out)
                 return agg_fn, out_cap
@@ -1029,7 +1017,6 @@ class Executor:
                 # negated node id: any duplicate live build key re-lowers
                 # onto the expansion hash_join below.
                 use_merge = (bool(node.probe_keys)
-                             and self.session["merge_join_enabled"]
                              and node.join_type in (JoinType.INNER,
                                                     JoinType.LEFT,
                                                     JoinType.FULL)

@@ -13,8 +13,6 @@ serves worst.
 Layout:
 
   net/aio_server.py   asyncio event-loop HTTP server (both node roles)
-  net/threaded.py     thread-per-connection baseline over the same App
-                      contract (bench before/after, ops fallback)
 
 The connection pool itself lives in `protocol/transport.py` (the single
 RPC chokepoint); it shares this package's metrics so one scrape shows

@@ -27,7 +27,7 @@ Architecture:
     list-of-frames bodies are written frame by frame — never
     ``b"".join``-copied.
 
-The App contract (shared with net/threaded.py):
+The App contract:
 
   handle(request) -> Response | None     sync router; None = tear the
                                          connection with no response
@@ -98,8 +98,7 @@ class Request:
 
 class SendFile:
     """A zero-copy response body: `count` bytes of `path` starting at
-    `offset`, shipped via loop.sendfile (threaded fallback reads the
-    range)."""
+    `offset`, shipped via loop.sendfile."""
 
     __slots__ = ("path", "offset", "count")
 
@@ -144,8 +143,7 @@ def json_response(status: int, obj, headers: Optional[dict] = None
 
 def render_head(resp: Response, keep_alive: bool,
                 server_name: str) -> bytes:
-    """Serialize the status line + headers (shared with the threaded
-    fallback so both shells frame identically)."""
+    """Serialize the status line + headers."""
     try:
         reason = HTTPStatus(resp.status).phrase
     except ValueError:

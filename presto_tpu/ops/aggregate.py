@@ -122,8 +122,7 @@ def _hll_estimate(present_sum: jnp.ndarray, zeros: jnp.ndarray):
 _DIRECT_MAX_BINS = 64
 
 
-def _direct_domains(page: Page, group_fields: Sequence[int],
-                    max_bins: int = _DIRECT_MAX_BINS):
+def _direct_domains(page: Page, group_fields: Sequence[int]):
     """Per-key domain sizes if the direct path applies, else None."""
     domains = []
     for f in group_fields:
@@ -137,7 +136,7 @@ def _direct_domains(page: Page, group_fields: Sequence[int],
     prod = 1
     for d in domains:
         prod *= d + 1                      # +1: per-key NULL bin
-        if prod > max_bins:
+        if prod > _DIRECT_MAX_BINS:
             return None
     return domains, prod
 
@@ -443,8 +442,7 @@ def _agg_inputs(a: AggSpec, page: Page):
 def grouped_aggregate(page: Page, group_fields: Sequence[int],
                       aggs: Sequence[AggSpec],
                       out_capacity: Optional[int] = None,
-                      row_mask: Optional[jnp.ndarray] = None,
-                      direct_max_bins: int = _DIRECT_MAX_BINS):
+                      row_mask: Optional[jnp.ndarray] = None):
     """Group `page` by `group_fields` and evaluate `aggs`. Output columns:
     group keys (in order) then one column per agg (avg_partial emits two).
     With no group fields, emits exactly one row (SQL global aggregation).
@@ -470,8 +468,7 @@ def grouped_aggregate(page: Page, group_fields: Sequence[int],
     # Decimal128 merge steps read limb-lane columns — sorted path only
     merge128 = any(a.kind in ("sum128_merge", "avg128_merge")
                    for a in aggs)
-    d = None if merge128 else _direct_domains(page, group_fields,
-                                              direct_max_bins)
+    d = None if merge128 else _direct_domains(page, group_fields)
     if d is not None:
         domains, prod = d
         return _direct_grouped_aggregate(

@@ -320,7 +320,7 @@ class TpuCluster:
 
         from presto_tpu.cache import AffinityRouter
         from presto_tpu.config import DEFAULT_EXCHANGE, DEFAULT_SPOOL
-        from presto_tpu.server.resource_groups import ResourceGroupManager
+        from presto_tpu.admission.groups import ResourceGroupManager
         from presto_tpu.sql.analyzer import Planner
 
         # internal-communication JWT (InternalCommunicationConfig

@@ -529,12 +529,22 @@ class WorkerApp:
     def _closed_buffer_results(self, req: Request, task_id: str,
                                buffer_id: str, token: str) -> Response:
         """The task's buffers were closed under a long-poll (worker
-        shutting down, task deleted): a committed spool serves the SAME
-        bytes at the same tokens; otherwise refuse retryably — never
-        answer `complete` for frames this buffer no longer serves."""
+        shutting down, task deleted, task FAILED under
+        retry_policy=TASK): a committed spool serves the SAME bytes at
+        the same tokens; otherwise refuse — never answer `complete` for
+        frames this buffer no longer serves. A FAILED attempt's output
+        is void and will not come back, so its consumers get what a
+        deleted task's get (404: they fail and are re-planned against
+        the replacement attempt) and the worker's breaker takes no
+        penalty for a task's fault; a closing worker refuses
+        retryably."""
         committed = self._spool_for(task_id)
         if committed is not None:
             return self._spool_results(req, committed, buffer_id, token)
+        task = self.tm.get(task_id)
+        if task is not None and task.state == "FAILED":
+            return _json_response(
+                req, 404, {"error": "task failed; its output is void"})
         return _json_response(
             req, 503, {"error": "output buffer closed (worker "
                        "shutting down); retry"})

@@ -713,10 +713,18 @@ class TpuTaskManager:
             else:
                 task.failures.append(traceback.format_exc())
             if task.buffers is not None:
-                task.buffers.set_no_more_pages()
-                writer = getattr(task.buffers, "spool_writer", None)
-                if writer is not None:
-                    writer.discard()   # never publish a failed attempt
+                if task.buffers.spool_writer is not None:
+                    # retry_policy=TASK: consumers outlive this attempt
+                    # (it is re-planned as attempt N+1), so its buffers
+                    # must REFUSE, never end the stream: a consumer told
+                    # `complete` here would finish without this task's
+                    # rows. close() discards the unpublished spool and
+                    # turns every GET into a 404, or into the
+                    # replacement attempt's committed spool once there
+                    # is one (http._closed_buffer_results)
+                    task.buffers.close()
+                else:
+                    task.buffers.set_no_more_pages()
             task.set_state("FAILED")
         finally:
             if self.memory_pool is not None:

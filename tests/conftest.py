@@ -17,12 +17,22 @@ if "xla_force_host_platform_device_count" not in flags:
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 
+# One temp root per test process, inherited by the children it starts.
+# The driver runs six xdist workers that would otherwise share the
+# system temp directory, and the stray-directory guards
+# (test_spool_chaos.py, test_elastic.py, test_memory_chaos.py) can only
+# answer for the spill / spool / shuffle directories their own process
+# made: another worker's live cluster is not this one's leak.
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
+
+_TMP_ROOT = tempfile.mkdtemp(prefix="presto_tpu_tests_")
+tempfile.tempdir = os.environ["TMPDIR"] = _TMP_ROOT
+
 # Hermetic learned-capacity store: without this, a previous session's
 # grown caps warm-start plans and tests that assert on cold-start
 # behavior (overflow retries, compile counts) become order-dependent.
 # setdefault so a harness that pins its own path wins.
-import tempfile  # noqa: E402
-
 os.environ.setdefault(
     "PRESTO_TPU_CAPS_CACHE",
     os.path.join(tempfile.mkdtemp(prefix="presto_tpu_caps_"),
@@ -53,6 +63,7 @@ def pytest_configure(config):
 def pytest_sessionfinish(session, exitstatus):
     from presto_tpu.analysis import locksan
 
+    shutil.rmtree(_TMP_ROOT, ignore_errors=True)
     san = locksan.active()
     if san is None:
         return

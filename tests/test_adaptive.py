@@ -223,7 +223,12 @@ def test_second_run_uses_history_local(conn):
 
 @pytest.fixture(scope="module")
 def cluster(conn):
-    c = TpuCluster(conn, n_workers=2)
+    # the probe stage waits for the build's key domain as long as it
+    # takes (the wait ends when the build finishes): these tests are
+    # about the pruning, not about the 400 ms a production session
+    # gives up after, which six test workers on one machine outlast
+    c = TpuCluster(conn, n_workers=2, session_properties={
+        "dynamic_filter_wait_ms": "300000"})
     yield c
     c.stop()
 

@@ -186,8 +186,15 @@ def test_sendfile_body_served_byte_exact(served):
         body = resp.read()
         assert resp.headers["Content-Type"] == "application/octet-stream"
     assert body == b"\xabZ" * 8192
-    # >= not ==: the counter is global and straggler result serving
-    # from earlier tests' clusters can add to it concurrently
+    # the loop thread counts the bytes after loop.sendfile returns,
+    # which may be after the client has read the last of them: wait for
+    # the count, not for the clock. >= not ==: the counter is global and
+    # straggler result serving from earlier tests' clusters can add to
+    # it concurrently
+    deadline = time.monotonic() + 60
+    while (M_SENDFILE_BYTES.value() < before + len(body)
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
     assert M_SENDFILE_BYTES.value() >= before + len(body)
 
 

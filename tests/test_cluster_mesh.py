@@ -166,7 +166,8 @@ def test_worker_mesh_surface(cluster):
 # ---------------------------------------------------------------------------
 # chaos: kill the chosen mesh worker mid-query (retry_policy=TASK)
 # ---------------------------------------------------------------------------
-def _stabilize(cluster, deadline_s: float = 15.0):
+def _stabilize(cluster, deadline_s: float = 120.0):
+    # polls for the re-admission itself; the bound only ends a wedge
     deadline = time.monotonic() + deadline_s
     while time.monotonic() < deadline:
         if len(cluster.check_workers()) == len(cluster.all_worker_uris):
@@ -203,7 +204,16 @@ def test_kill_mesh_worker_mid_query_stays_exact(cluster, oracle, seed):
         try:
             start = time.monotonic()
             got = [tuple(r) for r in cluster.execute_sql(sql)]
-            assert time.monotonic() - start < DEADLINE_S + 60, \
+            # the engine's own clock (`query_max_execution_time`)
+            # bounds every await of the query, and a waited-out await
+            # is followed by at most one whole-query retry on the
+            # survivors: two deadlines, not one, are what a rung may
+            # take. Under six test workers a rung has read 184-186 s,
+            # rows exact (CHANGES.md PR 25); what held its first await
+            # for the whole deadline was not reproduced at PR 31
+            # (ROADMAP C13) and is not this assertion's to hide: it
+            # still fails a query that needs a third deadline
+            assert time.monotonic() - start < 2 * DEADLINE_S + 60, \
                 f"seed {seed}: mesh-kill query exceeded deadline"
             _assert_rows_match(got, oracle[3],
                                ctx=f"seed {seed} mesh kill")

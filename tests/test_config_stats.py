@@ -12,6 +12,7 @@ import pytest
 
 from presto_tpu.config import PROPERTIES, Session
 from presto_tpu.connectors import TpchConnector
+from presto_tpu.data.column import Page
 from presto_tpu.exec import LocalEngine
 from presto_tpu.exec.executor import MemoryLimitExceeded
 
@@ -19,10 +20,10 @@ from presto_tpu.exec.executor import MemoryLimitExceeded
 def test_session_property_parsing():
     s = Session({"query_max_memory_per_node": "2GB",
                  "lifespan_batches": "4",
-                 "merge_join_enabled": "false"})
+                 "spill_enabled": "false"})
     assert s["query_max_memory_per_node"] == 2 << 30
     assert s["lifespan_batches"] == 4
-    assert s["merge_join_enabled"] is False
+    assert s["spill_enabled"] is False
     with pytest.raises(KeyError):
         Session({"not_a_property": "1"})
     assert len(Session.describe().splitlines()) == len(PROPERTIES)
@@ -35,16 +36,28 @@ def test_memory_limit_session_property():
         eng.execute_sql("select count(*) from lineitem")
 
 
-def test_merge_join_can_be_disabled():
-    eng = LocalEngine(TpchConnector(0.01), session=Session(
-        {"merge_join_enabled": "false"}))
-    rows = eng.execute_sql(
-        "select count(*) from lineitem, orders "
-        "where l_orderkey = o_orderkey")
-    base = LocalEngine(TpchConnector(0.01)).execute_sql(
-        "select count(*) from lineitem, orders "
-        "where l_orderkey = o_orderkey")
-    assert rows == base
+def test_merge_join_agrees_with_hash_join_on_a_unique_build():
+    """The executor picks `merge_join` itself wherever the build keys
+    are unique (no session switch); `hash_join`, its fallback after
+    duplicate build keys, is the reference: lineitem against orders,
+    the same rows from both."""
+    from presto_tpu.ops.join import hash_join, merge_join
+    conn = TpchConnector(0.01)
+
+    def two(table, *names):
+        page = conn.table(table).page()
+        return Page.from_columns(
+            [page.column(page.names.index(n)) for n in names],
+            page.num_rows, list(names))
+
+    probe = two("lineitem", "l_orderkey", "l_linenumber")
+    build = two("orders", "o_orderkey", "o_custkey")
+    merged, dup, _match = merge_join(probe, build, [0], [0], "inner")
+    hashed, pairs = hash_join(probe, build, [0], [0], probe.capacity,
+                              "inner")
+    assert int(dup) == 0
+    assert int(pairs) == int(merged.num_rows) == int(probe.num_rows)
+    assert sorted(merged.to_pylist()) == sorted(hashed.to_pylist())
 
 
 def test_explain_analyze(tmp_path):

@@ -167,8 +167,8 @@ def test_long_poll_storm_1000_clients_flat_server_threads():
 
         # the tentpole claim: 5x the clients, flat server threads.
         # Loop + fixed executor + fixed dispatch pool — parked polls
-        # cost a loop task, never a thread (the threaded server would
-        # show ~+800 here).
+        # cost a loop task, never a thread (a thread-per-connection
+        # server would show ~+800 here).
         assert (storm.peak_server_threads
                 <= base.peak_server_threads + 8), (
             f"server thread population grew with client count: "
@@ -177,8 +177,17 @@ def test_long_poll_storm_1000_clients_flat_server_threads():
 
         # closed-loop e2e p99 grows with the backlog (5x statements),
         # so allow linear scaling with headroom; thread-per-connection
-        # collapse is superlinear and blows through this
-        base_p99 = max(base.latency()["e2e_p99_s"], 0.2)
+        # collapse is superlinear and blows through this. The ratio
+        # holds only between runs under the same load, and five other
+        # test workers change the machine's between two phases: the
+        # 200-client run is taken again after the storm and the slower
+        # of the two is the base
+        again = LoadHarness(srv.base, TENANTS, clients=200,
+                            statements=200, seed=17,
+                            timeout_s=120.0).run()
+        again.assert_zero_dropped()
+        base_p99 = max(base.latency()["e2e_p99_s"],
+                       again.latency()["e2e_p99_s"], 0.2)
         storm_p99 = storm.latency()["e2e_p99_s"]
         assert storm_p99 <= 10 * base_p99, (
             f"e2e p99 collapsed under the storm: {storm_p99:.2f}s vs "

@@ -256,14 +256,26 @@ def test_coordinator_metrics_and_status(cluster, statement_server):
 
 # ------------------------------------- cross-node tracing, with chaos
 
+class _TwoFaultsAHost(FaultInjector):
+    """A 500 on each host's 2nd and 5th request and on no other. A
+    drawn rate makes the number of faults grow with the number of
+    status polls, i.e. with the machine's load, until three in a row
+    open a healthy worker's breaker (threshold 3 here) and the query
+    runs on one worker; two a host can never open it, and each is
+    retried by its request's own policy."""
+
+    def _roll(self, kind, host, ordinal):
+        return 0.0 if kind == "http500" and ordinal in (1, 4) else 1.0
+
+
 def test_trace_propagation_two_workers_under_retry(cluster):
     """A 2-worker query with an injected-retry transport yields ONE
     stitched trace: the coordinator's root `query` span plus task spans
     from BOTH workers parented under it, and the injected faults show
     up as retry + breaker metrics on the /v1/metrics page."""
     hosts = {u.split("://", 1)[1] for u in cluster.all_worker_uris}
-    inj = FaultInjector(seed=2, spec=FaultSpec(http_500_rate=0.15),
-                        only_hosts=hosts)
+    inj = _TwoFaultsAHost(spec=FaultSpec(http_500_rate=0.5),
+                          only_hosts=hosts)
     cluster.http.fault_injector = inj
     try:
         rows = cluster.execute_sql("select count(*) from lineitem")

@@ -9,7 +9,7 @@ import pytest
 
 from presto_tpu.connectors import TpchConnector
 from presto_tpu.exec import LocalEngine
-from presto_tpu.server.resource_groups import (
+from presto_tpu.admission.groups import (
     QueryQueueFull, ResourceGroup, ResourceGroupManager, Selector,
 )
 from presto_tpu.utils import EVENTS, TRACER, QueryEvent

@@ -311,3 +311,77 @@ def test_worker_serves_results_from_spool_after_task_delete(tmp_path):
         assert abs(rows[0][0] - exp[0][0]) <= 1e-6 * abs(exp[0][0])
     finally:
         srv.stop()
+
+
+# ------------------------------- a FAILED attempt never ends a stream
+
+def test_failed_task_refuses_its_consumers_under_task_retry(tmp_path):
+    """retry_policy=TASK re-plans a FAILED task as attempt N+1 while
+    its consumers keep running, so what the failed attempt tells them
+    decides the rows: its buffers must refuse (retryably) and never
+    answer `complete`. A consumer that polled between the failure and
+    the coordinator's re-plan used to read a clean, empty end of stream
+    and finish without the producer's rows (the missing nation rows of
+    test_spool_chaos's kill matrix). Once the replacement attempt has
+    committed, the failed attempt's location serves ITS spool."""
+    from presto_tpu.protocol import structs as S
+    from presto_tpu.server import TpuWorkerServer
+    from tests.protocol_fixtures import (
+        call, fragment, task_update_request, var,
+    )
+
+    scfg = SpoolConfig(enabled=True, base_dir=str(tmp_path / "spool"),
+                       sweep_on_start=False)
+    srv = TpuWorkerServer(TpchConnector(SF), spool_config=scfg).start()
+    base = f"http://127.0.0.1:{srv.port}/v1/task"
+    try:
+        # a middle-stage task whose one producer is gone and left no
+        # spool: its pull fails and the task with it, with no timing
+        rev = var("revenue", "double")
+        remote = S.RemoteSourceNode(id="0", sourceFragmentIds=["0"],
+                                    outputVariables=[rev])
+        keep = call("GREATER_THAN_OR_EQUAL",
+                    "$operator$greater_than_or_equal", "boolean",
+                    [rev, rev], ["double", "double"])
+        tur = task_update_request(
+            fragment("1", S.FilterNode(id="1", source=remote,
+                                       predicate=keep), [rev], ["0"]),
+            n_splits=0, sf=SF,
+            session_properties={"retry_policy": "TASK"})
+        tur.sources = [S.TaskSource(
+            planNodeId="0",
+            splits=[S.ScheduledSplit(
+                sequenceId=0, planNodeId="0",
+                split=S.Split(connectorId="$remote", connectorSplit={
+                    "location": f"{base}/qf.0.0.0.0",
+                    "bufferId": "0"}))],
+            noMoreSplits=True)]
+        req = urllib.request.Request(
+            f"{base}/qf.1.0.0.0", data=tur.dumps().encode(),
+            method="POST", headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=30) as resp:
+            resp.read()
+        state = "PLANNED"
+        while state in ("PLANNED", "RUNNING"):
+            req = urllib.request.Request(
+                f"{base}/qf.1.0.0.0/status",
+                headers={"X-Presto-Current-State": state,
+                         "X-Presto-Max-Wait": "1s"})
+            with urllib.request.urlopen(req, timeout=30) as resp:
+                state = json.loads(resp.read())["state"]
+        assert state == "FAILED"
+
+        consumer = PageStream(f"{base}/qf.1.0.0.0",
+                              client=HttpClient(FAST))
+        with pytest.raises(OSError):
+            consumer.fetch()
+        assert not consumer.complete and consumer.token == 0
+
+        # attempt 1 of the same work unit commits (another worker's, on
+        # the shared spool base): the failed attempt's location now
+        # serves those frames from token 0
+        frames = [_frame(b"replacement" * 3), _frame(b"attempt")]
+        _commit_task(srv.task_manager.spool, "qf.1.0.0.1", frames)
+        assert consumer.drain() == b"".join(frames)
+    finally:
+        srv.stop()

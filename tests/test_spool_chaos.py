@@ -13,10 +13,12 @@ Results are checked against an independent sqlite oracle, not a
 cluster baseline — a recovery bug that corrupts rows deterministically
 would poison a cluster-produced baseline too.
 
-The final test is the stray-directory guard for the whole chaos family
-(this module alphabetically follows tests/test_chaos.py, so both
-matrices have run): no new `presto_tpu_spill_*` / `presto_tpu_spool_*`
-/ `presto_tpu_shuffle_*` entries may survive in the system temp dir."""
+The final test is the stray-directory guard for the chaos family, as
+far as this test process ran it (tests/conftest.py gives every process
+a temp root of its own; in one process this module follows
+tests/test_chaos.py, so both matrices have run): no new
+`presto_tpu_spill_*` / `presto_tpu_spool_*` / `presto_tpu_shuffle_*`
+entries may survive in the temp dir."""
 
 import math
 import os
@@ -130,7 +132,8 @@ def probe(monkeypatch):
     return executed
 
 
-def _stabilize(cluster, deadline_s: float = 15.0):
+def _stabilize(cluster, deadline_s: float = 120.0):
+    # polls for the re-admission itself; the bound only ends a wedge
     deadline = time.monotonic() + deadline_s
     while time.monotonic() < deadline:
         if len(cluster.check_workers()) == len(cluster.all_worker_uris):
@@ -213,6 +216,56 @@ def test_task_retry_kill_worker_matrix(cluster, oracle, probe, seed):
         f"seed {seed}: worker kill never triggered recovery"
 
 
+def test_failed_middle_task_costs_no_rows(cluster, oracle, monkeypatch):
+    """What seed 4 of the matrix lost, forced at its protocol phase
+    instead of waited for: a middle-stage task on a LIVE worker fails
+    (in the matrix: its pull from the killed producer ran out before
+    the replacement attempt had committed) while the final stage's
+    tasks are already pulling from it, and the coordinator's recovery
+    round comes only after they have had their answer. The failed
+    attempt used to answer `complete` with no frames, so the final
+    stage finished without that task's share of `nation` (18 of 25
+    rows) and recovery, which re-plans FAILED tasks only, never looked
+    at it again. No kill and no clock: the failure is raised in the
+    task, and the first recovery round is held until every final-stage
+    task of attempt 0 has either finished or failed."""
+    sql = QUERIES[2]
+    failed = []
+    orig_pull = TpuTaskManager._pull_remote_inputs
+
+    def pull(self, task, plan, skip=None):
+        tid = TaskId.parse(task.task_id)
+        if (tid.stage_id, tid.task_index, tid.attempt) == (2, 0, 0):
+            failed.append(task.task_id)
+            raise OSError("producer killed before its spool committed")
+        return orig_pull(self, task, plan, skip=skip)
+
+    def final_stage_pending():
+        return [t.task_id for w in cluster.workers
+                for t in list(w.task_manager.tasks.values())
+                if TaskId.parse(t.task_id).stage_id == 0
+                and t.state in ("PLANNED", "RUNNING")]
+
+    orig_recover = TpuCluster._recover_spooled
+    held = []
+
+    def recover(self, qid, stages, by_id):
+        deadline = time.monotonic() + DEADLINE_S
+        while not held and final_stage_pending() \
+                and time.monotonic() < deadline:
+            time.sleep(0.05)
+        held.append(qid)
+        return orig_recover(self, qid, stages, by_id)
+
+    monkeypatch.setattr(TpuTaskManager, "_pull_remote_inputs", pull)
+    monkeypatch.setattr(TpuCluster, "_recover_spooled", recover)
+    got = cluster.execute_sql(sql)
+    assert len(failed) == 1, "the middle-stage task never ran"
+    _assert_rows_match(got, oracle[sql], ctx="failed middle task")
+    assert ("retask", 2, 0) in cluster.last_recovery_events
+    assert os.listdir(cluster.spool.base_dir) == []
+
+
 def test_retry_policy_none_same_fault_fails_cleanly():
     """Control group: the SAME kill without retry_policy=TASK must
     either produce exact rows (whole-query retry on survivors) or raise
@@ -249,9 +302,9 @@ def test_retry_policy_none_same_fault_fails_cleanly():
 
 
 def test_no_stray_spill_or_spool_dirs_after_chaos(cluster):
-    """Runs after BOTH chaos matrices (tests/test_chaos.py sorts before
-    this module; this test is last in it): every spill / spool /
-    shuffle temp entry created by the suite must be gone — the
+    """Runs after the chaos matrices of this process (tests/test_chaos.py
+    sorts before this module; this test is last in it): every spill /
+    spool / shuffle temp entry this process created must be gone — the
     exception-safe FileSpiller teardown and the spool GC are what keep
     a long-lived cluster's disk from filling. The module cluster's own
     spool base is still alive here (fixture teardown comes later), so

@@ -361,13 +361,16 @@ def test_refresh_exact_across_worker_kill_task_retry(monkeypatch):
         orig = TpuTaskManager._run_inner
         executed = []
         on_victim = threading.Event()
+        killed = threading.Event()
 
         def spy(self, task):
             executed.append(
                 (self.node_id, int(task.task_id.rsplit(".", 1)[1])))
             if self.node_id == victim:
                 on_victim.set()
-                time.sleep(0.5)   # hold the victim's work for the kill
+                # hold the victim's work until the kill has landed,
+                # however long this thread's rival takes to get there
+                killed.wait(timeout=60)
             return orig(self, task)
 
         monkeypatch.setattr(TpuTaskManager, "_run_inner", spy)
@@ -388,6 +391,7 @@ def test_refresh_exact_across_worker_kill_task_retry(monkeypatch):
             "victim never executed a task"
         from tests.test_elastic import _hard_kill
         _hard_kill(c.workers[1])
+        killed.set()
         t.join(timeout=120)
         assert not t.is_alive(), "refresh wedged across the kill"
         assert not errors, f"refresh failed despite recovery: {errors}"

@@ -34,18 +34,27 @@ SF = 0.01
 
 
 class SlowScanConnector:
-    """Delegating connector that sleeps on per-split table() fetches of
-    one table — throttles the worker's lifespan loop so the test can
-    observe mid-task state deterministically."""
+    """Delegating connector that holds per-split table() fetches of one
+    table — throttles the worker's lifespan loop so the test can
+    observe mid-task state deterministically: for `delay_s`, or, with a
+    `gate`, until gate(n) returns, n counting the table's splits in the
+    order they are first asked for (lowering and scan both ask)."""
 
-    def __init__(self, inner, slow_table: str, delay_s: float):
+    def __init__(self, inner, slow_table: str, delay_s: float,
+                 gate=None):
         self._inner = inner
         self._slow = slow_table
         self._delay = delay_s
+        self._gate = gate
+        self._ordinal = {}
 
     def table(self, name, part=None, num_parts=None, **kw):
         if name == self._slow and part is not None:
-            time.sleep(self._delay)
+            if self._gate is None:
+                time.sleep(self._delay)
+            else:
+                self._gate(self._ordinal.setdefault(
+                    (part, num_parts), len(self._ordinal)))
         if part is None:
             return self._inner.table(name, **kw)
         return self._inner.table(name, part=part,
@@ -220,8 +229,19 @@ def test_three_stage_pipeline_streams_through_middle_stage():
     row-preserving fragment whose input is a RemoteSourceNode) emits
     output tokens while stage-1 is still RUNNING — pages flow through
     every stage of the section concurrently
-    (SqlTaskExecution.java:509 semantics)."""
-    conn = SlowScanConnector(TpchConnector(SF), "lineitem", 0.25)
+    (SqlTaskExecution.java:509 semantics). No clock decides it: stage 1
+    scans its n-th split only once this test has seen stage 2 put out
+    what the split before it became, so stage 1 cannot finish first
+    however slow the machine is, and a stage 2 that held its input
+    back until stage 1 was done would leave the gate shut."""
+    seen = threading.Condition()
+    s2_token = [0]
+
+    def gate(n):
+        with seen:
+            seen.wait_for(lambda: s2_token[0] >= n, timeout=60)
+
+    conn = SlowScanConnector(TpchConnector(SF), "lineitem", 0.0, gate)
     srv = TpuWorkerServer(conn).start()
     try:
         # stage 1: leaf project fragment over the slow scan (streams
@@ -263,7 +283,14 @@ def test_three_stage_pipeline_streams_through_middle_stage():
             s1 = _status(srv.port, "p3s1.0.0.0")
             if s1["state"] == "RUNNING" and stream.token > 0:
                 s2_tokens_while_s1_running.add(stream.token)
-        assert _status(srv.port, "p3s2.0.0.0")["state"] == "FINISHED"
+            with seen:
+                s2_token[0] = stream.token
+                seen.notify_all()
+        # the last frame is out before the task's state turns
+        s2 = _status(srv.port, "p3s2.0.0.0")
+        while s2["state"] == "RUNNING" and time.time() < deadline:
+            s2 = _status(srv.port, "p3s2.0.0.0")
+        assert s2["state"] == "FINISHED", s2
         assert len(s2_tokens_while_s1_running) >= 2, \
             s2_tokens_while_s1_running
 
