@@ -31,6 +31,8 @@ import decimal as _decimal
 import hashlib
 import itertools
 import struct
+import threading
+import weakref
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 import jax
@@ -87,7 +89,7 @@ class StringDict:
     pytree aux data without hashing millions of strings per jit-cache lookup;
     keep one instance per table column and reuse it."""
 
-    __slots__ = ("words", "sparse", "_wire")
+    __slots__ = ("words", "sparse", "_wire", "__weakref__")
 
     def __init__(self, words: Sequence[str], sparse: bool = False):
         self.words: Tuple[str, ...] = tuple(words)
@@ -654,6 +656,27 @@ class Page:
 # Host-side page assembly (exchange data plane, outside jit)
 # ---------------------------------------------------------------------------
 
+# A dictionary made by a fuse is the same object when its words are the
+# same. StringDict hashes by identity and sits in Column's pytree aux, so
+# a new object for the words of the last statement's fuse is a new jit
+# cache key: every program above the fuse would be traced and compiled
+# again, and each trace would pin its dictionary in that cache. Keyed by
+# (number of words, the 128-bit digest `wire_form` computes and keeps
+# for the codec anyway); weak, so a dictionary lives as long as a page
+# or a cached program names it.
+_INTERNED: "weakref.WeakValueDictionary" = weakref.WeakValueDictionary()
+_INTERNED_LOCK = threading.Lock()
+
+
+def intern_string_dict(d: StringDict) -> StringDict:
+    """The one StringDict of `d`'s words: `d` itself where they are new.
+    For dictionaries a fuse has just made (`compact_string_dict`,
+    `merge_string_dicts`), never for a sparse one."""
+    key = (len(d), d.wire_form()[2])
+    with _INTERNED_LOCK:
+        return _INTERNED.setdefault(key, d)
+
+
 def merge_string_dicts(dicts: Sequence[Optional[StringDict]]
                        ) -> Tuple[StringDict, List[np.ndarray]]:
     """Union N sorted dictionaries into one sorted dictionary; returns the
@@ -671,7 +694,7 @@ def merge_string_dicts(dicts: Sequence[Optional[StringDict]]
     word_lists = [list(d.words) if d is not None else [] for d in dicts]
     union = sorted(set().union(*[set(w) for w in word_lists]))
     union_arr = np.asarray(union, dtype=object).astype(str)
-    out = StringDict(union)
+    out = intern_string_dict(StringDict(union))
     remaps = []
     for words in word_lists:
         if not words:
@@ -684,18 +707,20 @@ def merge_string_dicts(dicts: Sequence[Optional[StringDict]]
 
 
 def compact_string_dict(dictionary: StringDict, codes: np.ndarray,
-                        nulls: np.ndarray) -> Tuple[StringDict, np.ndarray]:
+                        nulls: np.ndarray, interned: bool = True
+                        ) -> Tuple[StringDict, np.ndarray]:
     """The dictionary of exactly the words that the rows use, and the
     rows' codes into it: one pass of arrays over the rows and one over
     the words. What a page decoded from the wire has always carried (a
     sorted dictionary of the words present), and what
     `ops/aggregate._direct_domains` and every lowering that reads
     `len(dictionary)` therefore see. A null string crosses the wire as a
-    null slot and decodes as "", so "" stays where a row is null. Always
-    a new StringDict, as a decoded page's has always been."""
+    null slot and decodes as "", so "" stays where a row is null. The
+    same StringDict for the same words (`intern_string_dict`), unless the
+    caller unions the result straight away (`interned` false)."""
     k = len(dictionary)
     if not k:
-        return StringDict(()), codes
+        return intern_string_dict(StringDict(())), codes
     some_null = bool(nulls.any())
     used = np.zeros(k, dtype=bool)
     used[codes[~nulls] if some_null else codes] = True
@@ -705,7 +730,8 @@ def compact_string_dict(dictionary: StringDict, codes: np.ndarray,
     words = itertools.compress(dictionary.words, used.tolist())
     # null rows hold the sort sentinel once they have been a Column
     codes = remap[np.where(nulls, 0, codes) if some_null else codes]
-    return StringDict(words), codes
+    out = StringDict(words)
+    return (intern_string_dict(out) if interned else out), codes
 
 
 def concat_pages_host(pages: Sequence[Page],
@@ -757,9 +783,12 @@ def concat_pages_host(pages: Sequence[Page],
             if any(d is not d0 for d, _v, _nl in parts):
                 # a dictionary from the wire holds words its page does
                 # not use: union the words in use, as before
-                parts = [(*compact_string_dict(d, v, nl), nl)
-                         if d is not None and d.sparse else (d, v, nl)
-                         for d, v, nl in parts]
+                # (the per-page dictionaries go no further than the
+                # union below, so they stay out of the intern table)
+                parts = [
+                    (*compact_string_dict(d, v, nl, interned=False), nl)
+                    if d is not None and d.sparse else (d, v, nl)
+                    for d, v, nl in parts]
             union, remaps = merge_string_dicts([d for d, _v, _nl in parts])
             for (d, v, nl), remap in zip(parts, remaps):
                 if d is not union and len(remap):

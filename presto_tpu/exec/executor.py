@@ -19,6 +19,7 @@ subsequent page/split batch at that bucket).
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -38,6 +39,7 @@ from presto_tpu.obs.metrics import (
 from presto_tpu.ops.aggregate import grouped_aggregate
 from presto_tpu.ops.join import hash_join, merge_join
 from presto_tpu.ops.sort import limit_page, sort_page, top_n
+from presto_tpu.exec.program_cache import Program, ProgramCache
 from presto_tpu.utils.tracing import TRACER, now
 from presto_tpu.plan.nodes import (
     AggregationNode, AssignUniqueIdNode, ExchangeNode, FilterNode,
@@ -61,14 +63,17 @@ _M_OP_ROWS = _obs_histogram(
     buckets=DEFAULT_ROWS_BUCKETS)
 
 
-@dataclasses.dataclass
+# What a lowering asks for as its inputs. Frozen: the specs are part of a
+# program's cache key, capacities and all, because `_lower` closes Python
+# integers derived from them into the program.
+@dataclasses.dataclass(frozen=True)
 class ScanSpec:
     table: str
     columns: Tuple[str, ...]
     capacity: int
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class RemoteSpec:
     """Input read from another fragment's result (the consumer side of a
     cut exchange; reference: RemoteSourceNode -> ExchangeOperator)."""
@@ -85,11 +90,12 @@ class PageInputNode(PlanNode):
     slot: int = 0
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class PageInputSpec:
     """Scan-slot marker resolved from the executor's per-execution
     island inputs (no connector fetch)."""
     slot: int
+    capacity: int
 
 
 class Overflow(Exception):
@@ -137,6 +143,14 @@ def _operators(plan: PlanNode) -> List[str]:
     return list(kinds)
 
 
+def _same(old, new) -> bool:
+    """`new` is `old`, or a tuple of the objects `old` is a tuple of."""
+    return new is old or (
+        isinstance(old, tuple) and isinstance(new, tuple)
+        and len(old) == len(new)
+        and all(a is b for a, b in zip(old, new)))
+
+
 def _row_bytes(types) -> int:
     """Bytes per row of a page with these column types (values + null
     mask lane) — the static footprint unit of capacity accounting."""
@@ -145,15 +159,18 @@ def _row_bytes(types) -> int:
 
 class Executor:
     """Executes a plan against a connector. Compiles once per (plan,
-    capacity assignment); overflow retries bump capacities."""
+    capacity assignment, input specs) in its program cache; overflow
+    retries bump capacities."""
 
-    def __init__(self, connector, session=None):
+    def __init__(self, connector, session=None, programs=None):
         from presto_tpu.config import Session
 
         self.connector = connector
         self.session = session or Session()
-        self._compiled: Dict = {}   # (plan, caps) -> (jitted, scans, watch)
-        self._learned: Dict = {}    # plan -> learned capacity assignment
+        #: jitted programs and learned capacities: the worker's when a
+        #: task manager built this executor, else its own
+        self.programs: ProgramCache = (ProgramCache() if programs is None
+                                       else programs)
         # Static memory accounting (reference: memory/MemoryPool.java —
         # here capacities are static, so the whole footprint is known at
         # lower time). None = unlimited.
@@ -177,7 +194,8 @@ class Executor:
         # per-node output row counts from the last execution.
         self.last_node_rows: Dict[int, int] = {}
         self._node_map: Dict[int, tuple] = {}   # nid -> (plan node, cap)
-        self._stats_ids: List[int] = []
+        #: the last lowering's stats node ids, once its closure is traced
+        self.last_stats_box: List[int] = []
 
     def execute(self, plan: PlanNode) -> Page:
         import time
@@ -304,6 +322,7 @@ class Executor:
             return m
 
         mini = rec(plan, True)
+        del rec          # it calls itself and closes over `self`: a cycle
         # stable per-island stats-id base: islands build in a
         # deterministic traversal order, so len(cache) is reproducible
         base = (len(cache) + 1) * 1_000_000
@@ -365,6 +384,12 @@ class Executor:
                 result = run(plan)
             finally:
                 self._stats_base = 0
+                # `run` calls itself, so it and the cells it closes over
+                # (`self`, and through it the task's pages) are a
+                # reference cycle: emptied, or every page of the fragment
+                # waits on the device for the cyclic collector
+                run_memo.clear()
+                run = None
             if profile:
                 return result
             resolved = self._await_counters(pendings)
@@ -524,12 +549,14 @@ class Executor:
             return
         key = self._plan_fingerprint(plan)
         entry = {str(k): int(v) for k, v in caps.items()}
-        # in-memory dedup: streaming paths execute the same plan once
-        # per lifespan/chunk — only the FIRST convergence (or a capacity
-        # change) touches the file
-        saved = self.__dict__.setdefault("_saved_caps", {})
+        # in-memory dedup: streaming paths and later tasks execute the
+        # same plan again and again — only the FIRST convergence (or a
+        # capacity change) touches the file
+        saved = self.programs.saved
         if saved.get(key) == entry:
             return
+        if len(saved) >= 512:       # the file's own bound, below
+            saved.clear()
         saved[key] = entry
         path = self._caps_store_path()
         try:
@@ -560,12 +587,14 @@ class Executor:
         `_resolve_counters` — island execution defers every island's
         sync to the end of the chain, so K islands cost ONE wait for
         results instead of K host<->device syncs."""
-        caps: Dict = self._learned.setdefault(plan, None)
-        if caps is None:
-            caps = self._learned[plan] = self._load_caps(plan)
+        # a copy of what the cache's owner has learned for this plan
+        # (the caps file's, where the plan is new to it): concurrent tasks
+        # of one plan share the learning, never a dict
+        caps: Dict = self.programs.caps(plan, self._load_caps)
         # _lower is cheap (no tracing) and fills `caps` with its chosen
         # capacities, which completes the compilation cache key.
-        fn, scans, watch = self._lower(plan, caps)
+        lowered, scans, watch = self._lower(plan, caps)
+        stats_box = self.last_stats_box
         if self.memory_pool is not None:
             # admission control: swap the PREVIOUS attempt's
             # reservation for this one (capacity-grow retries must
@@ -575,14 +604,20 @@ class Executor:
             self.memory_pool.reserve(self.pool_query_id,
                                      self.last_memory_estimate)
             pool_prev = self.last_memory_estimate
-        key = (plan, tuple(sorted(caps.items(), key=repr)),
-               bool(self.session["collect_stats"]))
-        entry = self._compiled.get(key)
-        first_call = entry is None
-        if first_call:
-            # stats_box is filled at this entry's first execution
+        # Key and capacities are everything _lower read: the plan, the
+        # input specs (each with the capacity its page has: the closure
+        # holds integers derived from them), the hooks' class, and of the
+        # session collect_stats with the stats ids' base. Whoever reaches
+        # equal ones, in this task or a later one, runs the same program.
+        collect_stats = bool(self.session["collect_stats"])
+        key = (type(self), plan, tuple(scans), collect_stats,
+               getattr(self, "_stats_base", 0) if collect_stats else 0)
+        lowered_at = tuple(sorted(caps.items(), key=repr))
+
+        def make() -> Program:
+            # stats_box is filled at the program's first execution
             # (trace time fixes the node-id order for its lifetime).
-            program = self._wrap(fn)
+            program = self._wrap(lowered)
             program.__name__ = program.__qualname__ = \
                 self.program_name(plan)
             # what its `dispatch` spans say of the program ("+" joins
@@ -596,20 +631,21 @@ class Executor:
                 about["join_types"] = "+".join(self.last_join_types)
             if self.last_agg_steps:
                 about["agg_steps"] = "+".join(self.last_agg_steps)
-            entry = (jax.jit(program), scans, watch, [], about)
-            self._compiled[key] = entry
+            return Program(jax.jit(program), lowered_at, scans, watch,
+                           stats_box, about)
+
+        # on a hit this lowering's closure is dropped for the kept one's
+        program, first_call = self.programs.program(key, lowered_at, make)
+        if first_call:
             self._note_compile(plan)
-        fn, scans, watch, stats_box, about = entry
-        pages = [self._fetch(s) for s in scans]
-        self._stats_ids = []
-        # on a new executor the first call is Python trace + lowering +
-        # compile or cache read + enqueue; later calls only enqueue
-        with TRACER.span(None, "dispatch", first_call=first_call, **about):
-            out, needed = fn(pages)
-        if self._stats_ids and not stats_box:
-            stats_box.extend(self._stats_ids)
-        pending = {"plan": plan, "caps": caps, "watch": watch,
-                   "needed": needed, "stats_box": stats_box,
+        pages = [self._fetch(s) for s in program.scans]
+        # where the cache had no such program, the call is Python trace +
+        # lowering + compile or cache read + enqueue; else it only enqueues
+        with TRACER.span(None, "dispatch", first_call=first_call,
+                         **program.about):
+            out, needed = program(pages)
+        pending = {"plan": plan, "caps": caps, "watch": program.watch,
+                   "needed": needed, "stats_box": program.stats_box,
                    "pool_prev": pool_prev}
         return out, pending
 
@@ -622,6 +658,8 @@ class Executor:
             if need > caps[nid]:
                 caps[nid] = bucket_capacity(need)
                 grew = True
+        if grew:
+            self.programs.learn(pending["plan"], caps)
         return grew
 
     def _anneal_caps(self, pending, needed) -> None:
@@ -638,16 +676,17 @@ class Executor:
         recoverable: every watched counter reports its unclamped need
         and rides the normal overflow-retry loop."""
         caps = pending["caps"]
-        peaks = self.__dict__.setdefault("_peak_needs", {}) \
-            .setdefault(pending["plan"], {})
+        plan = pending["plan"]
+        lowered = []
         for nid, need in zip(pending["watch"], needed):
             if isinstance(nid, int) and nid < 0:
                 continue    # merge-join duplicate flags, not capacities
-            peak = max(peaks.get(nid, 0), int(need))
-            peaks[nid] = peak
+            peak = self.programs.peak(plan, nid, int(need))
             tgt = bucket_capacity(max(peak + (peak >> 2), 64))
             if tgt < caps[nid]:
                 caps[nid] = tgt
+                lowered.append(nid)
+        self.programs.learn(plan, caps, lowered)
 
     def _finish_counters(self, pending, needed) -> None:
         """Converged program: raise checked-arithmetic errors, record
@@ -716,13 +755,19 @@ class Executor:
     def _scan_rows(self, node) -> int:
         return self.connector.table(node.table).num_rows
 
-    def _unique_ids(self, p: Page) -> jnp.ndarray:
+    # Trace-time hooks: static here, so that a lowered closure holds no
+    # executor (a program outlives the task that made it); the mesh
+    # executor overrides them with methods and keeps a cache of its own.
+    @staticmethod
+    def _unique_ids(p: Page) -> jnp.ndarray:
         return jnp.arange(p.capacity, dtype=jnp.int64)
 
-    def _finish_agg(self, node, out: Page) -> Page:
+    @staticmethod
+    def _finish_agg(node, out: Page) -> Page:
         return out
 
-    def _finish_values(self, out: Page) -> Page:
+    @staticmethod
+    def _finish_values(out: Page) -> Page:
         return out
 
     def _remote_input(self, node, scans):
@@ -812,9 +857,19 @@ class Executor:
                     repl = {"sources": kids}
                 elif "source" in names:
                     repl = {"source": kids[0]}
-            return dataclasses.replace(node, **repl) if repl else node
+            if all(_same(getattr(node, k), v) for k, v in repl.items()):
+                # nothing under it changed: the plan keeps its identity,
+                # and with it its islands and their stats ids, so that a
+                # plan executed once a chunk is one program, not one a
+                # chunk (`_island_of` is keyed by identity)
+                return node
+            return dataclasses.replace(node, **repl)
 
-        return rewrite(plan)
+        try:
+            return rewrite(plan)
+        finally:
+            # they call themselves and close over `self`: a cycle
+            del rewrite, rewrite_expr
 
     # ------------------------------------------------------------------
     def _lower(self, plan: PlanNode, caps: Dict[int, int]
@@ -844,6 +899,11 @@ class Executor:
         mem_bytes = [0]
         collect_stats = bool(self.session["collect_stats"])
         _node_rows: List = []
+        stats_box: List[int] = []
+        # what the traced closures call of the executor, by value
+        finish_agg = self._finish_agg
+        finish_values = self._finish_values
+        unique_ids = self._unique_ids
         if base == 0:
             self._node_map = {}
         # island mode (base > 0): maps ACCUMULATE across the query's
@@ -877,8 +937,8 @@ class Executor:
             nid = node_id(node)
             if isinstance(node, PageInputNode):
                 idx = len(scans)
-                scans.append(PageInputSpec(node.slot))
                 cap = self._island_inputs[node.slot].capacity
+                scans.append(PageInputSpec(node.slot, cap))
                 return (lambda pages: pages[idx]), cap
             if isinstance(node, TableScanNode):
                 # Exact row count (generation is cached), not the planner
@@ -898,7 +958,7 @@ class Executor:
                             __import__("numpy").array(
                                 [r[i] for r in node.rows]), t)
                         for i, t in enumerate(node.output_types))
-                    return self._finish_values(
+                    return finish_values(
                         Page(cols, jnp.asarray(n, jnp.int32), ()))
                 return values_fn, bucket_capacity(max(len(node.rows), 1))
             if isinstance(node, FilterNode):
@@ -967,7 +1027,7 @@ class Executor:
                         p, node.group_fields, node.aggs, out_cap,
                         row_mask=mask)
                     _needed.append(true_groups)
-                    return self._finish_agg(node, out)
+                    return finish_agg(node, out)
                 return agg_fn, out_cap
             if isinstance(node, JoinNode):
                 psrc, pcap = build(node.probe)
@@ -1157,7 +1217,7 @@ class Executor:
 
                 def rowid_fn(pages, node=node):
                     p = src(pages)
-                    ids = self._unique_ids(p)
+                    ids = unique_ids(p)
                     col = Column(ids, ~p.row_valid(),
                                  node.output_types[-1], None)
                     return Page(p.columns + (col,), p.num_rows,
@@ -1289,30 +1349,44 @@ class Executor:
         join_types: List[str] = []
         agg_steps: List[str] = []
         root, _cap = build(plan)
+        # build and build_inner call each other and close over `self`: a
+        # reference cycle that would keep the executor, and the pages it
+        # holds, on the device until the cyclic collector runs. Nothing
+        # traced refers to them; emptying their cells breaks the cycle.
+        del build, build_inner
         self.last_memory_estimate = mem_bytes[0]
         self.last_join_paths = join_paths
         self.last_join_types = join_types
         self.last_agg_steps = agg_steps
+        self.last_stats_box = stats_box
         if self.memory_limit_bytes is not None \
                 and mem_bytes[0] > self.memory_limit_bytes:
             raise MemoryLimitExceeded(mem_bytes[0],
                                       self.memory_limit_bytes)
 
+        # the closures share the three lists above while a trace runs:
+        # a retrace (another dictionary on a string column) waits its turn
+        tracing = threading.Lock()
+
         def run(pages):
             from presto_tpu.expr import errors as E
-            _needed.clear()
-            run_cache.clear()
-            _node_rows.clear()
-            with E.collecting() as coll:
-                out = root(pages)
-                err = coll.combined()
-            # The checked-arithmetic error lane rides right after the
-            # capacity counters, then stats, in one stacked array (a
-            # single host transfer); the stats node-id order is fixed at
-            # trace time.
-            self._stats_ids = [nid for nid, _ in _node_rows]
-            extras = [r for _nid, r in _node_rows]
-            all_counters = list(_needed) + [err] + extras
+            with tracing:
+                try:
+                    with E.collecting() as coll:
+                        out = root(pages)
+                        err = coll.combined()
+                    # The checked-arithmetic error lane rides right after
+                    # the capacity counters, then stats, in one stacked
+                    # array (a single host transfer); the stats node-id
+                    # order is fixed at trace time.
+                    stats_box[:] = [nid for nid, _ in _node_rows]
+                    extras = [r for _nid, r in _node_rows]
+                    all_counters = list(_needed) + [err] + extras
+                finally:
+                    # a kept program keeps no tracer of its last trace
+                    _needed.clear()
+                    run_cache.clear()
+                    _node_rows.clear()
             counters = jnp.stack(
                 [jnp.asarray(n, jnp.int64) for n in all_counters])
             return out, counters

@@ -14,7 +14,7 @@ from presto_tpu.data.column import Column, Page
 from presto_tpu.exec.executor import Executor, ScanSpec
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class RemotePageSpec:
     """Scan-slot placeholder for an input pulled from upstream tasks
     (bound by node id; reference: RemoteSourceNode -> ExchangeOperator)."""
@@ -23,8 +23,8 @@ class RemotePageSpec:
 
 
 class SplitExecutor(Executor):
-    def __init__(self, connector, session=None):
-        super().__init__(connector, session=session)
+    def __init__(self, connector, session=None, programs=None):
+        super().__init__(connector, session=session, programs=programs)
         self.splits: Dict[str, List[Tuple[int, int]]] = {}
         # table -> pre-materialized host table (one streaming scan run);
         # consulted BEFORE split (part, numParts) resolution so lifespan

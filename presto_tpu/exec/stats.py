@@ -117,8 +117,7 @@ def explain_analyze(engine, sql: str) -> str:
     plan = ex._prepare(plan)
     old = ex.session.values["collect_stats"]
     ex.session.values["collect_stats"] = True
-    # collect_stats changes the traced program: bypass stale compiles.
-    compiled, ex._compiled = ex._compiled, {}
+    # collect_stats changes the traced program, and is in its cache key
     try:
         t0 = time.perf_counter()
         ex.last_node_rows = {}
@@ -138,4 +137,3 @@ def explain_analyze(engine, sql: str) -> str:
             est=lambda n: estimate_rows(n, engine.connector, history))
     finally:
         ex.session.values["collect_stats"] = old
-        ex._compiled = compiled

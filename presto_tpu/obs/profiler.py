@@ -131,14 +131,18 @@ class SamplingProfiler:
 
     # ------------------------------------------------------------- sampling
     def _sample_once(self) -> None:
-        me = threading.get_ident()
         frames = sys._current_frames()
+        # Not this thread's own frame: it holds `frames`, and `frames`
+        # would hold it -- a reference cycle around EVERY thread's stack,
+        # locals and all, a hundred times a second. Until the cyclic
+        # collector came by, each device page a worker thread had in
+        # hand at a sample stayed on the device (PERF.md, PR 32:
+        # `peak_hbm_gb` followed how often the collector ran).
+        del frames[threading.get_ident()]
         names = {t.ident: t.name for t in threading.enumerate()}
         traces = thread_traces()
         with self._lock:
             for tid, frame in frames.items():
-                if tid == me:
-                    continue
                 role, purpose = _parse_thread_name(
                     names.get(tid, "?"))
                 stack = self._collapse(frame)

@@ -396,6 +396,12 @@ class TpuTaskManager:
         )
 
         self.connector = connector
+        # The worker, not the task, owns the jitted programs and the
+        # capacities learned for them (exec/program_cache.py): a task's
+        # executor lives for one fragment, and a repeated statement
+        # would trace, lower and compile every program again.
+        from presto_tpu.exec.program_cache import ProgramCache
+        self.programs = ProgramCache()
         # cluster mesh execution tier (server/mesh_tier.py): owns this
         # worker's mesh slice, advertises it, and runs eligible task
         # fragments mesh-lowered with generic fallback
@@ -631,7 +637,8 @@ class TpuTaskManager:
                 # per-operator row counters feed the TaskInfo stats tree the
                 # coordinator renders (OperatorStats role) — on by default
                 props.setdefault("collect_stats", "true")
-                ex = SplitExecutor(self.connector, session=Session(props))
+                ex = SplitExecutor(self.connector, session=Session(props),
+                                   programs=self.programs)
                 if self.memory_pool is not None:
                     # static footprints reserve against the worker pool as
                     # programs dispatch; the unique task-id key lets

@@ -348,7 +348,7 @@ def test_filtering_semi_join_learns_its_survivors_capacity(
         want = ex.execute(plan)
         n = int(want.num_rows)
         assert n == 904 and want.capacity == 16384
-        for caps in ex._learned.values():
+        for caps in ex.programs.learned.values():
             for nid in [k for k in (caps or {}) if isinstance(k, int)
                         and k > 0]:
                 caps[nid] = 256

@@ -11,7 +11,11 @@ SF1, where the benchmark's cell `q18_serial` runs, both lie above it and
 every join is partitioned: eight fragments, the 6M-row probe exchanged
 three times, the SEMI join beside a SINGLE aggregation. The last case
 lowers the threshold to get that plan here. The 300 case also reads the
-statement's `dispatch` spans for `join_types` and `agg_steps`."""
+statement's `dispatch` spans for `join_types` and `agg_steps`.
+
+Last, Q18 and Q3 (the benchmark's two templates) each three times through
+`POST /v1/statement`: the workers keep their programs, so once the
+capacities have settled a repeated statement compiles nothing."""
 
 import os
 import sys
@@ -20,7 +24,9 @@ import pytest
 
 from presto_tpu.connectors import TpchConnector
 from presto_tpu.protocol import serde
+from presto_tpu.exec.program_cache import _PROGRAMS
 from presto_tpu.server.cluster import TpuCluster
+from presto_tpu.server.statement import StatementServer, run_statement
 from presto_tpu.utils.tracing import TRACER
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -29,6 +35,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import compare  # noqa: E402
+from compile_counter import compile_counter  # noqa: E402
 import qgen  # noqa: E402
 import run as bench_run  # noqa: E402
 
@@ -100,3 +107,41 @@ def test_q18_agrees_with_the_plain_reference(connector, q18, quantity,
                if s.name == "serialize") > 0
     if quantity == 300:
         holds_what_each_program_joins_and_aggregates(dispatched)
+
+
+@pytest.mark.parametrize("name, params", [
+    ("q18", {"QUANTITY": 250}),
+    ("q03", {"SEGMENT": "BUILDING", "DATE": "1995-03-15"})],
+    ids=["q18", "q03"])
+def test_a_repeated_statement_compiles_nothing(connector, name, params):
+    """The first statement learns its capacities and the second runs the
+    annealed variants (a checkout whose caps file has converged skips
+    both); from then on every island of every task is a program its
+    worker has kept (`presto_tpu_program_cache_total`) under dictionaries
+    `jax.jit` has seen: no trace, no lowering, no compile request."""
+    query = qgen.load_query(name)
+    sql = query["sql"].format(**params)
+    want = compare.load_reference(query)(bench_run.Tables(connector), params)
+    cluster = TpuCluster(connector, n_workers=2)
+    server = StatementServer(cluster).start()
+    counter = compile_counter()
+    runs = []
+    try:
+        for _ in range(3):
+            requests = counter.requests
+            misses = _PROGRAMS.value(result="miss")
+            hits = _PROGRAMS.value(result="hit")
+            _columns, rows = run_statement(server.base, sql)
+            runs.append((rows, counter.requests - requests,
+                         _PROGRAMS.value(result="miss") - misses,
+                         _PROGRAMS.value(result="hit") - hits))
+    finally:
+        server.stop()
+        cluster.stop()
+    (first, compiled, missed, _hit), _second, (third, *steady) = runs
+    assert compiled > 0 and missed > 0
+    assert steady[:2] == [0, 0] and steady[2] > 0
+    assert third == first and len(third) == len(want) > 0
+    gaps = compare.row_gaps(third, want)
+    assert gaps["wrong_cells"] == 0
+    assert gaps["max_rel_err"] <= query["limits"]["max_rel_err"]

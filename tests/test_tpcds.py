@@ -38,8 +38,7 @@ def _drop_compile_caches(engine):
     TPC-H suite)."""
     yield
     import jax
-    engine.executor._compiled.clear()
-    engine.executor._learned.clear()
+    engine.executor.programs.clear()
     jax.clear_caches()
 
 

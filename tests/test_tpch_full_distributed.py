@@ -31,8 +31,7 @@ def _drop_compile_caches(engine):
     don't re-execute each other's plans here, so drop everything."""
     yield
     import jax
-    engine.executor._compiled.clear()
-    engine.executor._learned.clear()
+    engine.executor.programs.clear()
     jax.clear_caches()
 
 

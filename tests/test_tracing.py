@@ -233,7 +233,8 @@ def test_the_device_sees_the_name_and_the_operators(engine):
     t_after = now()
     dispatched = [s for s in TRACER.get("prog1") if s.name == "dispatch"]
     programs = {s.attributes["program"]: s.attributes for s in dispatched}
-    compiled = {"jit_" + e[0].__name__ for e in ex._compiled.values()}
+    compiled = {"jit_" + e.fn.__name__
+                for e in ex.programs.jitted.values()}
     assert compiled == set(programs) and len(programs) >= 2
     joins = [a for a in programs.values()
              if "Join" in a["operators"].split("+")]
@@ -321,7 +322,8 @@ def test_each_operator_is_traced_under_its_named_scope(engine):
     ex = Executor(engine.connector)
     ex.execute(engine.plan_sql(
         "select count(*) from lineitem where l_quantity < 10"))
-    (fn, scans, _watch, _box, about), = ex._compiled.values()
+    program, = ex.programs.jitted.values()
+    fn, scans, about = program.fn, program.scans, program.about
     assert about["program"] == "jit_" + fn.__name__
     text = fn.lower([ex._fetch(s) for s in scans]).as_text(debug_info=True)
     # (a scan or an output relabels pages and leaves no operation)
