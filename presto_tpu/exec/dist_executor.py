@@ -176,9 +176,11 @@ class DistExecutor(Executor):
         if self.ndev == 1:
             return super()._wrap(fn)
 
-        def wrapped(pages):
+        def wrapped(pages, params=()):
+            # the lifted literals are the same on every device: the
+            # local function closes over them
             def local_fn(*locals_):
-                out, counters = fn(list(locals_))
+                out, counters = fn(list(locals_), params)
                 if counters.shape[0]:
                     counters = mesh_max(counters)
                 return out, counters

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from presto_tpu.data.column import (
     Column, Page, bucket_capacity, compact, page_nbytes,
 )
-from presto_tpu.expr.compile import compile_expr
+from presto_tpu.expr.compile import binding, compile_expr
+from presto_tpu.expr.params import lift_plan, same_objects
 from presto_tpu.expr.nodes import (
     Call, InputRef, Literal, RowExpression, SpecialForm,
 )
@@ -141,14 +142,6 @@ def _operators(plan: PlanNode) -> List[str]:
                 walk(c)
     walk(plan)
     return list(kinds)
-
-
-def _same(old, new) -> bool:
-    """`new` is `old`, or a tuple of the objects `old` is a tuple of."""
-    return new is old or (
-        isinstance(old, tuple) and isinstance(new, tuple)
-        and len(old) == len(new)
-        and all(a is b for a, b in zip(old, new)))
 
 
 def _row_bytes(types) -> int:
@@ -481,8 +474,11 @@ class Executor:
             pass
         salt = (type(self.connector).__name__,
                 getattr(self.connector, "sf", None), tuple(sizes))
+        # over the plan with its lifted literals blanked (types kept):
+        # every value of a literal learns and names one program
         return hashlib.sha1(
-            (repr(salt) + repr(plan)).encode()).hexdigest()[:24]
+            (repr(salt) + repr(lift_plan(plan).plan)).encode()
+        ).hexdigest()[:24]
 
     def program_name(self, plan: PlanNode) -> str:
         """What the device trace calls the island's program
@@ -587,13 +583,19 @@ class Executor:
         `_resolve_counters` — island execution defers every island's
         sync to the end of the chain, so K islands cost ONE wait for
         results instead of K host<->device syncs."""
+        # The literals whose value shapes nothing leave the plan and are
+        # handed to the program when it is called (expr/params.py): what
+        # is lowered, cached, named and learned from here on is the plan
+        # with their places blank, one program for every value of them.
+        lifted = lift_plan(plan)
+        plan = lifted.plan
         # a copy of what the cache's owner has learned for this plan
         # (the caps file's, where the plan is new to it): concurrent tasks
         # of one plan share the learning, never a dict
         caps: Dict = self.programs.caps(plan, self._load_caps)
         # _lower is cheap (no tracing) and fills `caps` with its chosen
         # capacities, which completes the compilation cache key.
-        lowered, scans, watch = self._lower(plan, caps)
+        lowered, scans, watch = self._lower(plan, caps, lifted.origin)
         stats_box = self.last_stats_box
         if self.memory_pool is not None:
             # admission control: swap the PREVIOUS attempt's
@@ -642,8 +644,8 @@ class Executor:
         # where the cache had no such program, the call is Python trace +
         # lowering + compile or cache read + enqueue; else it only enqueues
         with TRACER.span(None, "dispatch", first_call=first_call,
-                         **program.about):
-            out, needed = program(pages)
+                         params=len(lifted.values), **program.about):
+            out, needed = program(pages, lifted.values)
         pending = {"plan": plan, "caps": caps, "watch": program.watch,
                    "needed": needed, "stats_box": program.stats_box,
                    "pool_prev": pool_prev}
@@ -672,9 +674,12 @@ class Executor:
         Each converged run updates a per-counter peak and re-buckets
         the cap at peak + 25% headroom; peaks are monotone, so the cap
         steps down to the true requirement and stays there instead of
-        flip-flopping. An undershoot on later, larger data is always
-        recoverable: every watched counter reports its unclamped need
-        and rides the normal overflow-retry loop."""
+        flip-flopping. The peak is kept under the plan with its lifted
+        literals blanked, so it is the largest need any literal value
+        has shown. An undershoot on later, larger data, or on a first
+        less selective literal, is always recoverable: every watched
+        counter reports its unclamped need and rides the normal
+        overflow-retry loop (one re-lowering at the next bucket)."""
         caps = pending["caps"]
         plan = pending["plan"]
         lowered = []
@@ -857,7 +862,8 @@ class Executor:
                     repl = {"sources": kids}
                 elif "source" in names:
                     repl = {"source": kids[0]}
-            if all(_same(getattr(node, k), v) for k, v in repl.items()):
+            if all(same_objects(getattr(node, k), v)
+                   for k, v in repl.items()):
                 # nothing under it changed: the plan keeps its identity,
                 # and with it its islands and their stats ids, so that a
                 # plan executed once a chunk is one program, not one a
@@ -872,10 +878,15 @@ class Executor:
             del rewrite, rewrite_expr
 
     # ------------------------------------------------------------------
-    def _lower(self, plan: PlanNode, caps: Dict[int, int]
+    def _lower(self, plan: PlanNode, caps: Dict[int, int],
+               origin: Optional[Dict[int, PlanNode]] = None
                ) -> Tuple[Callable, List[ScanSpec], List[int]]:
-        """Build (traced_fn(pages) -> (Page, needed[]), scan specs,
-        watched node ids). Node ids are stable pre-order positions."""
+        """Build (traced_fn(pages, params) -> (Page, needed[]), scan
+        specs, watched node ids). Node ids are stable pre-order
+        positions. `params` are the values of the plan's `Param`s;
+        `origin` maps a node that `lift_plan` rebuilt to the statement's
+        own, which is what the stats maps keep."""
+        origin = origin or {}
         scans: List[ScanSpec] = []
         watch: List[int] = []
         counter = [0]
@@ -916,7 +927,7 @@ class Executor:
             nid_stats = base + counter[0] + 1  # id build_inner assigns
             fn, cap = build_inner(node)
             mem_bytes[0] += cap * _row_bytes(node.output_types)
-            self._node_map[nid_stats] = (node, cap)
+            self._node_map[nid_stats] = (origin.get(id(node), node), cap)
 
             def cached(pages, fn=fn, key=key, nid=nid_stats,
                        kind=_kind(node)):
@@ -1368,11 +1379,11 @@ class Executor:
         # a retrace (another dictionary on a string column) waits its turn
         tracing = threading.Lock()
 
-        def run(pages):
+        def run(pages, params=()):
             from presto_tpu.expr import errors as E
             with tracing:
                 try:
-                    with E.collecting() as coll:
+                    with E.collecting() as coll, binding(params):
                         out = root(pages)
                         err = coll.combined()
                     # The checked-arithmetic error lane rides right after

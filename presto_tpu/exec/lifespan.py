@@ -455,7 +455,7 @@ class BatchedRunner:
     def _finish_above(self, page: Page) -> Page:
         # Interpret the small chain above the aggregation.
         from presto_tpu.data.column import compact
-        from presto_tpu.expr.compile import compile_expr
+        from presto_tpu.expr.params import evaluate
 
         for node in reversed(self.above):
             if isinstance(node, SortNode):
@@ -465,11 +465,11 @@ class BatchedRunner:
             elif isinstance(node, LimitNode):
                 page = limit_page(page, node.count)
             elif isinstance(node, ProjectNode):
-                cols = tuple(compile_expr(e)(page)
+                cols = tuple(evaluate(e, page)
                              for e in node.expressions)
                 page = Page(cols, page.num_rows, node.output_names)
             elif isinstance(node, FilterNode):         # HAVING
-                c = compile_expr(node.predicate)(page)
+                c = evaluate(node.predicate, page)
                 page = compact(page, ~c.nulls & c.values.astype(bool))
             else:  # OutputNode
                 page = Page(page.columns, page.num_rows,

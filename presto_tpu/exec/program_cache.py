@@ -11,7 +11,10 @@ its own, and behaves as it always has.
 
 Everything here is keyed by value (a plan is a frozen dataclass tree),
 so nothing of the task that first lowered a program is kept: not its
-executor, not its pages."""
+executor, not its pages. The plans are the executor's blanked ones
+(expr/params.py): a literal whose value shapes nothing is an input of the
+program and no part of a key, so one program, one capacity assignment and
+one set of peaks serve every value of it."""
 
 from __future__ import annotations
 
@@ -51,11 +54,13 @@ class Program:
         self.lock = threading.Lock()
         self.traced = False
 
-    def __call__(self, pages):
+    def __call__(self, pages, params=()):
+        """`params`: the values of the plan's lifted literals, 0-d
+        arrays the trace reads where the plan has a `Param`."""
         if self.traced:
-            return self.fn(pages)
+            return self.fn(pages, params)
         with self.lock:
-            out = self.fn(pages)
+            out = self.fn(pages, params)
             self.traced = True
         return out
 
@@ -125,7 +130,10 @@ class ProgramCache:
                     shared[k] = v
 
     def peak(self, plan, counter, need: int) -> int:
-        """The largest `need` this counter of `plan` has reported."""
+        """The largest `need` this counter of `plan` has reported, over
+        every value its lifted literals have taken: annealing sizes a
+        capacity from this, so a selective literal cannot shrink what a
+        less selective one needed."""
         with self._lock:
             if plan not in self.learned:      # evicted: start over
                 return need

@@ -130,7 +130,7 @@ def _apply_rowwise(above: List[PlanNode], page: Page) -> Page:
     """Interpret the small chain above the join (same discipline as
     lifespan.BatchedRunner._finish_above)."""
     from presto_tpu.data.column import compact as _compact
-    from presto_tpu.expr.compile import compile_expr
+    from presto_tpu.expr.params import evaluate
     from presto_tpu.ops.sort import limit_page, sort_page, top_n
 
     for node in reversed(above):
@@ -141,11 +141,11 @@ def _apply_rowwise(above: List[PlanNode], page: Page) -> Page:
         elif isinstance(node, LimitNode):
             page = limit_page(page, node.count)
         elif isinstance(node, ProjectNode):
-            cols = tuple(compile_expr(e)(page)
+            cols = tuple(evaluate(e, page)
                          for e in node.expressions)
             page = Page(cols, page.num_rows, node.output_names)
         elif isinstance(node, FilterNode):
-            c = compile_expr(node.predicate)(page)
+            c = evaluate(node.predicate, page)
             page = _compact(page, ~c.nulls & c.values.astype(bool))
         else:  # OutputNode
             page = Page(page.columns, page.num_rows, node.output_names)

@@ -23,7 +23,9 @@ PrestoException DIVISION_BY_ZERO).
 
 from __future__ import annotations
 
+import contextlib
 import re
+import threading
 from functools import reduce
 from typing import Callable, Optional
 
@@ -37,7 +39,7 @@ from presto_tpu.types import (
     Type,
 )
 from presto_tpu.expr.nodes import (
-    Call, Form, InputRef, Literal, RowExpression, SpecialForm,
+    Call, Form, InputRef, Literal, Param, RowExpression, SpecialForm,
 )
 
 # ---------------------------------------------------------------------------
@@ -346,6 +348,8 @@ def compile_expr(expr: RowExpression) -> Compiled:
             return page.columns[e.field]
         if isinstance(e, Literal):
             return _literal_column(e, cap)
+        if isinstance(e, Param):
+            return _param_column(e, cap)
         if isinstance(e, SpecialForm):
             return _special(e, page, ev)
         if isinstance(e, Call):
@@ -372,6 +376,29 @@ def _literal_column(e: Literal, cap: int) -> Column:
         lanes = I.from_python_int(int(e.value), (cap,))
         return Decimal128Column(*lanes, jnp.zeros(cap, bool), t)
     return _const_column(e.value, t, cap)
+
+
+_BOUND = threading.local()
+
+
+@contextlib.contextmanager
+def binding(params):
+    """The parameter tuple a program was handed, for the `Param`s its
+    trace evaluates (per thread: tasks trace side by side)."""
+    outer = getattr(_BOUND, "params", None)
+    _BOUND.params = params
+    try:
+        yield
+    finally:
+        _BOUND.params = outer
+
+
+def _param_column(e: Param, cap: int) -> Column:
+    """A lifted literal (expr/params.py): the 0-d value the program took
+    as an input, broadcast where `_literal_column` builds a constant."""
+    value = jnp.asarray(_BOUND.params[e.index], dtype=e.type.dtype)
+    return Column(jnp.broadcast_to(value, (cap,)),
+                  jnp.zeros((cap,), dtype=bool), e.type, None)
 
 
 def _special(e: SpecialForm, page: Page, ev) -> Column:

@@ -44,6 +44,19 @@ class Literal(RowExpression):
 
 
 @dataclasses.dataclass(frozen=True)
+class Param(RowExpression):
+    """A literal whose value the program takes as input `index` of its
+    parameter tuple (expr/params.py lifts it out of the plan before an
+    island is lowered): the plan keeps the type and the place, so every
+    value of it runs one traced program."""
+    index: int
+    type: Type
+
+    def __str__(self):
+        return f"?{self.index}:{self.type}"
+
+
+@dataclasses.dataclass(frozen=True)
 class Call(RowExpression):
     """Scalar function call. `name` is the registry key (expr/compile.py):
     arithmetic ('add','subtract','multiply','divide','modulus','negate'),

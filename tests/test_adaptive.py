@@ -342,16 +342,19 @@ def test_filtering_semi_join_learns_its_survivors_capacity(
     assert third.capacity == learned and _rows(third) == _rows(first)
 
     if op == "in":
-        # 904 survivors against a learned capacity of 256: the overflow
-        # loop re-runs at the bucket of what was needed, the same rows
-        plan = engine.plan_sql(SEMI_SQL.format(op=op, q=200))
-        want = ex.execute(plan)
-        n = int(want.num_rows)
-        assert n == 904 and want.capacity == 16384
-        for caps in ex.programs.learned.values():
-            for nid in [k for k in (caps or {}) if isinstance(k, int)
-                        and k > 0]:
-                caps[nid] = 256
-        got = ex.execute(plan)
-        assert int(got.num_rows) == n and got.capacity == 1024
-        assert _rows(got) == _rows(want)
+        # the threshold is an input of the programs (expr/params.py), so
+        # 200 runs what 250 learned: 904 survivors against a learned
+        # capacity of 256, the overflow loop re-runs at the bucket of
+        # what was needed, and an executor that never learned (a caps
+        # file of its own) gives the same rows
+        less_selective = engine.plan_sql(SEMI_SQL.format(op=op, q=200))
+        got = ex.execute(less_selective)
+        assert int(got.num_rows) == 904 and got.capacity == 1024
+        monkeypatch.setenv("PRESTO_TPU_CAPS_CACHE",
+                           str(tmp_path / "unlearned.json"))
+        want = Executor(conn).execute(less_selective)
+        assert want.capacity == 16384 and _rows(got) == _rows(want)
+        # the capacity holds the peak of the values seen: 250 again runs
+        # at 1,024 slots and lowers nothing
+        again = ex.execute(plan)
+        assert again.capacity == 1024 and _rows(again) == _rows(first)

@@ -47,6 +47,17 @@ def test_rehearsal_query_is_exact(rehearsal, query, rows):
     assert line["compilations"]["cold"] >= 1
 
 
+def test_rehearsal_answers_q06_at_every_discount_of_its_clause(rehearsal):
+    """All eight DISCOUNT values against the benchmark's reference, and
+    no program built for them: the first q06 built it."""
+    _rc, lines = rehearsal
+    [line] = [ln for ln in lines if ln.get("query") == "q06_sweep"]
+    assert line["discounts"] == [f"0.0{d}" for d in range(2, 10)]
+    assert line["exact"] is True and line["not_exact_at"] == []
+    assert line["wrong_cells"] == 0 and line["max_rel_err"] <= 1e-9
+    assert line["compilations"] == 0
+
+
 def test_refused_without_a_tpu(capsys):
     rc = chip_smoke.main(["--sf", "0.01"])
     out = capsys.readouterr().out

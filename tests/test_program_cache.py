@@ -97,12 +97,14 @@ def test_a_second_task_of_a_fragment_compiles_nothing(connector):
 
 # ---- the key, and what a kept program holds ------------------------------
 
-def _sum_over_remote(threshold: int = 10):
-    """sum(x), count(*) over the remote rows with x > threshold."""
+def _sum_over_remote(threshold: int = 10, op: str = "gt"):
+    """sum(x), count(*) over the remote rows with x > threshold (or
+    x >= threshold: another plan, where another threshold is the same
+    plan with another input)."""
     remote = RemoteSourceNode(("x",), (BIGINT,), node_id="7",
                               source_fragment_ids=("1",))
     keep = FilterNode(("x",), (BIGINT,), source=remote, predicate=Call(
-        "gt", (InputRef(0, BIGINT), Literal(threshold, BIGINT)), BOOLEAN))
+        op, (InputRef(0, BIGINT), Literal(threshold, BIGINT)), BOOLEAN))
     return AggregationNode(
         ("s", "n"), (BIGINT, BIGINT), source=keep,
         aggs=(AggSpec("sum", 0, BIGINT),
@@ -178,8 +180,9 @@ def test_tasks_reaching_a_new_key_together_trace_it_once(
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
-        for round_, threshold in enumerate((3, 5)):
-            plan = _sum_over_remote(threshold)
+        for round_, (threshold, op) in enumerate(((3, "gt"), (4, "ge"))):
+            plan = _sum_over_remote(threshold, op)
+            threshold -= op == "ge"        # x >= 4 keeps what x > 3 does
             start = threading.Barrier(len(sizes))
             rows = {}
 
@@ -215,8 +218,9 @@ def test_the_cache_evicts_the_least_recently_used_at_its_bound(connector):
     _execute(connector, cache, plan, _remote_page(30, 256))    # evicted
     assert _misses() == misses + 1 and len(cache.jitted) == 2
     # plans with learned capacities are bounded apart, the same way
-    for threshold in (1, 2, 3):
-        _execute(connector, cache, _sum_over_remote(threshold),
+    # (another comparison is another plan; another threshold is not)
+    for op in ("gt", "ge", "lt"):
+        _execute(connector, cache, _sum_over_remote(1, op),
                  _remote_page(30, 256))
     assert len(cache.learned) == 2 and len(cache.jitted) == 2
 

@@ -86,15 +86,21 @@ def test_fused_q06_at_sf1_capacity(engine, one_chip, no_compile_cache,
                                    monkeypatch):
     """The whole q06 program at the SF1 scan capacity, from shapes alone:
     the 0.01 connector plans it, the executor is told SF1's row count."""
+    from presto_tpu.expr.params import lift_plan
     ex = engine.executor
     monkeypatch.setattr(ex, "_scan_rows", lambda node: SF1_LINEITEM_ROWS)
-    fn, scans, _watch = ex._lower(_plan(engine, QUERIES[6]), {})
+    # as the executor lowers it: the five literals are inputs (0-d)
+    lifted = lift_plan(_plan(engine, QUERIES[6]))
+    assert len(lifted.values) == 5
+    fn, scans, _watch = ex._lower(lifted.plan, {})
     cap = bucket_capacity(SF1_LINEITEM_ROWS)
     assert [s.capacity for s in scans] == [cap]
     small = engine.connector.table("lineitem").page(
         columns=list(scans[0].columns))
     compiled = jax.jit(fn).lower(
-        [_shapes(small, one_chip, capacity=cap)]).compile()
+        [_shapes(small, one_chip, capacity=cap)],
+        tuple(jax.ShapeDtypeStruct((), v.dtype, sharding=one_chip)
+              for v in lifted.values)).compile()
     mem = compiled.memory_analysis()
     # four 8-byte columns and their null masks at 8M rows
     assert mem.argument_size_in_bytes > 4 * 8 * cap
@@ -166,7 +172,7 @@ def test_dist_executor_counters_reduce_on_four_chips(mesh4,
     ex = DistExecutor.__new__(DistExecutor)
     ex.mesh, ex.ndev = mesh4, 4
 
-    def fn(pages):
+    def fn(pages, params=()):
         return pages[0], jnp.arange(6, dtype=jnp.int64) + pages[0][0]
 
     wrapped = ex._wrap(fn)

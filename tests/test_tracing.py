@@ -320,12 +320,15 @@ def test_upload_counts_only_what_moves(engine):
 def test_each_operator_is_traced_under_its_named_scope(engine):
     from presto_tpu.exec.executor import Executor
     ex = Executor(engine.connector)
-    ex.execute(engine.plan_sql(
-        "select count(*) from lineitem where l_quantity < 10"))
+    from presto_tpu.expr.params import lift_plan
+    plan = engine.plan_sql(
+        "select count(*) from lineitem where l_quantity < 10")
+    ex.execute(plan)
     program, = ex.programs.jitted.values()
     fn, scans, about = program.fn, program.scans, program.about
     assert about["program"] == "jit_" + fn.__name__
-    text = fn.lower([ex._fetch(s) for s in scans]).as_text(debug_info=True)
+    text = fn.lower([ex._fetch(s) for s in scans],
+                    lift_plan(plan).values).as_text(debug_info=True)
     # (a scan or an output relabels pages and leaves no operation)
     assert re.search(r'loc\("[^"]*\bAggregation\b', text)
 
