@@ -188,31 +188,47 @@ class Column:
 
     # -- construction -----------------------------------------------------
     @staticmethod
+    def host_from_numpy(values: np.ndarray, type: Type,
+                        nulls: Optional[np.ndarray] = None,
+                        dictionary: Optional[StringDict] = None,
+                        capacity: Optional[int] = None) -> "Column":
+        """A column over numpy arrays: `from_numpy`'s padding and
+        sentinels, and nothing on the device. The form of a page that
+        only crosses the exchange (`select_page_host`, `decode_pages`);
+        a jitted island takes none (`page_to_device`)."""
+        n = len(values)
+        cap = capacity if capacity is not None else bucket_capacity(n)
+        dt = type.dtype
+        vals = np.asarray(values, dtype=dt)
+        nl = (np.zeros(n, dtype=bool) if nulls is None
+              else np.asarray(nulls, dtype=bool))
+        return Column(
+            fuse_lanes([null_sentinels(vals, nl, type)], cap, dt,
+                       dt.type(type.null_sentinel())),
+            fuse_lanes([nl], cap, np.bool_, True), type, dictionary)
+
+    @staticmethod
     def from_numpy(values: np.ndarray, type: Type,
                    nulls: Optional[np.ndarray] = None,
                    dictionary: Optional[StringDict] = None,
                    capacity: Optional[int] = None) -> "Column":
-        n = len(values)
-        cap = capacity if capacity is not None else bucket_capacity(n)
-        dt = type.dtype
-        out = np.full(cap, type.null_sentinel(), dtype=dt)
-        out[:n] = np.asarray(values, dtype=dt)
-        nl = np.ones(cap, dtype=bool)
-        if nulls is None:
-            nl[:n] = False
-        else:
-            nl[:n] = np.asarray(nulls, dtype=bool)
-            out[:n] = np.where(nl[:n], dt.type(type.null_sentinel()), out[:n])
-        return Column(jnp.asarray(out), jnp.asarray(nl), type, dictionary)
+        c = Column.host_from_numpy(values, type, nulls, dictionary, capacity)
+        return Column(jnp.asarray(c.values), jnp.asarray(c.nulls), type,
+                      dictionary)
+
+    @staticmethod
+    def host_from_strings(strings: Sequence[Optional[str]],
+                          capacity: Optional[int] = None) -> "Column":
+        nulls = np.array([s is None for s in strings], dtype=bool)
+        filled = ["" if s is None else s for s in strings]
+        d, codes = StringDict.build(filled)
+        return Column.host_from_numpy(codes, VARCHAR, nulls=nulls,
+                                      dictionary=d, capacity=capacity)
 
     @staticmethod
     def from_strings(strings: Sequence[Optional[str]],
                      capacity: Optional[int] = None) -> "Column":
-        nulls = np.array([s is None for s in strings], dtype=bool)
-        filled = ["" if s is None else s for s in strings]
-        d, codes = StringDict.build(filled)
-        return Column.from_numpy(codes, VARCHAR, nulls=nulls, dictionary=d,
-                                 capacity=capacity)
+        return page_to_device(Column.host_from_strings(strings, capacity))
 
     # -- host access ------------------------------------------------------
     def to_numpy(self, num_rows: Optional[int] = None):
@@ -306,11 +322,11 @@ class Decimal128Column:
         return int128.from_int64(v)
 
     @staticmethod
-    def from_unscaled_ints(ints, type: Type, nulls=None,
-                           capacity: Optional[int] = None,
-                           ) -> "Decimal128Column":
+    def host_from_unscaled_ints(ints, type: Type, nulls=None,
+                                capacity: Optional[int] = None,
+                                ) -> "Decimal128Column":
         """Host build from python-int unscaled values (exact for the
-        full 38-digit range)."""
+        full 38-digit range): numpy lanes, nothing on the device."""
         n = len(ints)
         cap = capacity if capacity is not None else bucket_capacity(n)
         lanes = [np.zeros(cap, np.int64) for _ in range(4)]
@@ -324,10 +340,15 @@ class Decimal128Column:
             lanes[1][i] = (v >> 64) & 0xFFFFFFFF
             lanes[2][i] = (v >> 32) & 0xFFFFFFFF
             lanes[3][i] = v & 0xFFFFFFFF
-        return Decimal128Column(
-            jnp.asarray(lanes[0]), jnp.asarray(lanes[1]),
-            jnp.asarray(lanes[2]), jnp.asarray(lanes[3]),
-            jnp.asarray(nl), type)
+        return Decimal128Column(lanes[0], lanes[1], lanes[2], lanes[3],
+                                nl, type)
+
+    @staticmethod
+    def from_unscaled_ints(ints, type: Type, nulls=None,
+                           capacity: Optional[int] = None,
+                           ) -> "Decimal128Column":
+        return page_to_device(Decimal128Column.host_from_unscaled_ints(
+            ints, type, nulls, capacity))
 
     # -- generic row-lane protocol (compact/sort payload) -----------------
     def row_lanes(self):
@@ -481,6 +502,13 @@ class NestedColumn:
                     capacity: Optional[int] = None) -> "NestedColumn":
         """Build from python values: lists (array), dicts (map), tuples
         (row), or None."""
+        return page_to_device(
+            NestedColumn.host_from_pylist(vals, type, capacity))
+
+    @staticmethod
+    def host_from_pylist(vals, type: Type,
+                         capacity: Optional[int] = None) -> "NestedColumn":
+        """`from_pylist` over numpy arrays, children included."""
         n = len(vals)
         cap = capacity if capacity is not None else bucket_capacity(n)
         nulls = np.array([v is None for v in vals] + [True] * (cap - n),
@@ -491,9 +519,8 @@ class NestedColumn:
                 fvals = [None if v is None else v[i] for v in vals]
                 fields.append(_column_from_pylist(fvals, ft, cap))
             ident = np.arange(cap, dtype=np.int32)
-            return NestedColumn(jnp.asarray(ident),
-                                jnp.asarray(np.ones(cap, np.int32)),
-                                jnp.asarray(nulls), tuple(fields), type)
+            return NestedColumn(ident, np.ones(cap, np.int32), nulls,
+                                tuple(fields), type)
         lengths = np.zeros(cap, np.int32)
         flat_items: list = []
         starts = np.zeros(cap, np.int32)
@@ -514,8 +541,7 @@ class NestedColumn:
         else:
             children = (_column_from_pylist(
                 flat_items, type.element, ecap),)
-        return NestedColumn(jnp.asarray(starts), jnp.asarray(lengths),
-                            jnp.asarray(nulls), children, type)
+        return NestedColumn(starts, lengths, nulls, children, type)
 
     def value_at(self, i: int):
         """Python value of row i (host; to_pylist support)."""
@@ -534,11 +560,11 @@ class NestedColumn:
 
 
 def _column_from_pylist(vals, t: Type, capacity: int):
-    """list of python values -> Column/NestedColumn of type t."""
+    """list of python values -> host Column/NestedColumn of type t."""
     if isinstance(t, Type) and t.name in ("array", "map", "row"):
-        return NestedColumn.from_pylist(vals, t, capacity)
+        return NestedColumn.host_from_pylist(vals, t, capacity)
     if t.is_string:
-        return Column.from_strings(vals, capacity=capacity)
+        return Column.host_from_strings(vals, capacity=capacity)
     nulls = np.array([v is None for v in vals], dtype=bool)
     if t.is_decimal:
         # exact unscaling: Decimal values never round-trip through
@@ -547,7 +573,7 @@ def _column_from_pylist(vals, t: Type, capacity: int):
                            for v in vals], dtype=np.int64)
     else:
         filled = np.array([0 if v is None else v for v in vals])
-    return Column.from_numpy(filled, t, nulls=nulls, capacity=capacity)
+    return Column.host_from_numpy(filled, t, nulls=nulls, capacity=capacity)
 
 
 def _pyvalue(col, i: int):
@@ -606,6 +632,13 @@ class Page:
                     tuple(names))
 
     @staticmethod
+    def host_from_columns(columns: Sequence[Column], num_rows,
+                          names: Sequence[str] = ()) -> "Page":
+        """A page whose row count stays on the host too (`np.int32`):
+        with host columns, a page no part of which is on the device."""
+        return Page(tuple(columns), np.int32(num_rows), tuple(names))
+
+    @staticmethod
     def from_pydict(data: dict, types: dict, capacity: Optional[int] = None
                     ) -> "Page":
         """Build a Page from {name: list-of-python-values} (tests/tools)."""
@@ -617,7 +650,7 @@ class Page:
             cap = capacity if capacity is not None else bucket_capacity(n)
             cols.append(_column_from_pylist(list(vals), t, cap))
             names.append(name)
-        return Page.from_columns(cols, n, names)
+        return page_to_device(Page.host_from_columns(cols, n, names))
 
     # -- host access ------------------------------------------------------
     def to_pylist(self) -> List[tuple]:
@@ -734,34 +767,68 @@ def compact_string_dict(dictionary: StringDict, codes: np.ndarray,
     return (intern_string_dict(out) if interned else out), codes
 
 
+def page_to_device(tree):
+    """A page (or a column) with every numpy leaf put on the device, in
+    one `jax.device_put` of the whole tree; a leaf that is there already
+    stays. The one way a host page becomes an island's input."""
+    return jax.device_put(tree)
+
+
+def device_leaves(tree) -> int:
+    """How many arrays of a page (or of pages) live on the device. A
+    page's form is the type of its arrays: 0 says a host page."""
+    return sum(isinstance(a, jax.Array)
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def null_sentinels(vals: np.ndarray, nulls: np.ndarray, t: Type
+                   ) -> np.ndarray:
+    """`vals` with the type's sort sentinel in every null slot (what a
+    Column holds there): `vals` itself where no slot is null."""
+    if not nulls.any():
+        return vals
+    return np.where(nulls, t.dtype.type(t.null_sentinel()), vals)
+
+
+def fuse_lanes(parts: Sequence[np.ndarray], cap: int, dtype, fill
+               ) -> np.ndarray:
+    """The parts end to end in one new array of `cap` slots, `fill`
+    after the last: each part is copied once, into its place."""
+    out = np.empty(cap, dtype=dtype)
+    at = 0
+    for a in parts:
+        out[at:at + len(a)] = a
+        at += len(a)
+    out[at:] = fill
+    return out
+
+
 def concat_pages_host(pages: Sequence[Page],
                       capacity: Optional[int] = None) -> Page:
-    """Concatenate pages row-wise on the host (numpy), merging per-column
-    string dictionaries. Used by the worker to fuse pulled exchange streams
-    into one scan-like input page (the consumer side of
-    ExchangeClient.java:255, materialized batch-wise for the jit engine)."""
+    """Concatenate pages row-wise in numpy, merging per-column string
+    dictionaries, and put the fused page on the device: the one upload
+    of an exchange's consumer. Used by the worker to fuse pulled exchange
+    streams into one scan-like input page (the consumer side of
+    ExchangeClient.java:255, materialized batch-wise for the jit engine).
+    It reads every page through `np.asarray`: a host page (the
+    exchange's `decode_pages`) costs nothing to read, a device page
+    (`DistSplitExecutor`, the mesh tier, a test) is fetched first. The
+    result is a device page whatever came in."""
     assert pages, "concat of zero pages"
     first = pages[0]
-    total = sum(int(p.num_rows) for p in pages)
+    rows = [int(p.num_rows) for p in pages]
+    total = sum(rows)
     cap = capacity if capacity is not None else bucket_capacity(max(total, 1))
     cols: List[Column] = []
     for ci, c0 in enumerate(first.columns):
-        vals_parts, null_parts = [], []
         if isinstance(c0, Decimal128Column):
-            lanes_parts = [[] for _ in c0.row_lanes()]
-            for p in pages:
-                c = p.columns[ci]
-                n_p = int(p.num_rows)
-                for li, lane in enumerate(c.row_lanes()):
-                    lanes_parts[li].append(np.asarray(lane)[:n_p])
             lanes = []
-            for li, parts in enumerate(lanes_parts):
-                a = np.concatenate(parts) if parts else \
-                    np.zeros(0, np.int64)
-                pad = cap - len(a)
-                fill = True if li == 2 else 0
-                lanes.append(jnp.asarray(
-                    np.pad(a, (0, pad), constant_values=fill)))
+            for li in range(len(c0.row_lanes())):
+                parts = [np.asarray(p.columns[ci].row_lanes()[li])[:n_p]
+                         for p, n_p in zip(pages, rows)]
+                # row_lanes: l3..l0, nulls, then the count of an average
+                lanes.append(fuse_lanes(parts, cap, parts[0].dtype,
+                                        True if li == 4 else 0))
             cols.append(c0.from_lanes(lanes))
             continue
         if isinstance(c0, NestedColumn):
@@ -769,16 +836,15 @@ def concat_pages_host(pages: Sequence[Page],
             # volumes of nested data are modest until nested compute
             # exists; correctness first)
             pyvals: List = []
-            for p in pages:
+            for p, n_p in zip(pages, rows):
                 col = p.columns[ci]
-                pyvals.extend(col.value_at(i)
-                              for i in range(int(p.num_rows)))
-            cols.append(NestedColumn.from_pylist(pyvals, c0.type, cap))
+                pyvals.extend(col.value_at(i) for i in range(n_p))
+            cols.append(NestedColumn.host_from_pylist(pyvals, c0.type, cap))
             continue
+        parts = [(p.columns[ci].dictionary, *p.columns[ci].to_numpy(n_p))
+                 for p, n_p in zip(pages, rows)]
+        union = None
         if c0.type.is_string:
-            parts = [(p.columns[ci].dictionary,
-                      *p.columns[ci].to_numpy(int(p.num_rows)))
-                     for p in pages]
             d0 = parts[0][0]
             if any(d is not d0 for d, _v, _nl in parts):
                 # a dictionary from the wire holds words its page does
@@ -790,30 +856,23 @@ def concat_pages_host(pages: Sequence[Page],
                     if d is not None and d.sparse else (d, v, nl)
                     for d, v, nl in parts]
             union, remaps = merge_string_dicts([d for d, _v, _nl in parts])
-            for (d, v, nl), remap in zip(parts, remaps):
-                if d is not union and len(remap):
-                    v = remap[np.clip(v, 0, len(remap) - 1)]
-                vals_parts.append(v)
-                null_parts.append(nl)
-            vals = (np.concatenate(vals_parts) if vals_parts else
-                    np.zeros(0, np.int32))
-            nulls = np.concatenate(null_parts)
-            if union.sparse:
-                # every page brought the one wire dictionary: compact
-                # once, for the fused page
-                union, vals = compact_string_dict(union, vals, nulls)
-            cols.append(Column.from_numpy(
-                vals, c0.type, nulls=nulls, dictionary=union,
-                capacity=cap))
-        else:
-            for p in pages:
-                v, nl = p.columns[ci].to_numpy(int(p.num_rows))
-                vals_parts.append(v)
-                null_parts.append(nl)
-            cols.append(Column.from_numpy(
-                np.concatenate(vals_parts), c0.type,
-                nulls=np.concatenate(null_parts), capacity=cap))
-    return Page.from_columns(cols, total, first.names)
+            parts = [
+                (d, remap[np.clip(v, 0, len(remap) - 1)]
+                 if d is not union and len(remap) else v, nl)
+                for (d, v, nl), remap in zip(parts, remaps)]
+        dt = c0.type.dtype
+        vals = fuse_lanes([v for _d, v, _nl in parts], cap, dt,
+                          dt.type(c0.type.null_sentinel()))
+        nulls = fuse_lanes([nl for _d, _v, nl in parts], cap, np.bool_,
+                           True)
+        live, live_nulls = vals[:total], nulls[:total]
+        if union is not None and union.sparse:
+            # every page brought the one wire dictionary: compact
+            # once, for the fused page
+            union, live[:] = compact_string_dict(union, live, live_nulls)
+        live[:] = null_sentinels(live, live_nulls, c0.type)
+        cols.append(Column(vals, nulls, c0.type, union))
+    return page_to_device(Page.host_from_columns(cols, total, first.names))
 
 
 def page_nbytes(page: Page) -> int:
@@ -825,9 +884,12 @@ def page_nbytes(page: Page) -> int:
 
 def page_to_host(page: Page) -> None:
     """Fetch every array of a device page. jax keeps the host copy with
-    the array, so the conversions that follow (`to_numpy`, the wire
-    blocks) find it there: the device->host step of an output page is
-    paid here, once, apart from partitioning and serialization."""
+    the array, so the `np.asarray` of each leaf that follows is free:
+    the device->host step of an output page is paid here, once, apart
+    from partitioning and serialization. Who relies on that: the
+    partitioner (`_hash_partition_ids`, `select_page_host`) and the
+    single-buffer `_serialize(page)`, which all read the device page's
+    own arrays; a partition is a host page from then on."""
     for a in jax.tree_util.tree_leaves(page):
         np.asarray(a)
 
@@ -835,38 +897,29 @@ def page_to_host(page: Page) -> None:
 def select_page_host(page: Page, idx: np.ndarray) -> Page:
     """Host-side row selection (numpy take) keeping dictionaries — the
     producer side of partitioned output (PartitionedOutputOperator.java:57
-    splitting rows into per-destination pages)."""
-    n = len(idx)
-    cap = bucket_capacity(max(n, 1))
+    splitting rows into per-destination pages). The result is a host
+    page of exactly the selected rows, with no padding: it exists to
+    become wire blocks (`page_to_wire_blocks` reads `[:num_rows]`), and
+    nothing of it touches the device."""
     cols = []
     for c in page.columns:
         if isinstance(c, Decimal128Column):
-            pad = cap - n
-            lanes = []
-            for li, lane in enumerate(c.row_lanes()):
-                a = np.asarray(lane)[idx]
-                fill = True if li == 4 else 0   # row_lanes: l3..l0, nulls
-                lanes.append(jnp.asarray(
-                    np.pad(a, (0, pad), constant_values=fill)))
-            cols.append(c.from_lanes(lanes))
+            cols.append(c.from_lanes(
+                [np.asarray(lane)[idx] for lane in c.row_lanes()]))
             continue
         if isinstance(c, NestedColumn):
-            starts = np.asarray(c.starts)[idx]
-            lengths = np.asarray(c.lengths)[idx]
-            nulls = np.asarray(c.nulls)[idx]
-            pad = cap - n
+            # starts are absolute child positions: the children stay
+            # whole, as the host copies `page_to_host` left with them
             cols.append(NestedColumn(
-                jnp.asarray(np.pad(starts, (0, pad))),
-                jnp.asarray(np.pad(lengths, (0, pad))),
-                jnp.asarray(np.pad(nulls, (0, pad),
-                                   constant_values=True)),
-                c.children, c.type))
+                np.asarray(c.starts)[idx], np.asarray(c.lengths)[idx],
+                np.asarray(c.nulls)[idx],
+                jax.tree_util.tree_map(np.asarray, c.children), c.type))
             continue
-        v, nl = c.to_numpy(int(page.num_rows))
-        cols.append(Column.from_numpy(v[idx], c.type, nulls=nl[idx],
-                                      dictionary=c.dictionary,
-                                      capacity=cap))
-    return Page.from_columns(cols, n, page.names)
+        v, nl = c.to_numpy()
+        v, nl = v[idx], nl[idx]
+        cols.append(Column(null_sentinels(v, nl, c.type), nl, c.type,
+                           c.dictionary))
+    return Page.host_from_columns(cols, len(idx), page.names)
 
 
 # ---------------------------------------------------------------------------

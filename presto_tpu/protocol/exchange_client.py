@@ -25,6 +25,7 @@ from presto_tpu.protocol.transport import (
     HttpClient, RetriesExhaustedError, TransportError,
     WorkerRestartedError, get_client,
 )
+from presto_tpu.utils.tracing import TRACER
 
 _M_FETCHES = _counter("presto_tpu_exchange_fetches_total",
                       "Exchange fetch rounds (one sequenced GET each)")
@@ -248,13 +249,17 @@ class PageStream:
 
 
 def decode_pages(data: bytes, types) -> List:
-    """Concatenated wire frames -> engine Pages of an exchange. A string
-    column keeps its dictionary as it crossed the wire (`sparse`, one
-    object for all the pages that name it): a consumer fuses what it
-    pulls (`concat_pages_host`, which compacts once for the fused
-    page), and the root reads rows by code."""
+    """Concatenated wire frames -> engine Pages of an exchange: host
+    pages, over numpy arrays, their row counts too. A string column
+    keeps its dictionary as it crossed the wire (`sparse`, one object
+    for all the pages that name it). A consumer fuses what it pulls
+    (`concat_pages_host`, which compacts once and puts the fused page
+    on the device: no program takes a page from here as it is), and the
+    root reads rows by code. The `deserialize` span around the call
+    gets `device_fetches`: the arrays a consumer would have to fetch
+    back (none)."""
     from presto_tpu.protocol.serde import (
-        decode_serialized_page, wire_blocks_to_page,
+        decode_serialized_page, note_exchange_pages, wire_blocks_to_page,
     )
 
     pages = []
@@ -262,5 +267,7 @@ def decode_pages(data: bytes, types) -> List:
     while off < len(data):
         blocks, n, off = decode_serialized_page(data, off)
         pages.append(wire_blocks_to_page(blocks, types, n,
-                                         compact_strings=False))
+                                         compact_strings=False, host=True))
+    TRACER.add("deserialize",
+               device_fetches=note_exchange_pages("decode", pages))
     return pages

@@ -94,6 +94,13 @@ _DICTIONARY = _counter(
     "dictionary's wire form (encode) or its decoded words (decode) "
     "kept from an earlier page, a miss built them",
     labelnames=("side", "result"))
+_EXCHANGE_PAGE = _counter(
+    "presto_tpu_exchange_page_total",
+    "Pages of the exchange by where their arrays live: a partition as "
+    "the producer built it, a page as the consumer decoded it, a page "
+    "as it went into the consumer's fuse. A host page crosses the "
+    "exchange without touching the device",
+    labelnames=("side", "form"))
 _ENCODE_SECONDS = _histogram(
     "presto_tpu_serde_encode_seconds", "Wall time per encode_serialized_page call")
 _DECODE_SECONDS = _histogram(
@@ -852,12 +859,28 @@ def page_to_wire_blocks(page) -> List[WireBlock]:
     return out
 
 
+def _host_column(t, vals: np.ndarray, nulls: np.ndarray, capacity: int,
+                 dictionary=None):
+    """Decoded values (of `t`'s dtype) and null flags as a host Column
+    under `Column.host_from_numpy`'s rules. Where they fill `capacity`
+    (a page of the exchange is decoded at its rows) the arrays are the
+    column, views of the frame included; else copies padded to it."""
+    from presto_tpu.data.column import Column, fuse_lanes, null_sentinels
+
+    vals = null_sentinels(vals, nulls, t)
+    if len(vals) != capacity:
+        vals = fuse_lanes([vals], capacity, vals.dtype,
+                          t.dtype.type(t.null_sentinel()))
+        nulls = fuse_lanes([nulls], capacity, np.bool_, True)
+    return Column(vals, nulls, t, dictionary)
+
+
 def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int,
                     compact_strings: bool = True):
-    """One wire block -> engine Column/NestedColumn of type t."""
-    from presto_tpu.data.column import Column, NestedColumn, \
+    """One wire block -> engine Column/NestedColumn of type t, over
+    numpy arrays: nothing here touches the device."""
+    from presto_tpu.data.column import NestedColumn, \
         bucket_capacity, compact_string_dict
-    import jax.numpy as jnp
 
     b = _materialize_rle(b)
     if b.encoding in ("ARRAY", "MAP", "ROW"):
@@ -879,10 +902,8 @@ def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int,
             for cb, ct in zip(b.children, child_types))
         pad = capacity - n
         return NestedColumn(
-            jnp.asarray(np.pad(starts, (0, pad))),
-            jnp.asarray(np.pad(lengths, (0, pad))),
-            jnp.asarray(np.pad(nulls[:n], (0, pad),
-                               constant_values=True)),
+            np.pad(starts, (0, pad)), np.pad(lengths, (0, pad)),
+            np.pad(nulls[:n], (0, pad), constant_values=True),
             children, t)
     if b.encoding == "INT128_ARRAY" and getattr(t, "uses_int128", False):
         from presto_tpu.data.column import Decimal128Column
@@ -896,16 +917,14 @@ def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int,
                 continue
             low = int(b.values[i, 0]) & ((1 << 64) - 1)
             ints.append((int(b.values[i, 1]) << 64) | low)
-        return Decimal128Column.from_unscaled_ints(
+        return Decimal128Column.host_from_unscaled_ints(
             ints, t, capacity=capacity)
     if t.is_string:
         dictionary, codes, nulls = _block_to_strings(b)
         if compact_strings:
             dictionary, codes = compact_string_dict(dictionary, codes,
                                                     nulls)
-        return Column.from_numpy(codes, t, nulls=nulls,
-                                 dictionary=dictionary,
-                                 capacity=capacity)
+        return _host_column(t, codes, nulls, capacity, dictionary)
     vals = b.values
     nulls = b.nulls if b.nulls is not None else \
         np.zeros(position_count, dtype=bool)
@@ -916,28 +935,35 @@ def _wire_to_column(b: WireBlock, t, position_count: int, capacity: int,
     elif t.dtype == np.bool_:
         vals = vals.astype(bool)
     else:
-        vals = vals.astype(t.dtype)
-    vals = np.where(nulls, t.dtype.type(t.null_sentinel()), vals) \
-        if nulls.any() else vals
-    return Column.from_numpy(vals, t, nulls=nulls, capacity=capacity)
+        vals = vals.astype(t.dtype, copy=False)
+    return _host_column(t, vals, nulls, capacity)
 
 
 def wire_blocks_to_page(blocks: List[WireBlock], types, position_count: int,
                         capacity: Optional[int] = None,
-                        compact_strings: bool = True):
-    """Wire blocks -> engine Page. `types` are presto_tpu SQL types. A
-    string column comes with a sorted dictionary of exactly the words
-    its rows use. Only a caller whose pages all go through
-    `concat_pages_host` next (the exchange) passes `compact_strings`
-    False: the columns then keep the dictionary as it crossed the wire,
-    marked `sparse`, one object for every page that names it, and the
-    fuse compacts once."""
-    from presto_tpu.data.column import Page, bucket_capacity
+                        compact_strings: bool = True, host: bool = False):
+    """Wire blocks -> engine Page, on the device: what a program takes
+    (`exec/spill` reads a spilled page straight back into one). `types`
+    are presto_tpu SQL types. A string column comes with a sorted
+    dictionary of exactly the words its rows use.
 
-    cap = capacity or bucket_capacity(max(position_count, 1))
+    The exchange's decoder (`exchange_client.decode_pages`) passes both
+    flags the other way, because its pages go no further than a fuse or
+    the root's row reader. `host`: the page stays over numpy arrays,
+    its row count too, at exactly its rows (no padding to a bucket: a
+    fixed-width column without NULLs is a view of the frame), and the
+    fuse (`concat_pages_host`) puts one fused page on the device.
+    `compact_strings` False: a string column keeps the dictionary as it
+    crossed the wire, marked `sparse`, one object for every page that
+    names it, and the fuse compacts once."""
+    from presto_tpu.data.column import Page, bucket_capacity, page_to_device
+
+    cap = capacity or (position_count if host
+                       else bucket_capacity(max(position_count, 1)))
     cols = [_wire_to_column(b, t, position_count, cap, compact_strings)
             for b, t in zip(blocks, types)]
-    return Page.from_columns(cols, position_count)
+    page = Page.host_from_columns(cols, position_count)
+    return page if host else page_to_device(page)
 
 
 def _materialize_rle(b: WireBlock) -> WireBlock:
@@ -979,6 +1005,21 @@ def _note_dictionary(side: str, hit: bool) -> None:
         TRACER.add(span, dict_hits=1)
     else:
         TRACER.add(span, dict_misses=1)
+
+
+def note_exchange_pages(side: str, pages) -> int:
+    """Count pages of the exchange (`side`: partition, decode, fuse)
+    under the form their arrays have, and return how many of the arrays
+    live on the device: what the span around the work reports as
+    arrays that crossed between host and device."""
+    from presto_tpu.data.column import device_leaves
+
+    crossed = 0
+    for p in pages:
+        k = device_leaves(p)
+        _EXCHANGE_PAGE.inc(side=side, form="device" if k else "host")
+        crossed += k
+    return crossed
 
 
 def _decode_words(d: WireBlock):

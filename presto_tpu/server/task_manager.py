@@ -21,7 +21,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from presto_tpu.data.column import (
-    Page, concat_pages_host, page_nbytes, page_to_host,
+    Page, concat_pages_host, device_leaves, page_nbytes, page_to_host,
     select_page_host,
 )
 from presto_tpu.exec.split_executor import SplitExecutor
@@ -31,7 +31,7 @@ from presto_tpu.obs.metrics import (
 from presto_tpu.plan.nodes import RemoteSourceNode
 from presto_tpu.protocol import structs as S
 from presto_tpu.protocol.serde import (
-    encode_serialized_page, page_to_wire_blocks,
+    encode_serialized_page, note_exchange_pages, page_to_wire_blocks,
 )
 from presto_tpu.server.buffers import OutputBufferManager
 from presto_tpu.utils.threads import spawn
@@ -119,11 +119,18 @@ def _fragment_has_remote_sources(frag: S.PlanFragment) -> bool:
 
 
 def _concat_upload(pages: List[Page], source: str) -> Page:
-    """Pulled pages fused row-wise on the host and put on the device as
-    one input page: the host->device step of an exchange."""
+    """Pulled pages fused row-wise in numpy and put on the device as one
+    input page: the host->device step of an exchange, and the only one
+    (the pulled pages are host pages). `bytes` is the fused page at its
+    capacity; `device_puts` its arrays, `device_fetches` the arrays of
+    the pulled pages that had to come back from the device first (none
+    from `decode_pages`)."""
     with TRACER.span(None, "upload", source=source) as sp:
+        fetches = note_exchange_pages("fuse", pages)
         page = concat_pages_host(pages)
-        sp.attributes["bytes"] = page_nbytes(page)
+        sp.attributes.update(bytes=page_nbytes(page),
+                             device_fetches=fetches,
+                             device_puts=device_leaves(page))
     return page
 
 
@@ -1238,7 +1245,7 @@ class TpuTaskManager:
         with TRACER.span(None, "download",
                          bytes=page_nbytes(page)):
             page_to_host(page)
-        with TRACER.span(None, "serialize",
+        with TRACER.span(None, "serialize", device_puts=0,
                          buffers=len(task.buffers.buffers)) as sp:
             before = task.bytes_out
             self._route_output(task, page)
@@ -1246,7 +1253,9 @@ class TpuTaskManager:
 
     def _route_output(self, task: Task, page: Page) -> None:
         """Partition, serialize, compress and buffer one output page
-        (its arrays already on the host)."""
+        (its arrays already on the host). A partition is a host page:
+        the `serialize` span's `device_puts` counts the arrays of the
+        partitions that are on the device all the same (none)."""
         codec = (task.session_properties or {}).get(
             "exchange_compression_codec")
         if codec in (None, "", "none"):
@@ -1266,6 +1275,12 @@ class TpuTaskManager:
                 self.total_bytes_out += len(frame)
             task.buffers.add_page(buffer_id, frame)
 
+        def partition(idx: np.ndarray) -> bytes:
+            part = select_page_host(page, idx)
+            TRACER.add("serialize",
+                       device_puts=note_exchange_pages("partition", [part]))
+            return self._serialize(part, codec)
+
         if kind in ("FIXED_BROADCAST_DISTRIBUTION", "SINGLE") \
                 and nbuf > 1:
             # BROADCAST — and SINGLE gathers shared by several consumers:
@@ -1281,7 +1296,7 @@ class TpuTaskManager:
             n = int(page.num_rows)
             for b_idx, b in enumerate(buffer_ids):
                 idx = np.arange(b_idx, n, nbuf)
-                emit(b, self._serialize(select_page_host(page, idx), codec))
+                emit(b, partition(idx))
             return
         if kind != "FIXED_HASH_DISTRIBUTION" and nbuf > 1:
             raise NotImplementedError(
@@ -1293,7 +1308,7 @@ class TpuTaskManager:
             pid = _hash_partition_ids(page, channels, nbuf)
             for b_idx, b in enumerate(buffer_ids):
                 idx = np.nonzero(pid == b_idx)[0]
-                emit(b, self._serialize(select_page_host(page, idx), codec))
+                emit(b, partition(idx))
             return
         # SINGLE (and the 1-buffer degenerate of every other scheme)
         emit(buffer_ids[0], self._serialize(page, codec))
