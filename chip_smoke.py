@@ -6,12 +6,14 @@ in-process workers and a `StatementServer`, then sends TPC-H q06, q01 and
 q03 through `run_statement` (client POST /v1/statement to last row) twice
 each: cold, then warm. Every result is compared, outside the timed part,
 with an oracle that never touches the engine: numpy over the generated
-arrays for q01/q06, a pandas merge for q03. Then q06 at each of clause
+arrays for q01/q06, a pandas merge for q03. Then q01 at both ends of
+clause 2.4.1.3's DELTA (60, 120; the two statements before them ran the
+validation parameter, 90) and q06 at each of clause
 2.4.6.3's eight DISCOUNT values (the year and the quantity cutoff moving
 with it), against the benchmark's plain reference, which decides the band
 in whole hundredths: a decimal literal descaled on the device answered
 five of the eight 37-50% short (PERF.md), and since the literals are
-program inputs the eight statements compile nothing.
+program inputs neither sweep compiles anything.
 
 It needs a TPU: with none it exits non-zero and prints no result
 (`--allow-cpu` exists only for the CPU rehearsal in the tests, `--sf` only
@@ -116,31 +118,40 @@ Q06_SWEEP = [{"DATE": f"{1993 + i % 5}-01-01", "DISCOUNT": f"0.0{d}",
               "QUANTITY": 24 + i % 2} for i, d in enumerate(range(2, 10))]
 
 
-def q06_sweep(conn, base: str, counter) -> dict:
-    """q06 at every DISCOUNT of its clause through the statement server,
-    held to the benchmark's reference at the benchmark's limits."""
+#: clause 2.4.1.3's DELTA at both ends (QUERIES[1] holds the 90 between)
+Q01_SWEEP = [{"DELTA": 60}, {"DELTA": 120}]
+
+
+def sweep(conn, base: str, counter, template: str, draws: list,
+          param: str) -> dict:
+    """One template at each of `draws` through the statement server, held
+    to the benchmark's reference at the benchmark's limits; the
+    compilations are those after the template's first statements, which
+    `run_queries` sent before."""
     import compare                  # benchmarks/ is on sys.path (main)
     import qgen
     import run as bench_run
     from presto_tpu.server.statement import run_statement
 
-    query = qgen.load_query("q06")
+    query = qgen.load_query(template)
     reference = compare.load_reference(query)
     tables = bench_run.Tables(conn)
     before = counter.compiled
     records, wanted = [], []
-    for params in Q06_SWEEP:
+    for params in draws:
         _cols, rows = run_statement(base, query["sql"].format(**params))
-        records.append({"template": "q06", "rows": [list(r) for r in rows]})
+        records.append({"template": template,
+                        "rows": [list(r) for r in rows]})
         wanted.append(reference(tables, params))
-    verdict = compare.judge(records, wanted, {"q06": query["limits"]})
-    return {"query": "q06_sweep",
-            "discounts": [p["DISCOUNT"] for p in Q06_SWEEP],
+    verdict = compare.judge(records, wanted, {template: query["limits"]})
+    compared = verdict["compared"]
+    return {"query": template + "_sweep",
+            param.lower() + "s": [p[param] for p in draws],
             "exact": verdict["correct"],
-            "not_exact_at": [Q06_SWEEP[i]["DISCOUNT"]
+            "not_exact_at": [draws[i][param]
                              for i in verdict["wrong_statements"]],
-            "max_rel_err": verdict["compared"]["q06.max_rel_err"]["value"],
-            "wrong_cells": verdict["compared"]["q06.wrong_cells"]["value"],
+            "max_rel_err": compared[template + ".max_rel_err"]["value"],
+            "wrong_cells": compared[template + ".wrong_cells"]["value"],
             "compilations": counter.compiled - before}
 
 
@@ -208,7 +219,10 @@ def run_queries(sf: float, counter) -> bool:
                                                    - before[1])
                     entry[f"{phase}_rows"] = rows
                 served[qid] = entry
-            sweep = q06_sweep(conn, srv.base, counter)
+            sweeps = [sweep(conn, srv.base, counter, "q01", Q01_SWEEP,
+                            "DELTA"),
+                      sweep(conn, srv.base, counter, "q06", Q06_SWEEP,
+                            "DISCOUNT")]
         finally:
             srv.stop()
     finally:
@@ -231,8 +245,9 @@ def run_queries(sf: float, counter) -> bool:
         if diff:
             line["mismatch"] = diff[:300]
         _say(**line)
-    all_exact &= sweep["exact"]
-    _say(sf=sf, **sweep)
+    for line in sweeps:
+        all_exact &= line["exact"]
+        _say(sf=sf, **line)
 
     stats = jax.devices()[0].memory_stats() or {}
     _say(generation_s=generation_s, table_rows=table_rows,

@@ -56,7 +56,19 @@ if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         "jax_compilation_cache_dir",
         _os.path.join(_os.path.dirname(_os.path.dirname(
             _os.path.abspath(__file__))), ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+# What is kept: every program whose compile took this long. Whether the
+# next process finds a program must follow from what the program is, not
+# from how long one compile of it happened to take, so the line lies in
+# a gap of the compile times read on a v5e (PERF.md section 6, PR 35):
+# below it the eager one-op programs of the host path (jit_add,
+# jit_convert_element_type, ...) and the one-row finals of Q6 and Q3,
+# 0.04-0.59 s, which every process compiles anew (the same section has
+# the reading at 0.0); above it every fused island, from
+# Q6's scan-filter-sum at 2.1 s over Q1's 3.2-8.7 s to Q3's and Q18's
+# joins and aggregations at 17-150 s.
+PERSISTENT_CACHE_MIN_COMPILE_SECS = 1.0
+jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                  PERSISTENT_CACHE_MIN_COMPILE_SECS)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 from presto_tpu.types import (  # noqa: E402

@@ -403,8 +403,15 @@ def _gen_orders_lineitem(which: str, sf: float) -> HostTable:
     ck = rng.integers(1, c["customer"] + 1, size=n).astype(np.int64)
     ck = np.where(ck % 3 == 0, (ck % (c["customer"] - 1)) + 1, ck)
     ck = np.where(ck % 3 == 0, ck + 1, ck)
-    odate = rng.integers(_MIN_DATE, _MAX_ORDER_DATE - 121, size=n
-                         ).astype(np.int32)
+    # Clause 4.2.3: O_ORDERDATE uniform in [STARTDATE, ENDDATE - 151 days],
+    # both ends drawn; the 1..121 days a line takes to ship come on top.
+    # The dates have a stream of their own, and `rng` still makes the draw
+    # that stopped 121 days short: a bounded draw rejects now and then, so
+    # another bound moves every later column and lineitem's row count.
+    odate = np.random.default_rng(_seed("orders.o_orderdate", sf, part)
+                                  ).integers(_MIN_DATE, _MAX_ORDER_DATE + 1,
+                                             size=n).astype(np.int32)
+    rng.integers(_MIN_DATE, _MAX_ORDER_DATE - 121, size=n)
 
     nlines = rng.integers(1, 8, size=n)
     total_lines = int(nlines.sum())

@@ -174,9 +174,11 @@ class Executor:
         #: joins, duplicate build keys seen)
         self.last_join_paths: List[str] = []
         #: beside it, each JoinNode's type (INNER, LEFT, FULL, SEMI, ANTI)
-        #: and each AggregationNode's step (PARTIAL, FINAL, SINGLE)
+        #: and each AggregationNode's step (PARTIAL, FINAL, SINGLE), with
+        #: how many grouping keys and how many aggregate calls it has
         self.last_join_types: List[str] = []
         self.last_agg_steps: List[str] = []
+        self.last_agg_shapes: List[Tuple[int, int]] = []
         # Optional MemoryPool (exec/memory.py): static footprints
         # reserve against it at lower time (admission control BEFORE
         # execution — the TPU analog of MemoryPool.java's runtime
@@ -633,6 +635,9 @@ class Executor:
                 about["join_types"] = "+".join(self.last_join_types)
             if self.last_agg_steps:
                 about["agg_steps"] = "+".join(self.last_agg_steps)
+                keys, calls = zip(*self.last_agg_shapes)
+                about["group_keys"] = "+".join(map(str, keys))
+                about["aggregates"] = "+".join(map(str, calls))
             return Program(jax.jit(program), lowered_at, scans, watch,
                            stats_box, about)
 
@@ -1022,6 +1027,7 @@ class Executor:
                 caps[nid] = out_cap
                 watch.append(nid)
                 agg_steps.append(node.step.name)
+                agg_shapes.append((len(node.group_fields), len(node.aggs)))
 
                 def agg_fn(pages, node=node, out_cap=out_cap, steps=steps):
                     p = src(pages)
@@ -1359,6 +1365,7 @@ class Executor:
         join_paths: List[str] = []
         join_types: List[str] = []
         agg_steps: List[str] = []
+        agg_shapes: List[Tuple[int, int]] = []
         root, _cap = build(plan)
         # build and build_inner call each other and close over `self`: a
         # reference cycle that would keep the executor, and the pages it
@@ -1369,6 +1376,7 @@ class Executor:
         self.last_join_paths = join_paths
         self.last_join_types = join_types
         self.last_agg_steps = agg_steps
+        self.last_agg_shapes = agg_shapes
         self.last_stats_box = stats_box
         if self.memory_limit_bytes is not None \
                 and mem_bytes[0] > self.memory_limit_bytes:
