@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from presto_tpu.connectors.base import SplitSource
-from presto_tpu.connectors.tpch import HostTable, _slice_rows
+from presto_tpu.connectors.tpch import HostTable
 from presto_tpu.data.column import StringDict
 from presto_tpu.types import Type
 
@@ -83,14 +83,9 @@ class MemoryConnector(SplitSource):
             if self.fallback is not None:
                 return self.fallback.table(name, part, num_parts)
             raise KeyError(f"unknown table {name}")
-        if num_parts == 1:
-            return full
-        lo, hi = _slice_rows(full.num_rows, part, num_parts)
-        arrays = {c: a[lo:hi] for c, a in full.arrays.items()}
-        nulls = ({c: m[lo:hi] for c, m in full.nulls.items()}
-                 if full.nulls is not None else None)
-        return HostTable(name, hi - lo, arrays, full.types, full.dicts,
-                         nulls)
+        # the split view is kept on `full`, and every write replaces
+        # `self.tables[name]`: a new version starts without views
+        return full.split(part, num_parts)
 
     # ------------------------------------------------------------ writes
     def exists(self, name: str) -> bool:

@@ -24,7 +24,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from presto_tpu.connectors.tpch import HostTable, _slice_rows
+from presto_tpu.connectors.tpch import HostTable
 from presto_tpu.data.column import StringDict
 from presto_tpu.expr.compile import days_from_civil
 from presto_tpu.types import BIGINT, DATE, DOUBLE, INTEGER, VARCHAR, Type
@@ -1034,12 +1034,4 @@ class TpcdsConnector(SplitSource):
               ) -> HostTable:
         if name not in TPCDS_SCHEMA:
             raise KeyError(f"unknown tpcds table {name}")
-        full = _gen(name, self.scale_factor)
-        if num_parts == 1:
-            return full
-        lo, hi = _slice_rows(full.num_rows, part, num_parts)
-        arrays = {c: a[lo:hi] for c, a in full.arrays.items()}
-        nulls = ({c: m[lo:hi] for c, m in full.nulls.items()}
-                 if full.nulls is not None else None)
-        return HostTable(name, hi - lo, arrays, full.types, full.dicts,
-                         nulls)
+        return _gen(name, self.scale_factor).split(part, num_parts)

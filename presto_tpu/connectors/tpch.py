@@ -29,6 +29,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from presto_tpu.connectors.scan_cache import SCAN_CACHE, KeptColumns
 from presto_tpu.data.column import (Column, Page, StringDict,
                                     bucket_capacity, page_nbytes)
 from presto_tpu.expr.compile import days_from_civil
@@ -188,6 +189,10 @@ class HostTable:
     dicts: Dict[str, StringDict]
     nulls: Optional[Dict[str, np.ndarray]] = None
 
+    #: whether `page` keeps the device columns it builds (no dataclass
+    #: field: a `row_slice` window turns it off on itself)
+    keeps_device_columns = True
+
     def column_names(self) -> List[str]:
         return list(self.types)      # schema insertion order
 
@@ -199,63 +204,104 @@ class HostTable:
 
     def row_slice(self, lo: int, hi: int) -> "HostTable":
         """A [lo, hi) row window as a VIEW table: numpy slices share the
-        parent's buffers and StringDicts — no copy, and no entry in the
-        parent's device-page cache (run tables are throwaway by design;
-        streaming scans upload each run once). Column access goes
-        through `arrays[c]` so lazy tables (parquet) load on demand."""
+        parent's buffers and StringDicts — no copy. The window is
+        throwaway by design (streaming scan runs exist because the split
+        does not fit: each run is put up once), so its `page` keeps no
+        device column; `split` is the window that is kept. Column access
+        goes through `arrays[c]` so lazy tables (parquet) load on
+        demand."""
         arrays = {c: self.arrays[c][lo:hi] for c in self.column_names()}
         nulls = None
         if self.nulls is not None:
             nulls = {c: m[lo:hi] for c, m in
                      ((c, self.null_mask(c)) for c in self.column_names())
                      if m is not None}
-        return HostTable(self.name, hi - lo, arrays, self.types,
+        view = HostTable(self.name, hi - lo, arrays, self.types,
                          self.dicts, nulls)
+        view.keeps_device_columns = False
+        return view
+
+    def split(self, part: int, num_parts: int) -> "HostTable":
+        """Row range `part` of `num_parts` as a view, made once and kept
+        on THIS instance: every task that scans the split gets the same
+        view, so the device columns its `page` keeps outlive the task
+        and die with this table. A connector that replaces a written
+        table's instance (connectors/memory.py) thereby drops every
+        split of the old version with it — no version check to race."""
+        if num_parts == 1:
+            return self
+        views = self.__dict__.setdefault("_split_views", {})
+        view = views.get((part, num_parts))
+        if view is None:
+            lo, hi = _slice_rows(self.num_rows, part, num_parts)
+            view = self.row_slice(lo, hi)
+            view.keeps_device_columns = True
+            # two tasks may get here at once: one view wins, for both
+            view = views.setdefault((part, num_parts), view)
+        return view
+
+    def _device_column(self, c: str, cap: int):
+        t = self.types[c]
+        if t.name in ("array", "map", "row"):
+            from presto_tpu.data.column import NestedColumn
+            return NestedColumn.from_pylist(
+                list(self.arrays[c][:self.num_rows]), t, cap)
+        if getattr(t, "uses_int128", False):
+            # DECIMAL(p>18) at rest: python-int unscaled values
+            # -> four 32-bit limb lanes (exact 38-digit range)
+            from presto_tpu.data.column import Decimal128Column
+            return Decimal128Column.from_unscaled_ints(
+                list(self.arrays[c][:self.num_rows]), t,
+                nulls=self.null_mask(c), capacity=cap)
+        return Column.from_numpy(
+            self.arrays[c][:self.num_rows], t, nulls=self.null_mask(c),
+            dictionary=self.dicts.get(c), capacity=cap)
 
     def page(self, columns: Optional[Sequence[str]] = None,
              capacity: Optional[int] = None) -> Page:
         cols = list(columns) if columns is not None else self.column_names()
         cap = capacity or bucket_capacity(self.num_rows)
-        # per-(column, capacity) DEVICE cache: re-executions and sibling
-        # islands reuse resident columns instead of re-uploading hundreds
-        # of MB from host to device each run. Different column
-        # subsets share entries because caching is per column. NOTE: the
-        # cache lives on the HostTable instance, so it covers whole-table
-        # scans (lru-cached _gen_table / MemoryConnector.tables entries —
-        # the single-chip engine + bench path); split slices
-        # (table(part=...)) build throwaway HostTables and still upload
-        # per call.
-        cache = self.__dict__.setdefault("_dev_page_cache", {})
+        # per-(column, capacity) DEVICE columns, kept on this instance:
+        # re-executions, sibling islands and the next statement's tasks
+        # find them resident instead of moving hundreds of MB from host
+        # to device again. Different column subsets share entries
+        # because keeping is per column. Whole tables (lru-cached
+        # _gen_table / MemoryConnector.tables entries) and the split
+        # views a connector's table(part=...) hands out (`split`) are
+        # kept alike, under one budget (connectors/scan_cache.py); a
+        # `row_slice` window keeps nothing.
+        kept = self.__dict__.setdefault("_dev_page_cache", KeptColumns())
         out = []
-        held = [cache[c, cap] for c in cols if (c, cap) in cache]
-        if held:
-            # what the device already holds is not moved: the `upload`
-            # span around this call counts the rest
-            TRACER.add("upload", resident=page_nbytes(held))
+        resident = evicted = 0
         for c in cols:
             key = (c, cap)
-            col = cache.get(key)
-            if col is None:
-                t = self.types[c]
-                if t.name in ("array", "map", "row"):
-                    from presto_tpu.data.column import NestedColumn
-                    col = NestedColumn.from_pylist(
-                        list(self.arrays[c][:self.num_rows]), t, cap)
-                elif getattr(t, "uses_int128", False):
-                    # DECIMAL(p>18) at rest: python-int unscaled values
-                    # -> four 32-bit limb lanes (exact 38-digit range)
-                    from presto_tpu.data.column import Decimal128Column
-                    col = Decimal128Column.from_unscaled_ints(
-                        list(self.arrays[c][:self.num_rows]), t,
-                        nulls=self.null_mask(c), capacity=cap)
-                else:
-                    col = Column.from_numpy(
-                        self.arrays[c][:self.num_rows], t,
-                        nulls=self.null_mask(c),
-                        dictionary=self.dicts.get(c), capacity=cap)
-                cache[key] = col
+            col = kept.get(key)
+            if col is not None:
+                SCAN_CACHE.hit(kept, key)
+                resident += page_nbytes(col)
+            else:
+                SCAN_CACHE.miss()
+                col = self._device_column(c, cap)
+                if self.keeps_device_columns:
+                    evicted += SCAN_CACHE.keep(kept, key, col,
+                                               page_nbytes(col))
             out.append(col)
-        return Page.from_columns(out, self.num_rows, cols)
+        # the page's row count is four bytes on the device too, kept
+        # beside the columns and outside the budget
+        rows = kept.num_rows
+        if rows is not None:
+            resident += rows.nbytes
+        page = Page.from_columns(
+            out, self.num_rows if rows is None else rows, cols)
+        if self.keeps_device_columns:
+            kept.num_rows = page.num_rows
+        # what the device already holds is not moved: the `upload` span
+        # around this call counts the rest
+        if resident:
+            TRACER.add("upload", resident=resident)
+        if evicted:
+            TRACER.add("upload", evicted=evicted)
+        return page
 
 
 def _dictify(values: np.ndarray) -> Tuple[np.ndarray, StringDict]:
@@ -516,7 +562,8 @@ class TpchConnector(SplitSource):
     def table(self, name: str, part: int = 0, num_parts: int = 1
               ) -> HostTable:
         """Full table (cached), or split `part` of `num_parts` as a
-        row-range slice of it. Slices share the full table's StringDicts,
+        row-range slice of it (`HostTable.split`: one view a split, kept
+        on the full table). Slices share the full table's StringDicts,
         so codes are globally consistent — the property every cross-device
         exchange and dictionary-aligned operator relies on (reference
         analogue: TpchSplitManager handing row ranges of one logical
@@ -524,8 +571,4 @@ class TpchConnector(SplitSource):
         if name not in TPCH_SCHEMA:
             raise KeyError(f"unknown tpch table {name}")
         full = _gen_table(name, self.scale_factor)  # lru_cached
-        if num_parts == 1:
-            return full
-        lo, hi = _slice_rows(full.num_rows, part, num_parts)
-        arrays = {c: a[lo:hi] for c, a in full.arrays.items()}
-        return HostTable(name, hi - lo, arrays, full.types, full.dicts)
+        return full.split(part, num_parts)

@@ -1,7 +1,13 @@
 """SplitExecutor — scans read ASSIGNED splits (row ranges), not whole
 tables: the worker-side contract (splits arrive in
 TaskUpdateRequest.sources; reference ScheduledSplit / ConnectorSplit) and
-the building block of lifespan-batched execution (exec/lifespan.py)."""
+the building block of lifespan-batched execution (exec/lifespan.py).
+
+A scan of ONE split is that split's `HostTable.page`: the connector hands
+every task the same split view, so the columns a first task put on the
+device are resident for the next (connectors/scan_cache.py holds the
+bytes under its budget). Several splits in one task, and streaming scan
+runs (`set_split_tables`), are put up for the scan alone."""
 
 from __future__ import annotations
 
@@ -79,6 +85,14 @@ class SplitExecutor(Executor):
             return super()._scan_page(s)
         tables = [self.connector.table(s.table, part=p, num_parts=n)
                   for p, n in parts]
+        if len(tables) == 1:
+            # one split a table is what a task of the served path holds:
+            # the split's own page, whose columns stay on the device
+            # with the table the connector keeps (HostTable.split)
+            return tables[0].page(columns=list(s.columns),
+                                  capacity=s.capacity)
+        # several splits in one task (lifespans, pruned split sets):
+        # concatenated on the host and put up for this scan alone
         n_rows = sum(t.num_rows for t in tables)
         cols = []
         for c in s.columns:

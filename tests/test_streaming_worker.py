@@ -117,7 +117,11 @@ def test_tokens_advance_while_running():
             st = _status(srv.port, "stream.0.0.0.0")
             if st["state"] == "RUNNING":
                 observations.append(stream.token)
-        st = _status(srv.port, "stream.0.0.0.0")
+        # the buffers complete a moment BEFORE the task says FINISHED
+        # (task_manager: set_no_more_pages, spool commit, set_state)
+        while (st := _status(srv.port, "stream.0.0.0.0"))["state"] \
+                == "RUNNING" and time.time() < deadline:
+            time.sleep(0.02)
         assert st["state"] == "FINISHED", st
 
         # >= 2 distinct token positions seen while the task was RUNNING:
