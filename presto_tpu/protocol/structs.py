@@ -1128,6 +1128,10 @@ class TaskStatus(Struct):
     taskAgeInMillis: int = 0
     queuedPartitionedSplitsWeight: int = 0
     runningPartitionedSplitsWeight: int = 0
+    #: span clock (epoch seconds) of the last state transition: the
+    #: coordinator's `task_notice` starts there; a worker that does not
+    #: say (the reference's) leaves it out
+    stateChangedAt: Optional[float] = None
     _SCHEMA = [
         ("taskInstanceIdLeastSignificantBits",
          "taskInstanceIdLeastSignificantBits", None),
@@ -1157,6 +1161,7 @@ class TaskStatus(Struct):
          "queuedPartitionedSplitsWeight", None),
         ("runningPartitionedSplitsWeight",
          "runningPartitionedSplitsWeight", None),
+        ("stateChangedAt", "stateChangedAt", ("opt", None)),
     ]
 
 

@@ -41,7 +41,7 @@ from presto_tpu.plan.stats import (
     HistoryStore, canonical_key, default_history_path, estimate_rows,
 )
 from presto_tpu.utils.threads import spawn
-from presto_tpu.utils.tracing import TRACER, root_scope, trace_scope
+from presto_tpu.utils.tracing import TRACER, now, root_scope, trace_scope
 from presto_tpu.plan.nodes import ExchangeNode, Partitioning, PlanNode
 from presto_tpu.protocol import structs as S
 from presto_tpu.protocol.exchange import (
@@ -160,7 +160,10 @@ def bounded_merge(batch_sources, key, queue_pages=4):
     iterator of row batches (lists of tuples). Returns
     ``(rows, in_flight_high_water)``. The first real producer failure is
     re-raised after all producers stop; sibling streams abort instead of
-    draining to completion."""
+    draining to completion. The merging loop is the `root_merge` span:
+    `blocked_ms` is its time blocked in a stream's `get` (not named
+    `waited_ms`, which marks a back-dated marker to the trace's reader),
+    `timeouts` the gets that ran out their 0.05 s."""
     import heapq
     import queue as _queue
 
@@ -199,17 +202,28 @@ def bounded_merge(batch_sources, key, queue_pages=4):
         finally:
             done[i] = True
 
+    # the merging thread's seconds blocked in `get`, the gets that ran
+    # out their timeout, the batches merged: `root_merge`'s attributes
+    blocked_s = [0.0]
+    timeouts = [0]
+    batches = [0]
+
     def stream(i):
         while True:
+            t0 = time.perf_counter()
             try:
                 batch = queues[i].get(timeout=0.05)
             except _queue.Empty:
+                blocked_s[0] += time.perf_counter() - t0
+                timeouts[0] += 1
                 if failed.is_set():
                     raise ClusterQueryError(
                         "merge input stream failed; aborting merge")
                 if done[i] and queues[i].empty():
                     return
                 continue
+            blocked_s[0] += time.perf_counter() - t0
+            batches[0] += 1
             with lock:
                 in_flight[0] -= 1
             for row in batch:
@@ -221,7 +235,14 @@ def bounded_merge(batch_sources, key, queue_pages=4):
     for t in threads:
         t.start()
     try:
-        rows = list(heapq.merge(*(stream(i) for i in range(n)), key=key))
+        with TRACER.span(None, "root_merge", streams=n) as sp:
+            try:
+                rows = list(heapq.merge(*(stream(i) for i in range(n)),
+                                        key=key))
+            finally:
+                sp.attributes.update(blocked_ms=1e3 * blocked_s[0],
+                                     timeouts=timeouts[0],
+                                     batches=batches[0])
     except BaseException:
         failed.set()                 # release blocked producers
         for t in threads:
@@ -2161,7 +2182,11 @@ class TpuCluster:
         tried = set()
         while True:
             try:
-                self._post(uri, body)
+                # the TaskUpdateRequest round trip, the worker's
+                # task_create with it
+                with TRACER.span(None, "task_post", task=task_id,
+                                 worker=worker_uri, bytes=len(body)):
+                    self._post(uri, body)
                 return task_id, uri
             except FatalResponseError as e:
                 if not e.draining:
@@ -2230,9 +2255,26 @@ class TpuCluster:
         wake = threading.Event()          # first failure OR all done
         remaining = [len(uris)]
         lock = threading.Lock()
+        # the watchers record `task_notice` for this thread's trace
+        here = TRACER.here()
+
+        def _notice(uri: str, st: dict, polls: int) -> None:
+            """`task_notice`: from the worker's terminal transition
+            (`stateChangedAt`, the worker's span clock) to this answer
+            of its status poll; none where the worker does not say."""
+            at = st.get("stateChangedAt")
+            if at is None:
+                return
+            end = now()
+            TRACER.record(here.trace_id if here else None, "task_notice",
+                          min(float(at), end), end,
+                          parent_id=here.parent_span_id if here else "",
+                          mark=True, task=uri.rsplit("/", 1)[-1],
+                          state=st["state"], polls=polls)
 
         def watch(uri: str):
             state = "PLANNED"
+            polls = 0
             try:
                 while state in ("PLANNED", "RUNNING"):
                     if wake.is_set() and errs:
@@ -2244,7 +2286,9 @@ class TpuCluster:
                         headers={"X-Presto-Current-State": state,
                                  "X-Presto-Max-Wait": "1s"},
                         request_class="status_poll")
+                    polls += 1
                     state = st["state"]
+                _notice(uri, st, polls)
                 results[uri] = st
                 if state != "FINISHED":
                     msgs = [f.get("message", "") for f in

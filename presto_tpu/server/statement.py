@@ -68,12 +68,15 @@ class _DoneEvent(threading.Event):
     long-poll registers a loop-threadsafe waker here so a parked poll
     wakes the instant the query finishes instead of sleeping out its
     poll window. Callbacks fire exactly once, from whichever thread
-    calls set(); one registered after set() fires immediately."""
+    calls set(); one registered after set() fires immediately. `at` is
+    the span clock when set() was called: the nextUri GET's
+    `statement_results` span starts there, not at the parked poll."""
 
     def __init__(self):
         super().__init__()
         self._cb_lock = threading.Lock()
         self._cbs: List[Callable[[], None]] = []
+        self.at: Optional[float] = None
 
     def add_callback(self, cb: Callable[[], None]) -> None:
         with self._cb_lock:
@@ -90,6 +93,7 @@ class _DoneEvent(threading.Event):
                 pass
 
     def set(self) -> None:
+        self.at = now()
         super().set()
         with self._cb_lock:
             cbs, self._cbs = self._cbs, []
@@ -116,6 +120,11 @@ class _Query:
         self.cancelled = False
         #: span clock at the hand-over to the dispatcher
         self.t_submitted: Optional[float] = None
+        #: drawn once a statement, at submit: the protocol's spans are
+        #: stored under the qid before `run` opens the trace
+        self.sampled = DEFAULT_OBS.sampled(random.random())
+        #: whether submit appended the statement to the journal
+        self.journaled = False
         # final-batch cache: clients auto-retry nextUri GETs, so the
         # last data batch must survive serving it once — a replayed GET
         # of the same token re-serves the same rows instead of silently
@@ -134,15 +143,20 @@ class _Query:
         admission grant -> a free pool thread: callback-driven, no
         thread sat in it) is recorded now, with its measured start."""
         self.state = "RUNNING"
-        with root_scope(self.qid, DEFAULT_OBS.sampled(random.random())), \
-                TRACER.span(None, "statement", qid=self.qid) as sp:
-            if self.t_submitted is not None:
-                handle = getattr(self, "_handle", None)
-                TRACER.record(
-                    None, "admission_wait", self.t_submitted, now(),
-                    parent_id=sp.span_id, mark=True,
-                    group=getattr(handle, "group_path", None) or "")
-            self._run(engine)
+        try:
+            with root_scope(self.qid, self.sampled), \
+                    TRACER.span(None, "statement", qid=self.qid) as sp:
+                if self.t_submitted is not None:
+                    handle = getattr(self, "_handle", None)
+                    TRACER.record(
+                        None, "admission_wait", self.t_submitted, now(),
+                        parent_id=sp.span_id, mark=True,
+                        group=getattr(handle, "group_path", None) or "")
+                self._run(engine)
+        finally:
+            # after the statement span: what the nextUri GET does from
+            # here on is the protocol's (`statement_results`)
+            self.done.set()
 
     def _run(self, engine):
         try:
@@ -191,7 +205,6 @@ class _Query:
                 self.error = "Query was canceled by the user"
                 self.rows = []
             _M_QUERIES.inc(state=self.state)
-            self.done.set()
 
     def results_json(self, base: str, token: int) -> dict:
         out = {
@@ -275,6 +288,43 @@ class StatementApp:
     def _json(code: int, obj) -> Response:
         return Response(code, json.dumps(obj).encode())
 
+    def _answer(self, q: "_Query", name: str, start: float, out: dict,
+                **attributes) -> Response:
+        """A statement-protocol response and its span: `name` covers
+        `start` to now, when the response is built, as a back-dated
+        marker (the handlers are coroutines on the loop, so no span can
+        stay open around them); stored under the query id where the
+        statement is sampled, annotated only where it is not. `bytes`
+        is the response body's unless the caller says otherwise."""
+        body = json.dumps(out).encode()
+        attributes.setdefault("bytes", len(body))
+        TRACER.record(q.qid if q.sampled else None, name, start, now(),
+                      mark=True, **attributes)
+        return Response(200, body)
+
+    def _posted(self, q: "_Query", arrived: float, req: Request
+                ) -> Response:
+        """POST /v1/statement's answer: `statement_post` runs from the
+        request's arrival through submit (admission, journal append,
+        dispatch) to the first QueryResults."""
+        return self._answer(q, "statement_post", arrived,
+                            q.results_json(self.base, 0),
+                            bytes=len(req.body), journal=q.journaled)
+
+    def _results(self, q: "_Query", arrived: float, token: int
+                 ) -> Response:
+        """A nextUri GET's answer: `statement_results` starts at the
+        later of the GET's arrival and the statement's end (the end of
+        the park where it is still running), so the parked long-poll is
+        left out and the loop's wake-up, `results_json` and the JSON
+        encoding are in."""
+        ready = q.done.at if q.done.is_set() else now()
+        start = max(arrived, ready)
+        out = q.results_json(self.base, token)
+        return self._answer(q, "statement_results", start, out,
+                            rows=len(out.get("data") or ()),
+                            parked_ms=1e3 * (start - arrived))
+
     # -------------------------------------------------- async hot paths
     def dispatch_async(self, req: Request, server: AioHttpServer):
         if req.method == "POST" and req.path == "/v1/statement":
@@ -301,6 +351,7 @@ class StatementApp:
     async def _submit_async(self, server: AioHttpServer, req: Request):
         if self._dead(server):
             return None
+        arrived = now()
         sql = req.body.decode()
         try:
             # admission + journal append touch locks and disk — run
@@ -312,7 +363,7 @@ class StatementApp:
                 req.headers.get("X-Presto-Idempotency-Key"))
         except OverloadedError as e:
             return self._overloaded(e)
-        return self._json(200, q.results_json(self.base, 0))
+        return self._posted(q, arrived, req)
 
     def _do_submit(self, sql, user, source, idem) -> "_Query":
         return self.coordinator.submit(sql, user=user, source=source,
@@ -334,6 +385,7 @@ class StatementApp:
                           qid: str, token: int):
         if self._dead(server):
             return None
+        arrived = now()
         co = self.coordinator
         q = co.queries.get(qid)
         if q is None:
@@ -357,7 +409,7 @@ class StatementApp:
                 q.done.remove_callback(wake)
         if self._dead(server):   # killed mid-poll: die silently
             return None
-        return self._json(200, q.results_json(self.base, token))
+        return self._results(q, arrived, token)
 
     # ------------------------------------------------------ sync router
     def handle(self, req: Request) -> Optional[Response]:
@@ -382,6 +434,7 @@ class StatementApp:
             return self._do_ingest(req, *m.groups())
         if path != "/v1/statement":
             return self._json(404, {"error": "no route"})
+        arrived = now()
         sql = req.body.decode()
         try:
             q = self.coordinator.submit(
@@ -392,7 +445,7 @@ class StatementApp:
                     "X-Presto-Idempotency-Key"))
         except OverloadedError as e:
             return self._overloaded(e)
-        return self._json(200, q.results_json(self.base, 0))
+        return self._posted(q, arrived, req)
 
     def _do_ingest(self, req: Request, catalog: str, schema: str,
                    table: str) -> Response:
@@ -425,6 +478,7 @@ class StatementApp:
         if m:
             # threaded fallback for the nextUri poll (normally served
             # async): same adopt + bounded wait semantics
+            arrived = now()
             q = self.coordinator.queries.get(m.group(1))
             if q is None:
                 q = self.coordinator.adopt(m.group(1))
@@ -433,8 +487,7 @@ class StatementApp:
             q.done.wait(timeout=1.0)
             if self._dead(self.coordinator.httpd):
                 return None     # killed mid-poll: die silently
-            return self._json(200, q.results_json(self.base,
-                                                  int(m.group(2))))
+            return self._results(q, arrived, int(m.group(2)))
         if path == "/v1/query":
             # the query list (QueryResource.getAllQueryInfo role —
             # the UI's landing data)
@@ -769,6 +822,7 @@ class StatementServer:
                                 group=self._group_path(user, source),
                                 state="QUEUED",
                                 owner=self.coordinator_id)
+            q.journaled = True
         try:
             self._dispatch(q, user=user, source=source)
         except OverloadedError:

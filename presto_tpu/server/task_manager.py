@@ -35,7 +35,8 @@ from presto_tpu.protocol.serde import (
 )
 from presto_tpu.server.buffers import OutputBufferManager
 from presto_tpu.utils.threads import spawn
-from presto_tpu.utils.tracing import TRACER, TraceContext, trace_scope
+from presto_tpu.utils.tracing import (TRACER, TraceContext, now,
+                                      trace_scope)
 
 _M_TASKS_CREATED = _counter("presto_tpu_tasks_created_total",
                             "Tasks ever created on this worker")
@@ -218,6 +219,8 @@ class Task:
         self.session_properties: Dict[str, str] = {}
         self.update_lock = threading.Lock()
         self.state_change = threading.Condition()
+        #: span clock of the last set_state (TaskStatus.stateChangedAt)
+        self.state_at: Optional[float] = None
         self.bytes_out = 0
         # execution stats (TaskStats/OperatorStats roles)
         self.start_time: Optional[float] = None
@@ -254,6 +257,7 @@ class Task:
     def set_state(self, state: str):
         with self.state_change:
             self.state = state
+            self.state_at = now()
             self.version += 1
             self.state_change.notify_all()
         _M_TASK_TRANSITIONS.inc(state=state)
@@ -279,6 +283,7 @@ class Task:
             taskAgeInMillis=int((time.time() - self.created) * 1000),
             failures=[{"message": m, "type": "PRESTO_TPU"}
                       for m in self.failures],
+            stateChangedAt=self.state_at,
         )
 
     def stats_tree(self) -> dict:
