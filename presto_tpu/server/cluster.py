@@ -156,14 +156,29 @@ def bounded_merge(batch_sources, key, queue_pages=4):
     materialized before the merge. The consumer side feeds
     ``heapq.merge`` — streams stay sorted, output is the total order.
 
+    A stream's end and a producer's failure reach the merging thread
+    through the queue it blocks on, never through a timer. A producer
+    whose iterator is exhausted puts an end marker on its own queue; the
+    consumer's `get` returns it at once and the stream ends. A failing
+    producer sets ``failed``, then puts a failure marker on every queue
+    with room — a full queue is not one the consumer can be blocked on,
+    and the consumer checks ``failed`` after every `get`, so whatever it
+    takes next (a batch, an end marker or the failure marker) aborts the
+    merge. An end marker is thus never read as a clean end once a sibling
+    has failed, and an aborted merge never returns truncated rows.
+    Markers are not batches: they count neither in flight nor in the
+    high-water mark.
+
     ``batch_sources`` is a list of zero-arg callables each returning an
     iterator of row batches (lists of tuples). Returns
     ``(rows, in_flight_high_water)``. The first real producer failure is
-    re-raised after all producers stop; sibling streams abort instead of
-    draining to completion. The merging loop is the `root_merge` span:
+    re-raised as soon as the merging thread wakes to it; sibling streams
+    abort at their next batch instead of draining to completion, and the
+    merge does not wait for them. The merging loop is the `root_merge` span:
     `blocked_ms` is its time blocked in a stream's `get` (not named
     `waited_ms`, which marks a back-dated marker to the trace's reader),
-    `timeouts` the gets that ran out their 0.05 s."""
+    `timeouts` the gets that ran out the 1 s guard against a lost
+    wake-up: 0 on a merge that no producer keeps waiting a second."""
     import heapq
     import queue as _queue
 
@@ -171,12 +186,22 @@ def bounded_merge(batch_sources, key, queue_pages=4):
     if n == 0:
         return [], 0
     queues = [_queue.Queue(maxsize=queue_pages) for _ in range(n)]
-    done = [False] * n
     failed = threading.Event()
     cause: List[BaseException] = []
     lock = threading.Lock()
     in_flight = [0]
     high_water = [0]
+    end, failure = object(), object()       # the queues' markers
+
+    def put(i, item):
+        """Blocks for room on queue i; False once the merge aborts."""
+        while not failed.is_set():
+            try:
+                queues[i].put(item, timeout=0.05)
+                return True
+            except _queue.Full:
+                continue
+        return False
 
     def produce(i):
         try:
@@ -187,23 +212,21 @@ def bounded_merge(batch_sources, key, queue_pages=4):
                     in_flight[0] += 1
                     if in_flight[0] > high_water[0]:
                         high_water[0] = in_flight[0]
-                while True:
-                    if failed.is_set():
-                        return
-                    try:
-                        queues[i].put(batch, timeout=0.05)
-                        break
-                    except _queue.Full:
-                        continue
+                if not put(i, batch):
+                    return
+            put(i, end)
         except BaseException as e:   # noqa: BLE001 — propagated below
             if not failed.is_set():
                 cause.append(e)      # the REAL failure, not a sibling's
             failed.set()             # abort placeholder
-        finally:
-            done[i] = True
+            for q in queues:         # wake the consumer where it waits
+                try:
+                    q.put_nowait(failure)
+                except _queue.Full:
+                    pass
 
     # the merging thread's seconds blocked in `get`, the gets that ran
-    # out their timeout, the batches merged: `root_merge`'s attributes
+    # out the guard, the batches merged: `root_merge`'s attributes
     blocked_s = [0.0]
     timeouts = [0]
     batches = [0]
@@ -212,17 +235,18 @@ def bounded_merge(batch_sources, key, queue_pages=4):
         while True:
             t0 = time.perf_counter()
             try:
-                batch = queues[i].get(timeout=0.05)
+                batch = queues[i].get(timeout=1.0)
             except _queue.Empty:
-                blocked_s[0] += time.perf_counter() - t0
+                batch = None
                 timeouts[0] += 1
-                if failed.is_set():
-                    raise ClusterQueryError(
-                        "merge input stream failed; aborting merge")
-                if done[i] and queues[i].empty():
-                    return
-                continue
             blocked_s[0] += time.perf_counter() - t0
+            if failed.is_set():
+                raise ClusterQueryError(
+                    "merge input stream failed; aborting merge")
+            if batch is end:
+                return
+            if batch is None:
+                continue
             batches[0] += 1
             with lock:
                 in_flight[0] -= 1
@@ -244,9 +268,10 @@ def bounded_merge(batch_sources, key, queue_pages=4):
                                      timeouts=timeouts[0],
                                      batches=batches[0])
     except BaseException:
-        failed.set()                 # release blocked producers
-        for t in threads:
-            t.join(timeout=5)
+        # release blocked producers; one still inside its source stops at
+        # its next batch, and no one waits for it: a failing producer
+        # records its cause before it sets `failed`
+        failed.set()
         if cause:
             raise cause[0]
         raise

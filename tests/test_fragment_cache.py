@@ -10,6 +10,8 @@ structurally unreachable — the cache can serve a wrong answer only if
 the fingerprint machinery itself is wrong, never by forgetting to purge.
 """
 
+import os
+import sys
 import threading
 import time
 
@@ -25,6 +27,7 @@ from presto_tpu.exec.split_executor import SplitExecutor
 from presto_tpu.plan.fingerprint import fragment_cache_key, plan_fingerprint
 from presto_tpu.server.cluster import TpuCluster, bounded_merge
 from presto_tpu.testing import FaultInjector, FaultSpec
+from presto_tpu.utils.tracing import TRACER, trace_scope
 
 CACHE_ON = {"fragment_result_cache_enabled": "true"}
 
@@ -256,7 +259,17 @@ def test_killed_worker_cache_degrades_to_misses_not_errors():
 
 
 # -------------------------------------------------------- bounded merge
-def test_bounded_merge_sorts_with_bounded_in_flight():
+class _Key:
+    def __init__(self, row):
+        self.row = row
+
+    def __lt__(self, other):
+        return self.row[0] < other.row[0]
+
+
+# one slot a queue: an end marker competes with the batches for it
+@pytest.mark.parametrize("queue_pages", [1, 2])
+def test_bounded_merge_sorts_with_bounded_in_flight(queue_pages):
     k = 4
     per_stream = 40
 
@@ -267,17 +280,10 @@ def test_bounded_merge_sorts_with_bounded_in_flight():
                 yield [((s + k * b),)]
         return batches
 
-    class Key:
-        def __init__(self, row):
-            self.row = row
-
-        def __lt__(self, other):
-            return self.row[0] < other.row[0]
-
-    rows, high = bounded_merge([source(s) for s in range(k)], key=Key,
-                               queue_pages=2)
+    rows, high = bounded_merge([source(s) for s in range(k)], key=_Key,
+                               queue_pages=queue_pages)
     assert [r[0] for r in rows] == list(range(k * per_stream))
-    assert high <= k * (2 + 2), \
+    assert high <= k * (queue_pages + 2), \
         f"in-flight batches must stay bounded, saw {high}"
 
 
@@ -290,16 +296,87 @@ def test_bounded_merge_propagates_producer_failure():
         yield [(0,)]
         raise ValueError("stream died")
 
-    class Key:
-        def __init__(self, row):
-            self.row = row
-
-        def __lt__(self, other):
-            return self.row[0] < other.row[0]
-
     with pytest.raises(ValueError, match="stream died"):
-        bounded_merge([lambda: ok(), lambda: boom()], key=Key,
+        bounded_merge([lambda: ok(), lambda: boom()], key=_Key,
                       queue_pages=2)
+
+
+def test_bounded_merge_failure_wakes_a_merge_blocked_on_a_sibling():
+    """The merging thread blocks on stream 0, whose producer sleeps 5 s;
+    stream 1's producer fails meanwhile. Its failure marker wakes the
+    merge, which raises the real error without waiting out the sleep."""
+    release = threading.Event()
+
+    def sleepy():
+        release.wait(5)
+        yield [(0,)]
+
+    def boom():
+        time.sleep(0.05)         # the merge is blocked on stream 0 by now
+        raise ValueError("stream died")
+        yield
+
+    t0 = time.perf_counter()
+    try:
+        with trace_scope("merge-woken"), \
+                pytest.raises(ValueError, match="stream died"):
+            bounded_merge([sleepy, boom], key=_Key)
+        took = time.perf_counter() - t0
+    finally:
+        release.set()
+    assert took < 2.0, took
+    merge, = [s for s in TRACER.get("merge-woken") if s.name == "root_merge"]
+    assert merge.attributes["timeouts"] == 0     # woken, not timed out
+
+
+def test_bounded_merge_never_takes_a_failure_for_an_end():
+    """A failing stream beside streams that finish always raises, whatever
+    the interleaving: an end marker read after the failure, or a failure
+    marker that found its queue full, never ends the merge with the rows
+    taken so far. Thread switches are forced often, and every tenth merge
+    has more producers than the machine has cores."""
+    def ok(n, s):
+        def batches():
+            for i in range(n):
+                yield [(2 * i + s,)]
+        return batches
+
+    def boom(n):
+        def batches():
+            for i in range(n):
+                yield [(2 * i + 1,)]
+            raise ValueError("stream died")
+        return batches
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for rep in range(240):
+            n_ok, n_boom, pages = rep % 5, (rep // 5) % 4, 1 + (rep // 20) % 3
+            siblings = (os.cpu_count() or 1) + 1 if rep % 10 == 9 else 1
+            sources = [ok(n_ok, s) for s in range(siblings)] + [boom(n_boom)]
+            if rep % 2:
+                sources.reverse()
+            with pytest.raises(ValueError, match="stream died"):
+                bounded_merge(sources, key=_Key, queue_pages=pages)
+    finally:
+        sys.setswitchinterval(interval)
+
+
+@pytest.mark.parametrize("streams", [
+    [[]], [[], []], [[[]]], [[[], []], []], [[[(1,)], []], [[], [(0,)]]],
+    [[[(0,)], [(2,)]], [[]], []],
+], ids=["one-empty", "two-empty", "one-empty-batch", "empty-batches",
+        "empty-batches-between-rows", "rows-beside-empty"])
+def test_bounded_merge_ends_empty_streams_without_timeouts(streams):
+    with trace_scope("merge-empty"):
+        rows, high = bounded_merge(
+            [(lambda b=b: iter(b)) for b in streams], key=_Key)
+    want = sorted(r for b in streams for batch in b for r in batch)
+    assert rows == want
+    assert high <= len(streams) * (4 + 2)
+    merge = [s for s in TRACER.get("merge-empty") if s.name == "root_merge"]
+    assert merge and all(m.attributes["timeouts"] == 0 for m in merge)
 
 
 def test_cluster_merge_records_bounded_high_water(cached_cluster):
