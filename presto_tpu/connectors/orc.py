@@ -20,9 +20,10 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from presto_tpu.connectors.parquet import (
-    FileCatalogConnector, LazyFileTable, _LazyArrays, _arrow_to_type,
+    FileCatalogConnector, LazyFileTable, _arrow_to_type,
     _decode_column, rows_to_arrow_table,
 )
+from presto_tpu.connectors.tpch import LazyColumns
 from presto_tpu.data.column import StringDict
 
 
@@ -52,7 +53,7 @@ class OrcTable(LazyFileTable):
             n = sum(self.stripe_lengths()[u] for u in self.units)
         self._dicts: Dict[str, StringDict] = {}
         self._nulls: Dict[str, np.ndarray] = {}
-        super().__init__(name, n, _LazyArrays(self._load_column),
+        super().__init__(name, n, LazyColumns(self._load_column),
                          types, self._dicts, self._nulls)
 
     def stripe_lengths(self) -> Dict[Tuple[int, int], int]:
@@ -83,7 +84,7 @@ class OrcTable(LazyFileTable):
         if d is not None:
             self._dicts[col] = d
         self._nulls[col] = nulls
-        return vals, nulls, d
+        return vals
 
 
 def read_orc_table(path: str, name: str) -> OrcTable:

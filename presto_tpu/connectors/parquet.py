@@ -39,7 +39,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from presto_tpu.connectors.base import SplitSource
-from presto_tpu.connectors.tpch import HostTable, _slice_rows
+from presto_tpu.connectors.tpch import HostTable, LazyColumns, _slice_rows
 from presto_tpu.data.column import StringDict
 from presto_tpu.types import (
     BIGINT, BOOLEAN, DATE, DOUBLE, INTEGER, REAL, SMALLINT, TIMESTAMP,
@@ -155,22 +155,6 @@ def _decode_column(col, t: Type):
     return (np.asarray(col.fill_null(0)).astype(t.dtype), mask, None)
 
 
-class _LazyArrays(dict):
-    """Column name -> ndarray, loaded from the file's column chunks on
-    first access (projection pushdown: `page(columns=[...])` only ever
-    touches the requested names). Shared by every lazy file-format
-    table (parquet, orc)."""
-
-    def __init__(self, loader):
-        super().__init__()
-        self._loader = loader
-
-    def __missing__(self, key):
-        vals, nulls, d = self._loader(key)
-        self[key] = vals
-        return vals
-
-
 class LazyFileTable(HostTable):
     """Base for lazily-loading file-format tables: the null-mask cache
     rides the same lazy column load."""
@@ -205,7 +189,7 @@ class ParquetTable(LazyFileTable):
                 for fi, g in self.units)
         self._dicts: Dict[str, StringDict] = {}
         self._nulls: Dict[str, np.ndarray] = {}
-        super().__init__(name, n, _LazyArrays(self._load_column), types,
+        super().__init__(name, n, LazyColumns(self._load_column), types,
                          self._dicts, self._nulls)
 
     def unit_rows(self, unit: Tuple[int, int]) -> int:
@@ -228,7 +212,7 @@ class ParquetTable(LazyFileTable):
         if d is not None:
             self._dicts[col] = d
         self._nulls[col] = nulls
-        return vals, nulls, d
+        return vals
 
     # -- row-group statistics (predicate pushdown support) --------------
     def _leaf_index(self, col: str) -> Optional[int]:
