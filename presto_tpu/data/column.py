@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import decimal as _decimal
+import functools
 import hashlib
 import itertools
 import struct
@@ -39,7 +40,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from presto_tpu.obs.metrics import counter as _counter
 from presto_tpu.types import Type, DecimalType, VARCHAR
+from presto_tpu.utils.tracing import TRACER
 
 
 #: explicit wide context for every engine-side Decimal op: python's
@@ -882,16 +885,106 @@ def page_nbytes(page: Page) -> int:
                for a in jax.tree_util.tree_leaves(page))
 
 
-def page_to_host(page: Page) -> None:
-    """Fetch every array of a device page. jax keeps the host copy with
-    the array, so the `np.asarray` of each leaf that follows is free:
-    the device->host step of an output page is paid here, once, apart
-    from partitioning and serialization. Who relies on that: the
-    partitioner (`_hash_partition_ids`, `select_page_host`) and the
-    single-buffer `_serialize(page)`, which all read the device page's
-    own arrays; a partition is a host page from then on."""
-    for a in jax.tree_util.tree_leaves(page):
-        np.asarray(a)
+_DOWNLOAD_TRANSFERS = _counter(
+    "presto_tpu_download_transfers_total",
+    "Device-to-host fetches of output pages: stacked, one for each dtype "
+    "of a page's live rows; per_array, one for each array of a page "
+    "whose arrays are not all on one device",
+    labelnames=("form",))
+
+#: a page of fewer slots comes to the host whole: its padding is at
+#: most 0.5 MB
+_LIVE_EXTENT_MIN_SLOTS = 65536
+#: a larger one at the least multiple of a sixteenth of its capacity
+#: that holds its rows, so a page shape has at most 16 fetch programs
+_LIVE_EXTENT_STEPS = 16
+
+
+def live_extent(capacity: int, num_rows: int) -> int:
+    """The slots of a page of `capacity` that the host fetches to read
+    its first `num_rows` rows."""
+    if capacity < _LIVE_EXTENT_MIN_SLOTS:
+        return capacity
+    step = -(-capacity // _LIVE_EXTENT_STEPS)
+    return min(capacity, max(1, -(-num_rows // step)) * step)
+
+
+def _row_lanes(c) -> list:
+    """The arrays of a column with one slot a row (a nested column's
+    children are not: they hold its entries)."""
+    if isinstance(c, Decimal128Column):
+        return c.row_lanes()
+    if isinstance(c, NestedColumn):
+        return [c.starts, c.lengths, c.nulls]
+    return [c.values, c.nulls]
+
+
+def _with_row_lanes(c, lanes):
+    if isinstance(c, Decimal128Column):
+        return c.from_lanes(lanes)
+    if isinstance(c, NestedColumn):
+        return NestedColumn(*lanes, jax.tree_util.tree_map(
+            np.asarray, c.children), c.type)
+    return Column(lanes[0], lanes[1], c.type, c.dictionary)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def presto_live_rows(lanes, k: int):
+    """The first `k` slots of every lane, the lanes of one dtype end to
+    end in one array, in the order their dtypes first appear."""
+    by_dtype: dict = {}
+    for a in lanes:
+        by_dtype.setdefault(a.dtype, []).append(a[:k])
+    return tuple(jnp.concatenate(parts) for parts in by_dtype.values())
+
+
+def page_to_host(page: Page) -> Page:
+    """A device page's host form at its live extent (`live_extent`):
+    every row-aligned array cut to that many slots by one program, the
+    arrays of one dtype stacked, each stack's transfer started before
+    any is waited on, and the host page built from views of them. Each
+    array keeps its dtype, so the bits that reach the host are those of
+    a fetch of the array itself. A nested column's children come whole,
+    one fetch an array. A page whose arrays are not all on one device (a
+    mesh tier's, or a host page) is read one array at a time, at its
+    capacity. The `download` span open on this thread gets `bytes` (what
+    moved), `capacity_bytes` (the page's arrays at capacity), `arrays`
+    and `transfers`."""
+    n = int(page.num_rows)
+    by_column = [_row_lanes(c) for c in page.columns]
+    lanes = [a for column in by_column for a in column]
+    cap = page.capacity
+    devices = {d for a in lanes if isinstance(a, jax.Array)
+               for d in a.devices()}
+    stacked = (cap > 0 and len(devices) == 1
+               and all(isinstance(a, jax.Array) and a.shape == (cap,)
+                       for a in lanes))
+    leaves = jax.tree_util.tree_leaves(page)
+    if stacked:
+        k = live_extent(cap, n)
+        outs = presto_live_rows(tuple(lanes), k)
+        for o in outs:
+            o.copy_to_host_async()
+        views = {o.dtype: iter(np.split(np.asarray(o), len(o) // k))
+                 for o in outs}
+        cols = [_with_row_lanes(c, [next(views[a.dtype]) for a in column])
+                for c, column in zip(page.columns, by_column)]
+        children = [a for c in page.columns if isinstance(c, NestedColumn)
+                    for a in jax.tree_util.tree_leaves(c.children)]
+        moved = sum(int(a.nbytes) for a in (*outs, *children))
+        transfers = len(outs) + device_leaves(children)
+        form = "stacked"
+    else:
+        host = jax.tree_util.tree_map(np.asarray, page)
+        cols = host.columns
+        on_device = [a for a in leaves if isinstance(a, jax.Array)]
+        moved = sum(int(a.nbytes) for a in on_device)
+        transfers = len(on_device)
+        form = "per_array"
+    _DOWNLOAD_TRANSFERS.inc(transfers, form=form)
+    TRACER.add("download", bytes=moved, capacity_bytes=page_nbytes(page),
+               arrays=len(leaves), transfers=transfers)
+    return Page.host_from_columns(cols, n, page.names)
 
 
 def select_page_host(page: Page, idx: np.ndarray) -> Page:
@@ -909,7 +1002,7 @@ def select_page_host(page: Page, idx: np.ndarray) -> Page:
             continue
         if isinstance(c, NestedColumn):
             # starts are absolute child positions: the children stay
-            # whole, as the host copies `page_to_host` left with them
+            # whole, as `page_to_host` fetched them
             cols.append(NestedColumn(
                 np.asarray(c.starts)[idx], np.asarray(c.lengths)[idx],
                 np.asarray(c.nulls)[idx],

@@ -1247,9 +1247,8 @@ class TpuTaskManager:
             # build-side fragment: summarize the join-key domain from
             # the pre-partitioning page (DynamicFilterSourceOperator)
             self._accumulate_df_domain(task, page)
-        with TRACER.span(None, "download",
-                         bytes=page_nbytes(page)):
-            page_to_host(page)
+        with TRACER.span(None, "download"):
+            page = page_to_host(page)
         with TRACER.span(None, "serialize", device_puts=0,
                          buffers=len(task.buffers.buffers)) as sp:
             before = task.bytes_out

@@ -7,6 +7,12 @@ on the device; the wire frames are, byte for byte, what the device-built
 pages of before gave. What a jitted island takes is a device page, always:
 a numpy leaf handed to `jax.jit` would be uploaded again on every call.
 
+A task's output page comes to the host as its live rows (`page_to_host`):
+one program cuts every row-aligned array to the page's live extent and
+stacks the arrays of each dtype, one transfer a stack; what it hands the
+router is a host page whose rows, dictionaries and frames are those of
+the device page.
+
 A page's form is the type of its arrays (`device_leaves(page) == 0` says
 host). CPU: forms, bytes and counts, never a rate."""
 
@@ -24,8 +30,8 @@ from presto_tpu.connectors import TpchConnector
 from presto_tpu.data import column as column_mod
 from presto_tpu.data.column import (
     Column, Decimal128Column, NestedColumn, Page, StringDict,
-    bucket_capacity, concat_pages_host, device_leaves, page_to_host,
-    select_page_host,
+    bucket_capacity, concat_pages_host, device_leaves, live_extent,
+    page_nbytes, page_to_host, select_page_host,
 )
 from presto_tpu.exec.split_executor import SplitExecutor
 from presto_tpu.protocol import serde
@@ -54,6 +60,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import compare  # noqa: E402
+from compile_counter import compile_counter  # noqa: E402
 import qgen  # noqa: E402
 import run as bench_run  # noqa: E402
 
@@ -195,10 +202,10 @@ def test_a_partition_is_a_host_page(kind):
     _types, pages = make_pages(kind)
     page = pages[0]
     assert all_on_device(page)
-    page_to_host(page)
+    host = page_to_host(page)
     n = int(page.num_rows)
     for idx in (np.arange(0, n, 2), np.arange(n), np.zeros(0, np.int64)):
-        part = select_page_host(page, idx)
+        part = select_page_host(host, idx)
         assert device_leaves(part) == 0
         assert type(part.num_rows) is np.int32
         assert all(isinstance(a, (np.ndarray, np.generic))
@@ -246,18 +253,27 @@ def _scheme(kind: str, keys):
 
 
 def _routed_frames(page: Page, distribution: str, keys, nbuf: int):
+    """The frames `_route_output` buffers for `page` (a device page is
+    routed as it is: the parent's form) and its `serialize` span."""
     tm = TpuTaskManager(TpchConnector(0.001))
     task = Task("frames.0.0.0")
     task.buffers = OutputBufferManager([str(b) for b in range(nbuf)])
     task.fragment = S.PlanFragment(
         id="0", root=None, variables=[],
         partitioningScheme=_scheme(distribution, keys))
-    page_to_host(page)
     with TRACER.span("frames", "serialize", device_puts=0) as span:
         tm._route_output(task, page)
     frames = [task.buffers.buffers[str(b)].pages for b in range(nbuf)]
     assert all(len(f) == 1 for f in frames)
     return [f[0] for f in frames], span.attributes
+
+
+def downloaded(page: Page):
+    """(`page_to_host(page)`, the attributes it gave its `download`)."""
+    with trace_scope("download"):
+        with TRACER.span(None, "download") as span:
+            host = page_to_host(page)
+    return host, span.attributes
 
 
 @pytest.mark.parametrize("nbuf", [1, 2, 4])
@@ -274,7 +290,9 @@ def test_frames_are_byte_equal_to_the_device_built_form(kind, distribution,
     keys = (1, 0) if isinstance(page.columns[1], Column) else (0,)
     partitions = serde._EXCHANGE_PAGE.value(side="partition", form="host")
     on_device = serde._EXCHANGE_PAGE.value(side="partition", form="device")
-    got, attributes = _routed_frames(page, distribution, keys, nbuf)
+    host, _download = downloaded(page)
+    assert device_leaves(host) == 0
+    got, attributes = _routed_frames(host, distribution, keys, nbuf)
     if nbuf == 1 or distribution == "FIXED_BROADCAST_DISTRIBUTION":
         want = [frame_of(page)] * nbuf
         built = 0
@@ -294,6 +312,132 @@ def test_frames_are_byte_equal_to_the_device_built_form(kind, distribution,
         - partitions == built
     assert serde._EXCHANGE_PAGE.value(side="partition", form="device") \
         == on_device
+
+
+# -- (ii b) the download ----------------------------------------------------
+
+def row_lanes(page: Page):
+    return [a for c in page.columns for a in column_mod._row_lanes(c)]
+
+
+def child_leaves(page: Page):
+    return [a for c in page.columns if isinstance(c, NestedColumn)
+            for a in jax.tree_util.tree_leaves(c.children)]
+
+
+def wide_page(n: int, capacity: int = 262144) -> Page:
+    """BIGINT, DOUBLE and VARCHAR columns of n rows in `capacity` slots."""
+    rng = np.random.default_rng(n)
+    return Page.from_columns([
+        Column.from_numpy(rng.integers(0, 50, n), BIGINT, capacity=capacity),
+        Column.from_numpy(rng.standard_normal(n), DOUBLE,
+                          nulls=rng.random(n) < 0.1, capacity=capacity),
+        Column.from_numpy(rng.integers(0, len(SHARED), n).astype(np.int32),
+                          VARCHAR, dictionary=SHARED, capacity=capacity),
+    ], n, ("k", "x", "s"))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_page_to_host_gives_the_device_pages_rows_as_a_host_page(kind):
+    """Each of the three pages (1,024 and 256 slots: fetched whole): the
+    same rows, dictionaries and names, every row-aligned array's slots
+    bit for bit, in one transfer a dtype (a nested column's children one
+    an array, as before)."""
+    _types, pages = make_pages(kind)
+    for page in pages:
+        host, attributes = downloaded(page)
+        assert device_leaves(host) == 0
+        assert type(host.num_rows) is np.int32
+        assert int(host.num_rows) == int(page.num_rows)
+        assert host.capacity == page.capacity == live_extent(
+            page.capacity, int(page.num_rows))
+        assert host.to_pylist() == page.to_pylist()
+        assert [c.dictionary for c in host.columns] == \
+            [c.dictionary for c in page.columns]
+        assert host.names == page.names
+        for got, want in zip(row_lanes(host), row_lanes(page)):
+            assert got.dtype == want.dtype
+            assert got.tobytes() == np.asarray(want).tobytes()
+        for got, want in zip(child_leaves(host), child_leaves(page)):
+            assert got.tobytes() == np.asarray(want).tobytes()
+        dtypes = {a.dtype for a in row_lanes(page)}
+        assert attributes["transfers"] == \
+            len(dtypes) + len(child_leaves(page))
+        assert attributes["arrays"] == len(jax.tree_util.tree_leaves(page))
+        assert attributes["transfers"] < attributes["arrays"]
+        assert attributes["capacity_bytes"] == page_nbytes(page)
+        assert attributes["bytes"] <= attributes["capacity_bytes"]
+
+
+def test_a_large_page_moves_the_sixteenths_that_hold_its_rows():
+    page = wide_page(1000)
+    stacked = column_mod._DOWNLOAD_TRANSFERS.value(form="stacked")
+    host, attributes = downloaded(page)
+    assert host.capacity == 262144 // 16
+    assert attributes["bytes"] < attributes["capacity_bytes"]
+    assert attributes["bytes"] * 16 <= attributes["capacity_bytes"]
+    assert attributes["transfers"] == 4       # int64, float64, int32, bool
+    assert column_mod._DOWNLOAD_TRANSFERS.value(form="stacked") \
+        - stacked == 4
+    assert host.to_pylist() == page.to_pylist()
+    for distribution, nbuf in (("FIXED_HASH_DISTRIBUTION", 4),
+                               ("FIXED_ARBITRARY_DISTRIBUTION", 2),
+                               ("SINGLE", 1)):
+        assert _routed_frames(host, distribution, (0, 1), nbuf)[0] == \
+            _routed_frames(page, distribution, (0, 1), nbuf)[0]
+
+
+def test_a_page_of_the_same_shape_and_sixteenth_compiles_nothing():
+    counter = compile_counter()
+    downloaded(wide_page(1000))
+    requests = counter.requests
+    host, _attributes = downloaded(wide_page(16384))
+    assert counter.requests == requests
+    assert host.capacity == 262144 // 16
+    # the next sixteenth is a program of its own, built once
+    assert downloaded(wide_page(16385))[0].capacity == 2 * 262144 // 16
+    assert counter.requests == requests + 1
+    downloaded(wide_page(30000))
+    assert counter.requests == requests + 1
+
+
+@pytest.mark.parametrize("nbuf", [1, 2, 4])
+@pytest.mark.parametrize("distribution", [
+    "FIXED_HASH_DISTRIBUTION", "FIXED_ARBITRARY_DISTRIBUTION",
+    "FIXED_BROADCAST_DISTRIBUTION"])
+def test_an_empty_page_routes_as_before(distribution, nbuf):
+    page = Page.from_columns([
+        Column.from_numpy(np.zeros(0, np.int64), BIGINT),
+        Column.from_strings([])], 0, ("k", "v"))
+    host, attributes = downloaded(page)
+    assert device_leaves(host) == 0 and int(host.num_rows) == 0
+    assert host.capacity == 256 and attributes["transfers"] == 3
+    assert _routed_frames(host, distribution, (0,), nbuf)[0] == \
+        _routed_frames(page, distribution, (0,), nbuf)[0]
+
+
+def test_a_page_across_devices_or_on_the_host_is_read_an_array_at_a_time():
+    """The mesh tier's page (its arrays sharded over devices) is fetched
+    as before, at capacity; a host page moves nothing."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    _types, pages = make_pages("double")
+    page = pages[0]
+    mesh = Mesh(np.array(jax.devices()[:2]), ("x",))
+    sharded = Page(jax.device_put(
+        page.columns, NamedSharding(mesh, PartitionSpec("x"))),
+        page.num_rows, page.names)
+    per_array = column_mod._DOWNLOAD_TRANSFERS.value(form="per_array")
+    host, attributes = downloaded(sharded)
+    leaves = len(jax.tree_util.tree_leaves(page))
+    assert attributes["transfers"] == attributes["arrays"] == leaves
+    assert attributes["bytes"] == attributes["capacity_bytes"]
+    assert column_mod._DOWNLOAD_TRANSFERS.value(form="per_array") \
+        - per_array == leaves
+    assert device_leaves(host) == 0 and host.capacity == page.capacity
+    assert host.to_pylist() == page.to_pylist()
+    again, attributes = downloaded(host)
+    assert attributes["transfers"] == 0 and attributes["bytes"] == 0
+    assert again.to_pylist() == page.to_pylist()
 
 
 # -- (iii) the fuse ----------------------------------------------------------

@@ -6,16 +6,21 @@ task's completion as its watcher learns of it (`task_notice`, from the
 worker's `stateChangedAt`) and the ordered root merge's consumer loop
 (`root_merge`, with what it spent blocked in `get`). One statement at
 SF0.01 through POST /v1/statement on two workers: a grouped aggregation
-whose two FINAL + Sort tasks the coordinator merges in order. CPU:
-what is there and how it nests, never a time."""
+whose two FINAL + Sort tasks the coordinator merges in order. And
+TPC-H Q1 itself: each task's `download` of its output page, what moved
+against what the page holds at capacity, and the transfers it took.
+CPU: what is there and how it nests, never a time."""
 
 import dataclasses
 import json
+import os
+import sys
 import threading
 
 import pytest
 
 from presto_tpu.config import DEFAULT_OBS
+from presto_tpu.obs.metrics import REGISTRY
 from presto_tpu.connectors import TpchConnector
 from presto_tpu.protocol import structs as S
 from presto_tpu.protocol.transport import get_client
@@ -23,6 +28,13 @@ from presto_tpu.server import statement as statement_mod
 from presto_tpu.server.cluster import TpuCluster, bounded_merge
 from presto_tpu.server.statement import StatementServer
 from presto_tpu.utils.tracing import TRACER, trace_scope
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import qgen  # noqa: E402
 
 SQL = ("select l_returnflag, l_linestatus, count(*), sum(l_quantity) "
        "from lineitem group by l_returnflag, l_linestatus "
@@ -131,6 +143,27 @@ def test_the_ordered_root_merges_two_streams_once(traced):
     assert merge.attributes["blocked_ms"] >= 0
     assert merge.attributes["timeouts"] == 0     # each end came queued
     assert "waited_ms" not in merge.attributes   # a span, not a marker
+
+
+def test_each_download_moves_its_live_rows_in_few_transfers(served):
+    """Q1 at DELTA 90: every task's output page comes to the host in
+    fewer transfers than it has arrays, moving no more than the page
+    holds at capacity, and the counter beside the span rises by the
+    spans' transfers."""
+    srv, _cluster = served
+    transfers = REGISTRY.get("presto_tpu_download_transfers_total")
+    before = transfers.value(form="stacked")
+    sql = qgen.load_query("q01")["sql"].format(DELTA=90)
+    qid, rows = _statement(srv.base, sql)
+    assert len(rows) == 4
+    downloads = [s.attributes for s in TRACER.get(qid)
+                 if s.name == "download"]
+    assert len(downloads) == 4          # two PARTIAL, two FINAL + Sort
+    for at in downloads:
+        assert at["bytes"] <= at["capacity_bytes"]
+        assert 0 < at["transfers"] < at["arrays"]
+    assert transfers.value(form="stacked") - before == \
+        sum(at["transfers"] for at in downloads)
 
 
 def test_an_unsampled_statement_stores_none_of_them(served, monkeypatch):
