@@ -41,6 +41,13 @@ from presto_tpu.types import (
 from presto_tpu.expr.nodes import (
     Call, Form, InputRef, Literal, Param, RowExpression, SpecialForm,
 )
+from presto_tpu.obs.metrics import counter
+from presto_tpu.utils.tracing import TRACER
+
+_DICT_PREDICATE_WORDS = counter(
+    "presto_tpu_dict_predicate_words_total",
+    "Dictionary words a string predicate (LIKE, starts_with, "
+    "regexp_like) evaluated on the host while a program was lowered")
 
 # ---------------------------------------------------------------------------
 # helpers
@@ -652,12 +659,20 @@ def _dict_transform(col: Column, fn) -> Column:
     return Column(nv, col.nulls, col.type, newd)
 
 
-def _dict_predicate(col: Column, fn) -> Column:
-    """Host predicate over dictionary words -> device boolean via gather."""
+def _dict_predicate(col: Column, fn, name: str) -> Column:
+    """Host predicate over dictionary words -> device boolean via gather.
+    The host pass runs once a lowering, under a `dict_predicate` span
+    (`words` the dictionary's size, `kept` those that satisfy `fn`,
+    `fn` the SQL function's `name`)."""
     words = col.dictionary.words
     if not words:
         return _bool(jnp.zeros_like(col.nulls), col.nulls)
-    tbl = jnp.asarray(np.array([bool(fn(w)) for w in words]))
+    with TRACER.span(None, "dict_predicate", words=len(words),
+                     fn=name) as sp:
+        kept = np.array([bool(fn(w)) for w in words])
+        sp.attributes["kept"] = int(kept.sum())
+    _DICT_PREDICATE_WORDS.inc(len(words))
+    tbl = jnp.asarray(kept)
     v = jnp.take(tbl, jnp.clip(col.values, 0, len(words) - 1))
     return _bool(v, col.nulls)
 
@@ -805,7 +820,8 @@ def _call(e: Call, page: Page, ev) -> Column:
         assert isinstance(pat, Literal), "LIKE pattern must be a literal"
         esc = e.args[2].value if len(e.args) > 2 else None
         rx = _like_regex(pat.value, esc)
-        return _dict_predicate(c, lambda w: rx.match(w) is not None)
+        return _dict_predicate(c, lambda w: rx.match(w) is not None,
+                               "like")
     if name == "substr":
         c = ev(e.args[0], page)
         start = e.args[1].value  # 1-based, literal
@@ -921,11 +937,13 @@ def _call(e: Call, page: Page, ev) -> Column:
     if name == "starts_with":
         c = ev(e.args[0], page)
         pre = _litstr(1, "prefix")
-        return _dict_predicate(c, lambda w: w.startswith(pre))
+        return _dict_predicate(c, lambda w: w.startswith(pre),
+                               "starts_with")
     if name == "regexp_like":
         c = ev(e.args[0], page)
         rx = _regex_cache(_litstr(1, "pattern"))
-        return _dict_predicate(c, lambda w: rx.search(w) is not None)
+        return _dict_predicate(c, lambda w: rx.search(w) is not None,
+                               "regexp_like")
     if name == "regexp_extract":
         c = ev(e.args[0], page)
         rx = _regex_cache(_litstr(1, "pattern"))

@@ -184,7 +184,7 @@ class ExchangeClient:
         here = self._trace
         with TRACER.span(here.trace_id if here else None, "deserialize",
                          parent_id=here.parent_span_id if here else None,
-                         bytes=len(data)):
+                         bytes=len(data), dict_words=0):
             return decode_pages(data, self.types)
 
     def _offer(self, nbytes: int, payload) -> bool:
@@ -303,7 +303,8 @@ def stream_pages(location: str, buffer_id: str = "0", types=None, *,
             if types is None:
                 yield data
             else:
-                with TRACER.span(None, "deserialize", bytes=len(data)):
+                with TRACER.span(None, "deserialize", bytes=len(data),
+                                 dict_words=0):
                     pages = decode_pages(data, list(types))
                 yield from pages
     finally:

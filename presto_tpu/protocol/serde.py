@@ -740,6 +740,8 @@ def _flat_to_wire(t, vals: np.ndarray, nulls: np.ndarray,
     if t.is_string and dictionary is not None:
         _note_dictionary("encode", dictionary.has_wire_form)
         ends, payload, (most, least) = dictionary.wire_form()
+        # every frame carries its dictionary's words, kept form or not
+        TRACER.add("serialize", dict_words=len(ends))
         ids = np.where(nulls, 0, vals).astype(np.int32)
         slot_nulls, sequence = None, 0
         # Presto represents a null string position as a null slot in
@@ -1028,12 +1030,13 @@ def _decode_words(d: WireBlock):
     identity), `isnull[i]` whether that position is a null slot (None
     where there is none). Null slots read as "", which the dictionary
     then holds. Once a dictionary: the one place with a pass of Python
-    over the words."""
+    over the words, counted on the `deserialize` span as `dict_words`."""
     from presto_tpu.data.column import StringDict
 
     if d.encoding != "VARIABLE_WIDTH":
         raise NotImplementedError(f"string dictionary block {d.encoding}")
     k = d.position_count
+    TRACER.add("deserialize", dict_words=k)
     if d.ends is not None:
         raw = d.payload.tobytes()
         text = raw.decode()

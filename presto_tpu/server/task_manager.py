@@ -1249,7 +1249,7 @@ class TpuTaskManager:
             self._accumulate_df_domain(task, page)
         with TRACER.span(None, "download"):
             page = page_to_host(page)
-        with TRACER.span(None, "serialize", device_puts=0,
+        with TRACER.span(None, "serialize", device_puts=0, dict_words=0,
                          buffers=len(task.buffers.buffers)) as sp:
             before = task.bytes_out
             self._route_output(task, page)
